@@ -3,9 +3,9 @@
 Three measurements:
   * single-tensor jnp reduction (baseline GB/s),
   * per-leaf vs FUSED whole-state fingerprint on a many-leaf model-like
-    state — the fused path packs all leaves into one u32 buffer and makes a
-    single fingerprint pass (one launch instead of n_leaves), which is the
-    engine's hot validation path,
+    state — the fused path hashes every leaf in place at its global offset
+    and returns one fingerprint for the whole state, which is the engine's
+    hot validation path,
   * Pallas kernel correctness vs the jnp oracle (interpret mode on CPU —
     relative numbers only; the BlockSpec tiling is what a TPU executes).
 """
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, timeit
-from repro.core.fingerprint import (packed_fingerprint, pytree_fingerprint,
+from repro.core.fingerprint import (pytree_fingerprint,
                                     pytree_fingerprint_fused,
                                     tensor_fingerprint)
 from repro.kernels import ops
@@ -120,10 +120,10 @@ def main() -> None:
     b = np.asarray(fingerprint_ref(x))
     emit("fingerprint_pallas_vs_oracle", 0.0,
          f"hash_exact_match={bool(np.array_equal(a[:2], b[:2]))}")
-    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    c = np.asarray(ops.fingerprint_packed(u))
-    d = np.asarray(packed_fingerprint(u))
-    emit("fingerprint_pallas_packed_vs_fused_jnp", 0.0,
+    state = _transformer_like_state()
+    c = np.asarray(pytree_fingerprint_fused(state, use_pallas=True))
+    d = np.asarray(pytree_fingerprint_fused(state, use_pallas=False))
+    emit("fingerprint_pallas_fused_vs_fused_jnp", 0.0,
          f"hash_exact_match={bool(np.array_equal(c[:2], d[:2]))}")
 
 

@@ -57,13 +57,8 @@ class SedarSafeStop(RuntimeError):
 # ---------------------------------------------------------------------------
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
-    except TypeError:   # older kwarg name
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_pod_comparator(mesh, axis: str = "pod"):

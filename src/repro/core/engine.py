@@ -577,11 +577,10 @@ class FusedSequentialExecutor(ReplicaExecutor):
             fps = jax.vmap(self.fast_state_fp_fn)(stacked)
             return fingerprints_equal(fps[0], fps[1])
 
-        # donation is skipped on CPU (XLA:CPU cannot alias; donating only
-        # produces "donated buffer unusable" warnings in the test container)
-        donate_args = (0,) if (donate and jax.default_backend() != "cpu") \
-            else ()
-        self._step_gated = jax.jit(_gated, donate_argnums=donate_args)
+        # the same donation on every backend: the CPU tests run the aliasing
+        # the chip runs, so a stale reference to a donated buffer fails there
+        self._step_gated = jax.jit(_gated,
+                                   donate_argnums=(0,) if donate else ())
         self._validate_jit = jax.jit(_validate)
 
     def init_dual(self, single):

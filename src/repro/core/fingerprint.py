@@ -25,12 +25,13 @@ replica comparison is a single small array equality.
 Two granularities (DESIGN.md §5):
   * per-leaf  -- `pytree_fingerprint` -> (n_leaves, 4). One reduction per
     leaf; keeps leaf-level localization for `mismatch_report`.
-  * fused     -- `pytree_fingerprint_fused` -> (4,). All leaves are packed
-    (bit-exactly, via `_to_u32`) into ONE flat u32 buffer and hashed in a
-    single streaming pass — one kernel launch instead of n_leaves, which is
-    what the comparison hot path wants (models have hundreds of leaves, most
-    of them small). The fused hash is NOT comparable to per-leaf hashes
-    (different index stream); both replicas must use the same granularity.
+  * fused     -- `pytree_fingerprint_fused` -> (4,). The hash of all leaves
+    LOGICALLY packed (bit-exactly, via `_to_u32`) into one flat u32 buffer:
+    each leaf is hashed in place at its global word offset and the partials
+    are summed, so the comparison hot path gets one fingerprint per state
+    without a packed copy. The fused hash is NOT comparable to per-leaf
+    hashes (different index stream); both replicas must use the same
+    granularity.
 """
 from __future__ import annotations
 
@@ -45,8 +46,9 @@ C2 = np.uint32(2246822519)   # xxhash prime
 C3 = np.uint32(3266489917)   # xxhash prime
 
 
-def _to_u32(x) -> jnp.ndarray:
-    """Exact reinterpretation of any dtype as a flat u32 vector."""
+def words_u32(x) -> jnp.ndarray:
+    """Exact reinterpretation of any dtype as u32 words, shape kept (so the
+    chip's kernel can read a leaf in its own tiled layout)."""
     x = jnp.asarray(x)
     if x.dtype in (jnp.float64, jnp.int64):  # CPU tests may use 64-bit
         x = x.astype(jnp.float32) if jnp.issubdtype(x.dtype, jnp.floating) \
@@ -63,7 +65,12 @@ def _to_u32(x) -> jnp.ndarray:
         u = x.astype(jnp.uint32)
     else:
         raise TypeError(f"unsupported dtype {x.dtype}")
-    return u.reshape(-1)
+    return u
+
+
+def _to_u32(x) -> jnp.ndarray:
+    """Exact reinterpretation of any dtype as a flat u32 vector."""
+    return words_u32(x).reshape(-1)
 
 
 def tensor_fingerprint(x) -> jnp.ndarray:
@@ -123,18 +130,52 @@ def packed_fingerprint(u: jnp.ndarray) -> jnp.ndarray:
     u = jnp.asarray(u)
     if u.dtype != jnp.uint32:
         u = _to_u32(u)
-    u = u.reshape(-1)
-    n = u.shape[0]
-    if n == 0:
-        return jnp.zeros((4,), jnp.uint32)
-    idx = jax.lax.iota(jnp.uint32, n)
-    h1 = jnp.sum((u ^ (idx * C1)) * C2, dtype=jnp.uint32)
+    return _combine([_words_partials(u, 0)] if u.size else [])
+
+
+def _row_major_index(shape) -> jnp.ndarray:
+    """u32 row-major position of every element of an array of `shape`."""
+    idx = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for d in reversed(range(len(shape))):
+        idx = idx + (jax.lax.broadcasted_iota(jnp.uint32, shape, d)
+                     * jnp.uint32(stride % (1 << 32)))
+        stride *= shape[d]
+    return idx
+
+
+def _words_partials(x, offset: int, lo: int = 0, hi: Optional[int] = None):
+    """jnp fingerprint terms of words [lo, hi) of one tensor (row-major
+    order), word i at global position offset + i -> (h1, h2, sum, absmax).
+    The tensor is read in its own shape, never through a flattened or
+    sliced copy."""
+    u = words_u32(x)
+    i = _row_major_index(u.shape)
+    idx = jnp.uint32(offset % (1 << 32)) + i
+    t1 = (u ^ (idx * C1)) * C2
     t2 = (u + idx) * C3
-    h2 = jnp.sum(t2 ^ (t2 >> jnp.uint32(15)), dtype=jnp.uint32)
+    t2 = t2 ^ (t2 >> jnp.uint32(15))
     xf = jax.lax.bitcast_convert_type(u, jnp.float32)
-    sb = jax.lax.bitcast_convert_type(jnp.sum(xf), jnp.uint32)
-    ab = jax.lax.bitcast_convert_type(jnp.max(jnp.abs(xf)), jnp.uint32)
-    return jnp.stack([h1, h2, sb, ab])
+    if lo > 0 or (hi is not None and hi < u.size):
+        inside = (i >= jnp.uint32(lo)) & (i < jnp.uint32(u.size if hi is None
+                                                         else hi))
+        t1 = jnp.where(inside, t1, jnp.uint32(0))
+        t2 = jnp.where(inside, t2, jnp.uint32(0))
+        xf = jnp.where(inside, xf, 0.0)
+    return (jnp.sum(t1, dtype=jnp.uint32), jnp.sum(t2, dtype=jnp.uint32),
+            jnp.sum(xf), jnp.max(jnp.abs(xf)))
+
+
+def _combine(partials) -> jnp.ndarray:
+    """Fold (h1, h2, sum, absmax) partials into one (4,) fingerprint."""
+    if not partials:
+        return jnp.zeros((4,), jnp.uint32)
+    h1s, h2s, ss, as_ = zip(*partials)
+    return jnp.stack([
+        jnp.sum(jnp.stack(h1s), dtype=jnp.uint32),
+        jnp.sum(jnp.stack(h2s), dtype=jnp.uint32),
+        jax.lax.bitcast_convert_type(jnp.sum(jnp.stack(ss)), jnp.uint32),
+        jax.lax.bitcast_convert_type(jnp.max(jnp.stack(as_)), jnp.uint32)])
 
 
 def pytree_fingerprint_fused(tree, use_pallas: Optional[bool] = None
@@ -142,60 +183,43 @@ def pytree_fingerprint_fused(tree, use_pallas: Optional[bool] = None
     """Whole-state fingerprint -> (4,) uint32: ONE fingerprint over the
     logically-packed state instead of one per leaf.
 
-    Two value-identical lowerings of the same hash (hash words compare equal
-    across both — verified by tests):
-      * Pallas (accelerators): flatten/concatenate the leaves once into a
-        packed u32 buffer and make a single `fingerprint_pallas` pass over
-        it — one kernel launch for the whole state.
-      * jnp (CPU/XLA): per-leaf partial reductions with GLOBAL element
-        offsets folded into the index stream, combined with one final
-        add/max. Modular-add reductions are associative/commutative, so the
-        partials sum to exactly the packed-buffer hash — without
-        materializing the concatenation (which would cost an extra full
-        write+read pass).
+    Every leaf is hashed IN PLACE with its GLOBAL word offset folded into
+    the index stream, and the per-leaf partials combine with one final
+    add/max. Modular-add reductions are associative/commutative, so the
+    partials sum to exactly the hash of `pack_tree_u32(tree)` — without
+    materializing the concatenation (an extra full write+read pass and a
+    second copy of the state in device memory). Two value-identical
+    lowerings of the per-leaf partials (hash words compare equal — verified
+    by tests):
+      * Pallas (the chip): `kernels.fingerprint.fingerprint_partials`, one
+        streaming kernel per leaf.
+      * jnp (the CPU backend): XLA reductions.
 
-    `use_pallas=None` auto-selects from the JAX backend."""
+    `use_pallas=None` selects Pallas everywhere but the CPU backend."""
     if use_pallas is None:
         from repro.kernels.fingerprint import default_interpret
         use_pallas = not default_interpret()
     if use_pallas:
-        u = pack_tree_u32(tree)
-        if u.shape[0]:
-            from repro.kernels.ops import fingerprint_packed
-            return fingerprint_packed(u)
-        return jnp.zeros((4,), jnp.uint32)
+        from repro.kernels.fingerprint import fingerprint_partials as partials
+    else:
+        partials = _words_partials
 
-    leaves = jax.tree.leaves(tree)
-    h1s, h2s, ss, as_ = [], [], [], []
+    parts = []
     offset = 0
-    for l in leaves:
-        u = _to_u32(l)
-        n = u.shape[0]
-        if n == 0:
-            continue
-        idx = jnp.uint32(offset) + jax.lax.iota(jnp.uint32, n)
-        h1s.append(jnp.sum((u ^ (idx * C1)) * C2, dtype=jnp.uint32))
-        t2 = (u + idx) * C3
-        h2s.append(jnp.sum(t2 ^ (t2 >> jnp.uint32(15)), dtype=jnp.uint32))
-        xf = jax.lax.bitcast_convert_type(u, jnp.float32)
-        ss.append(jnp.sum(xf))
-        as_.append(jnp.max(jnp.abs(xf)))
+    for l in jax.tree.leaves(tree):
+        n = int(np.size(l))
+        if n:
+            parts.append(partials(l, offset))
         offset += n
-    if not h1s:
-        return jnp.zeros((4,), jnp.uint32)
-    h1 = jnp.sum(jnp.stack(h1s), dtype=jnp.uint32)
-    h2 = jnp.sum(jnp.stack(h2s), dtype=jnp.uint32)
-    s = jnp.sum(jnp.stack(ss))
-    a = jnp.max(jnp.stack(as_))
-    return jnp.stack([h1, h2, jax.lax.bitcast_convert_type(s, jnp.uint32),
-                      jax.lax.bitcast_convert_type(a, jnp.uint32)])
+    return _combine(parts)
 
 
 def pytree_fingerprint_lanes(tree, n_lanes: int) -> jnp.ndarray:
     """Per-shard fingerprint lanes -> (n_lanes, 4) uint32 (DESIGN.md §16).
 
     The packed state is split into `n_lanes` equal contiguous chunks
-    (zero-padded tail) and each chunk is hashed independently, so a replica
+    (zero-padded tail) and each chunk is hashed independently (each leaf in
+    place, never through a packed copy of the state), so a replica
     divergence localizes to the lane covering the corrupted words instead
     of collapsing into one whole-state bit. Lane i covers packed u32 words
     [i*W, (i+1)*W), W = ceil(N/n_lanes); callers align n_lanes with shard
@@ -203,15 +227,26 @@ def pytree_fingerprint_lanes(tree, n_lanes: int) -> jnp.ndarray:
     runtime/cluster.lanes_to_hosts). NOT comparable with the fused or
     per-leaf granularities (different index streams)."""
     L = max(int(n_lanes), 1)
-    u = pack_tree_u32(tree)
-    n = int(u.shape[0])
-    if n == 0:
+    leaves = [l for l in jax.tree.leaves(tree) if np.size(l)]
+    total = sum(int(np.size(l)) for l in leaves)
+    if total == 0:
         return jnp.zeros((L, 4), jnp.uint32)
-    width = -(-n // L)
-    pad = L * width - n
-    if pad:
-        u = jnp.concatenate([u, jnp.zeros((pad,), jnp.uint32)])
-    return jax.vmap(packed_fingerprint)(u.reshape(L, width))
+    width = -(-total // L)
+    if L * width > total:   # the zero-padded tail is hashed like a leaf
+        leaves.append(jnp.zeros((L * width - total,), jnp.uint32))
+    # each leaf is hashed in place, once per lane it overlaps, at its
+    # lane-local word positions
+    parts = [[] for _ in range(L)]
+    offset = 0
+    for l in leaves:
+        n = int(np.size(l))
+        for lane in range(offset // width, (offset + n - 1) // width + 1):
+            start = lane * width
+            parts[lane].append(_words_partials(
+                l, offset - start, lo=max(start - offset, 0),
+                hi=min(start + width - offset, n)))
+        offset += n
+    return jnp.stack([_combine(p) for p in parts])
 
 
 def lane_of_leaf_index(tree, leaf_idx: int, flat_idx: int, n_lanes: int

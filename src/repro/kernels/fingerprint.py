@@ -2,28 +2,43 @@
 
 SEDAR's hot spot is the comparison/validation pass over every byte of
 gradient/parameter state (DESIGN.md §5). This kernel computes, in a single
-HBM pass with (block_rows, 128) VMEM tiles:
+HBM pass over one tensor's 32-bit words u_i at GLOBAL positions
+i = offset + row-major index:
 
     h1 = sum_i ((u_i XOR (i*C1)) * C2)        mod 2^32
     h2 = sum_i (t XOR (t >> 15)), t=(u_i+i)*C3
     s  = sum(x)       (f32)
     a  = max(|x|)     (f32)
 
-identical bit-for-bit to the pure-jnp oracle `repro.core.fingerprint.
-tensor_fingerprint` (= kernels/ref.py::fingerprint_ref). The reduction terms
-are associative/commutative, so the grid accumulates into 4 scalar output
-refs; padding lanes contribute the identity (0 for sum/xor, -inf for max).
+The hash words are identical bit-for-bit to the pure-jnp oracles
+(`repro.core.fingerprint.tensor_fingerprint` at offset 0, and the per-leaf
+partials of `pytree_fingerprint_fused` at the leaf's global offset). Both
+reductions are modular adds, so per-tensor partials at global offsets sum
+to the hash of the logically packed state — the whole-state fingerprint
+hashes every leaf IN PLACE, never through a concatenated copy.
 
-The tensor is viewed as (rows, 128) u32 lanes — the native f32 VREG tile is
-(8, 128), so block_rows is a multiple of 8 and the last dim is exactly the
-128-lane width. Arithmetic intensity is O(1) FLOP/byte: the kernel is
-memory-bound by design and its roofline cost is one read of the state.
+Layout: a tensor whose last dim is a multiple of 128 (and whose
+second-to-last dim is a multiple of 8 when it has more than two dims) is
+viewed as (rows, cols) without moving a byte — merging leading dims keeps
+the chip's (8, 128) tiled layout. Anything else (1-D buffers, head_dim-64
+leaves, short tensors) is flattened and padded to (rows, 128), a copy of
+that one leaf. The view is streamed in (block_rows, block_cols) tiles; an
+inner loop walks each tile one (8, 128) vreg at a time and folds it into
+four (8, 128) accumulator tiles that stay resident in the output across the
+sequential grid. The lane-dense accumulators are reduced to scalars after
+the call, so nothing stores a scalar to VMEM and the out BlockSpec keeps
+the (8, 128) tiling under `jax.vmap` (the replica axis of the fused
+executor's validation becomes a leading grid axis). Partial edge tiles and
+padding words are masked by position. All in-kernel arithmetic is int32
+with logical shifts, which is bit-identical to the u32 definition
+(add/mul/xor wrap mod 2^32). Arithmetic intensity is O(1) op/byte: the
+kernel is memory-bound by design and its roofline cost is one read of the
+state.
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,96 +50,145 @@ C2 = np.uint32(2246822519)
 C3 = np.uint32(3266489917)
 
 LANES = 128
-DEFAULT_BLOCK_ROWS = 256      # (256, 128) u32 = 128 KiB per VMEM tile
+SUBLANES = 8
+BLOCK_WORDS = 1 << 18         # words per input tile: 1 MiB of VMEM
+MAX_BLOCK_COLS = 2048
 
 
 def default_interpret() -> bool:
-    """Interpret-mode auto-detection: compile the kernel for real on TPU,
-    fall back to the Python interpreter elsewhere (CPU test containers).
-    REPRO_PALLAS_INTERPRET=0/1 overrides the backend check."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode only on the CPU backend (the test container);
+    on the chip every kernel compiles."""
+    return jax.default_backend() == "cpu"
 
 
-def _fingerprint_kernel(n_valid, u_ref, h1_ref, h2_ref, s_ref, a_ref):
-    i = pl.program_id(0)
-    rows = u_ref.shape[0]
+def _i32(v) -> np.int32:
+    """u32 bit pattern of a Python int as an int32 constant."""
+    return np.uint32(int(v) % (1 << 32)).view(np.int32)
 
-    @pl.when(i == 0)
+
+def _fingerprint_kernel(n_valid, n_rows, n_cols, offset, u_ref, h1_ref,
+                        h2_ref, s_ref, a_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+    block_rows, block_cols = u_ref.shape
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
     def _init():
-        h1_ref[0] = jnp.uint32(0)
-        h2_ref[0] = jnp.uint32(0)
-        s_ref[0] = jnp.float32(0)
-        a_ref[0] = jnp.float32(0)
+        h1_ref[...] = jnp.zeros_like(h1_ref)
+        h2_ref[...] = jnp.zeros_like(h2_ref)
+        s_ref[...] = jnp.zeros_like(s_ref)
+        a_ref[...] = jnp.zeros_like(a_ref)
 
-    u = u_ref[...]                                   # (rows, 128) u32
-    # program_id is int32 — keep everything uint32 or the h2 mix's right
-    # shift turns arithmetic (sign-extending) instead of logical
-    base = jnp.uint32(i) * jnp.uint32(rows * LANES)
-    idx = (base
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 0)
-           * jnp.uint32(LANES)
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 1))
-    idx = idx.astype(jnp.uint32)
-    valid = idx < jnp.uint32(int(n_valid))   # n_valid is static (x.size)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 1)
+    c1, c2, c3 = _i32(C1), _i32(C2), _i32(C3)
+    zero = jnp.int32(0)
 
-    t1 = jnp.where(valid, (u ^ (idx * C1)) * C2, jnp.uint32(0))
-    h1_ref[0] = h1_ref[0] + jnp.sum(t1, dtype=jnp.uint32)
+    def body(k, carry):
+        h1, h2, s, a = carry
+        r = pl.multiple_of(k * SUBLANES, SUBLANES)
+        row = i * block_rows + r + sub                     # view row
+        for c in range(0, block_cols, LANES):
+            u = u_ref[pl.ds(r, SUBLANES), pl.ds(c, LANES)]  # (8, 128)
+            if u.dtype != jnp.int32:
+                u = jax.lax.bitcast_convert_type(u, jnp.int32)
+            pos = row * n_cols + (j * block_cols + c) + lane
+            valid = jnp.logical_and(row < n_rows, pos < n_valid)
+            idx = pos + _i32(offset)                       # global position
+            t1 = (u ^ (idx * c1)) * c2
+            t2 = (u + idx) * c3
+            t2 = t2 ^ jax.lax.shift_right_logical(t2, jnp.int32(15))
+            xf = jax.lax.bitcast_convert_type(u, jnp.float32)
+            h1 = h1 + jnp.where(valid, t1, zero)
+            h2 = h2 + jnp.where(valid, t2, zero)
+            s = s + jnp.where(valid, xf, 0.0)
+            a = jnp.maximum(a, jnp.where(valid, jnp.abs(xf), 0.0))
+        return h1, h2, s, a
 
-    t2 = (u + idx) * C3
-    t2 = jnp.where(valid, t2 ^ (t2 >> jnp.uint32(15)), jnp.uint32(0))
-    h2_ref[0] = h2_ref[0] + jnp.sum(t2, dtype=jnp.uint32)
-
-    xf = jax.lax.bitcast_convert_type(u, jnp.float32)
-    xv = jnp.where(valid, xf, 0.0)
-    s_ref[0] = s_ref[0] + jnp.sum(xv, dtype=jnp.float32)
-    a_ref[0] = jnp.maximum(a_ref[0], jnp.max(jnp.where(valid, jnp.abs(xf), 0.0)))
+    carry = (h1_ref[...], h2_ref[...], s_ref[...], a_ref[...])
+    h1, h2, s, a = jax.lax.fori_loop(0, block_rows // SUBLANES, body, carry)
+    h1_ref[...] = h1
+    h2_ref[...] = h2
+    s_ref[...] = s
+    a_ref[...] = a
 
 
-def fingerprint_pallas(x, block_rows: int = DEFAULT_BLOCK_ROWS,
-                       interpret: Optional[bool] = None):
-    """-> (4,) uint32, bit-identical to fingerprint_ref. Accepts any floating
-    dtype (exact upcast to f32 first, matching the oracle) or an
-    already-packed uint32 buffer (the fused whole-state path — hashed as-is,
-    no bitcast). `interpret=None` auto-detects from the JAX backend."""
+def _lane_view(u) -> jnp.ndarray:
+    """(rows, cols) view of a 32-bit word tensor, cols a multiple of 128 and
+    rows >= 8. In place where the tiled layout allows it, else a padded flat
+    copy of this one tensor (the padding words are masked in the kernel)."""
+    shape, n = u.shape, u.size
+    if (len(shape) >= 2 and shape[-1] % LANES == 0
+            and (len(shape) == 2 or shape[-2] % SUBLANES == 0)
+            and n // shape[-1] >= SUBLANES):
+        return u.reshape(n // shape[-1], shape[-1])
+    rows = max(-(-n // LANES), SUBLANES)
+    flat = u.reshape(-1)
+    if rows * LANES > n:
+        flat = jnp.pad(flat, (0, rows * LANES - n))
+    return flat.reshape(rows, LANES)
+
+
+def _block_cols(cols: int) -> int:
+    """Widest multiple of 128 that divides `cols` and fits MAX_BLOCK_COLS."""
+    m = cols // LANES
+    return LANES * max(d for d in range(1, MAX_BLOCK_COLS // LANES + 1)
+                       if m % d == 0)
+
+
+def fingerprint_partials(x, offset: int = 0,
+                         block_rows: Optional[int] = None,
+                         interpret: Optional[bool] = None
+                         ) -> Tuple[jnp.ndarray, ...]:
+    """Fingerprint terms of one tensor (any shape and dtype; its words as
+    `core.fingerprint.words_u32` defines them, in row-major order) whose
+    first word sits at global position `offset` -> (h1 u32, h2 u32, sum
+    f32, absmax f32) scalars. Partials of consecutive tensors combine by
+    modular add (h1, h2), add (sum) and max (absmax). `interpret=None`
+    follows the backend.
+
+    32-bit tensors enter the kernel as they are and are reinterpreted
+    inside it: a bitcast ahead of the custom call would be materialized as
+    a copy of the tensor."""
     if interpret is None:
         interpret = default_interpret()
-    x = jnp.asarray(x)
-    if x.dtype == jnp.uint32:
-        u = x.reshape(-1)
-    else:
-        if x.dtype != jnp.float32:
-            x = x.astype(jnp.float32)
-        u = jax.lax.bitcast_convert_type(x.reshape(-1), jnp.uint32)
-    n = u.size
-
-    per_block = block_rows * LANES
-    nblocks = max((n + per_block - 1) // per_block, 1)
-    padded = nblocks * per_block
-    u = jnp.pad(u, (0, padded - n))
-    u = u.reshape(nblocks * block_rows, LANES)
-
-    kern = functools.partial(_fingerprint_kernel, int(n))
+    u = jnp.asarray(x)
+    if u.dtype not in (jnp.float32, jnp.int32, jnp.uint32):
+        from repro.core.fingerprint import words_u32
+        u = words_u32(u)
+    n = int(u.size)
+    if n >= 1 << 31:
+        raise ValueError(f"fingerprint buffer of {n} words exceeds int32 "
+                         "positions; split it into leaves")
+    view = _lane_view(u)
+    rows, cols = view.shape
+    bc = _block_cols(cols)
+    br = block_rows or max(SUBLANES, BLOCK_WORDS // bc // SUBLANES * SUBLANES)
+    br = min(-(-int(br) // SUBLANES) * SUBLANES,
+             -(-rows // SUBLANES) * SUBLANES)
+    acc = jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.int32)
+    accf = jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.float32)
     h1, h2, s, a = pl.pallas_call(
-        kern,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1,), jnp.uint32),
-            jax.ShapeDtypeStruct((1,), jnp.uint32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
-        ],
+        functools.partial(_fingerprint_kernel, n, rows, cols, offset),
+        grid=(pl.cdiv(rows, br), cols // bc),
+        in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j))],
+        out_specs=[pl.BlockSpec((SUBLANES, LANES), lambda i, j: (0, 0))] * 4,
+        out_shape=[acc, acc, accf, accf],
         interpret=interpret,
-    )(u)
-    sb = jax.lax.bitcast_convert_type(s[0], jnp.uint32)
-    ab = jax.lax.bitcast_convert_type(a[0], jnp.uint32)
-    return jnp.stack([h1[0], h2[0], sb, ab])
+        name="sedar_fingerprint",
+    )(view)
+    return (jnp.sum(jax.lax.bitcast_convert_type(h1, jnp.uint32),
+                    dtype=jnp.uint32),
+            jnp.sum(jax.lax.bitcast_convert_type(h2, jnp.uint32),
+                    dtype=jnp.uint32),
+            jnp.sum(s), jnp.max(a))
+
+
+def fingerprint_pallas(x, block_rows: Optional[int] = None,
+                       interpret: Optional[bool] = None):
+    """-> (4,) uint32 [h1, h2, bits(sum), bits(absmax)], bit-identical to
+    fingerprint_ref on the hash words. Accepts any floating dtype (exact
+    upcast to f32 first, matching the oracle) or an already-packed uint32
+    buffer (hashed as-is, no bitcast)."""
+    h1, h2, s, a = fingerprint_partials(x, 0, block_rows, interpret)
+    return jnp.stack([h1, h2, jax.lax.bitcast_convert_type(s, jnp.uint32),
+                      jax.lax.bitcast_convert_type(a, jnp.uint32)])

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,10 +79,14 @@ def _flash_kernel(scale, causal, window, bq, bk, seq_k,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: (B, H, Sq, hd); k/v: (B, KV, Sk, hd) — GQA when KV < H.
 
-    Returns (B, H, Sq, hd) in q.dtype."""
+    Returns (B, H, Sq, hd) in q.dtype. `interpret=None` follows the
+    backend (interpret mode only on the CPU)."""
+    if interpret is None:
+        from repro.kernels.fingerprint import default_interpret
+        interpret = default_interpret()
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     assert H % KV == 0, (H, KV)

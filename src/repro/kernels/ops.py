@@ -1,39 +1,23 @@
-"""jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute with interpret=True (Python
-emulation of the kernel body); on TPU set REPRO_PALLAS_INTERPRET=0 (or rely
-on the backend check) to compile them for real. Block shapes stay identical
-either way, so VMEM footprints claimed by the BlockSpecs are what a TPU
-would see.
+Interpret mode (Python emulation of the kernel body) is used only on the
+CPU backend (`kernels.fingerprint.default_interpret`); on the chip every
+kernel compiles. Block shapes stay identical either way, so VMEM footprints
+claimed by the BlockSpecs are what a TPU sees.
 """
 from __future__ import annotations
 
-import jax
+from typing import Optional
+
 import jax.numpy as jnp
 
-from repro.kernels.fingerprint import default_interpret, fingerprint_pallas
+from repro.kernels.fingerprint import fingerprint_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
-_interpret = default_interpret   # back-compat alias
 
-
-def fingerprint(x, block_rows: int = 256) -> jnp.ndarray:
+def fingerprint(x, block_rows: Optional[int] = None) -> jnp.ndarray:
     """Fused fingerprint of one tensor -> (4,) uint32."""
-    return fingerprint_pallas(x, block_rows=block_rows,
-                              interpret=default_interpret())
-
-
-def fingerprint_packed(u, block_rows: int = 256) -> jnp.ndarray:
-    """Fingerprint of an already-packed u32 buffer (the fused whole-state
-    path: core.fingerprint.pack_tree_u32 -> one kernel pass) -> (4,).
-
-    Float input is bit-reinterpreted by the kernel, never value-cast."""
-    u = jnp.asarray(u)
-    if u.dtype != jnp.uint32 and not jnp.issubdtype(u.dtype, jnp.floating):
-        raise TypeError(f"fingerprint_packed expects a packed uint32 buffer "
-                        f"(or a float tensor to bitcast), got {u.dtype}")
-    return fingerprint_pallas(u, block_rows=block_rows,
-                              interpret=default_interpret())
+    return fingerprint_pallas(x, block_rows=block_rows)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -45,6 +29,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     out = flash_attention_pallas(qt, kt, vt, causal=causal, window=window,
-                                 block_q=block_q, block_k=block_k,
-                                 interpret=_interpret())
+                                 block_q=block_q, block_k=block_k)
     return out.transpose(0, 2, 1, 3)

@@ -7,49 +7,24 @@ real single device.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
+from typing import Tuple
 
 import jax
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...],
-                     devices=None):
-    """Version-compat mesh construction.
-
-    `jax.sharding.AxisType` (and the `axis_types=` kwarg of `jax.make_mesh`)
-    only exist on newer JAX; older releases (e.g. 0.4.3x) reject either.
-    Ladder: make_mesh+axis_types -> make_mesh -> plain Mesh construction.
-    All three produce an Auto-axes mesh, which is what every call site here
-    wants."""
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """An Auto-axes mesh over the first prod(shape) of `devices` (default
+    `jax.devices()`)."""
     devices = list(devices if devices is not None else jax.devices())
-    n = 1
-    for s in shape:
-        n *= s
+    n = math.prod(shape)
     if len(devices) < n:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count "
             "BEFORE any jax import (see launch/dryrun.py)")
-    devs = devices[:n]
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    make = getattr(jax, "make_mesh", None)
-    if make is not None:
-        if axis_type is not None:
-            try:
-                return make(shape, axes, devices=devs,
-                            axis_types=(axis_type.Auto,) * len(axes))
-            except TypeError:
-                pass
-        try:
-            return make(shape, axes, devices=devs)
-        except TypeError:
-            pass
-    import numpy as np
-    return jax.sharding.Mesh(np.asarray(devs).reshape(shape), axes)
-
-
-def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
-    return make_mesh_compat(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -61,10 +36,22 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
+
+
+def make_pod_mesh(devices=None):
+    """Spatial replication over the devices at hand (default
+    `jax.devices()`): ("pod", "data", "model") = (2, n/2, 1), the two
+    replicas on the "pod" axis, each over half of the devices."""
+    devices = list(devices if devices is not None else jax.devices())
+    if len(devices) < 2 or len(devices) % 2:
+        raise RuntimeError(f"pod replication needs an even number of "
+                           f"devices >= 2, found {len(devices)}")
+    return make_mesh((2, len(devices) // 2, 1), ("pod", "data", "model"),
+                     devices=devices)
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2, 2),
                    axes: Tuple[str, ...] = ("pod", "data", "model")):
     """Small mesh for CPU multi-device tests (needs forced host devices)."""
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)

@@ -1,8 +1,11 @@
 """Serving launcher: synchronous batch or continuous-batching traffic replay.
 
+Without --smoke the architecture runs at its published widths (the chip's
+path); --smoke runs the reduced per-arch config (CPU-runnable).
+
 Synchronous whole-batch decode (the original loop):
 
-    PYTHONPATH=src python -m repro.launch.serve --arch xlstm-125m \
+    PYTHONPATH=src python -m repro.launch.serve --arch xlstm-125m --smoke \
         --batch 4 --steps 16 [--dual]
 
 Continuous-batching protected serving (DESIGN.md §13) replays an open-loop
@@ -10,7 +13,7 @@ synthetic traffic trace — arrival rate, prompt-length mix, per-request
 token budgets — through the slot scheduler, optionally with a fault
 campaign injected into the decode stream:
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
         --continuous --requests 16 --slots 4 --arrival-rate 0.5 \
         --prompt-mix 4:0.5,8:0.3,16:0.2 --max-new 4,12 \
         --validate-lag 8 --backend sequential \
@@ -36,6 +39,7 @@ from repro import obs
 from repro.configs import (RunConfig, TrainConfig, get_config, list_archs,
                            reduce_for_smoke)
 from repro.core.policy import make_server
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _parse_prompt_mix(spec: str):
@@ -70,8 +74,10 @@ def _continuous(args, cfg, ob=None) -> None:
             # 1 — the corruption must land on the instance that runs, and
             # the stream visibly corrupts with nothing detecting it)
             replica = 0 if args.backend == "none" else 1
+            # the high exponent bit of a logit in the compute dtype
             spec = InjectionSpec(
-                leaf_idx=args.fault_slot, flat_idx=7, bit=30,
+                leaf_idx=args.fault_slot, flat_idx=7,
+                bit=jnp.finfo(cfg.dtype).bits - 2,
                 step=args.fault_step, replica=replica, target="slot",
                 persistent=args.fault_persistent)
     buckets = (tuple(int(b) for b in args.prefill_buckets.split(","))
@@ -162,8 +168,11 @@ def _sync(args, cfg) -> None:
             (args.batch, cfg.frontend_seq, cfg.frontend_dim), jnp.float32)
     toks, rep = srv.generate(params, prompts, steps=args.steps)
     tps = rep.tokens_emitted / max(rep.wall_s, 1e-9)
+    dev = jax.devices()[0]
     print(f"{args.arch}: {rep.tokens_emitted} tokens, {tps:.1f} tok/s "
-          f"(CPU smoke), detections={len(rep.detections)}")
+          f"({dev.platform} {dev.device_kind}"
+          f"{', smoke config' if args.smoke else ''}), "
+          f"detections={len(rep.detections)}")
 
 
 def main() -> None:
@@ -174,7 +183,8 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--dual", action="store_true",
                     help="SEDAR dual-execution detection on decode")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="run the reduced per-arch config (CPU-sized)")
     # -- continuous-batching traffic replay (DESIGN.md §13) -----------------
     ap.add_argument("--continuous", action="store_true",
                     help="slot-scheduled continuous batching with "
@@ -255,6 +265,7 @@ def main() -> None:
     if args.autotune and not (args.continuous and args.metrics_dir):
         ap.error("--autotune needs --continuous and --metrics-dir")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)

@@ -1,11 +1,14 @@
 """Training launcher.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
-        --steps 8 --level 3 [--smoke] [--replication sequential|pod|none] \
+        --steps 8 --level 3 [--smoke] \
+        [--replication sequential|fused|pod|none] \
         [--inject-step N] [--manual-vote]
 
---smoke uses the reduced per-arch config (CPU-runnable); full configs are for
-real accelerators (and are exercised shape-only via the dry-run).
+Without --smoke the architecture runs at its published widths (the chip's
+path); --smoke runs the reduced per-arch config (CPU-runnable).
+--replication pod places the two replicas on a ("pod", "data", "model") =
+(2, n/2, 1) mesh of the n devices JAX finds.
 --manual-vote runs the paper's BASELINE protocol: two independent instances,
 final comparison, third run + majority vote on mismatch (Sec. 3, Eqs. 1-2).
 
@@ -15,13 +18,15 @@ return — the run shrinks onto survivors from the last validated checkpoint,
 then regrows and replays to a state bitwise-identical with an uninterrupted
 run:
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
         --steps 12 --level 3 --elastic --n-hosts 2 \
         --lose-host 1 --lose-at 300 --return-at 700
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
+import dataclasses
 import json
 import os
 import shutil
@@ -34,6 +39,8 @@ from repro.configs import (MeshConfig, RunConfig, SedarConfig, TrainConfig,
 from repro.core.fingerprint import pytree_fingerprint
 from repro.core.injection import InjectionSpec
 from repro.core.policy import make_trainer
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_pod_mesh
 from repro.runtime.cluster import Heartbeat
 
 
@@ -41,7 +48,6 @@ def manual_vote_baseline(run_cfg: RunConfig, workdir: str, steps: int,
                          inj_spec=None) -> None:
     """Paper baseline: two instances + compare; on mismatch, a third run and
     majority vote (semi-automatic, Eqs. 1-2)."""
-    import dataclasses
     fps = []
     for inst in range(2):
         rc = dataclasses.replace(
@@ -132,11 +138,12 @@ def main() -> None:
     ap.add_argument("--ckpt-compress", action="store_true",
                     help="compress leaf payloads (np.savez_compressed); "
                          "bytes-on-disk reported in the manifest")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="run the reduced per-arch config (CPU-sized)")
     ap.add_argument("--global-batch", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=16)
     ap.add_argument("--ckpt-interval", type=int, default=4)
-    ap.add_argument("--workdir", default="/tmp/sedar_train")
+    ap.add_argument("--workdir", default="out/sedar_train")
     ap.add_argument("--inject-step", type=int, default=None)
     ap.add_argument("--manual-vote", action="store_true")
     ap.add_argument("--host-id", type=int, default=0)
@@ -182,10 +189,15 @@ def main() -> None:
                          "JSON (open at ui.perfetto.dev)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_for_smoke(cfg)
-    mesh_cfg = None
+    mesh = mesh_cfg = None
+    if args.replication == "pod":
+        mesh = make_pod_mesh()
+        mesh_cfg = MeshConfig(shape=tuple(mesh.devices.shape),
+                              axis_names=tuple(mesh.axis_names))
     if args.elastic:
         if args.level < 3:
             ap.error("--elastic requires --level 3 (a validated checkpoint "
@@ -243,8 +255,10 @@ def main() -> None:
                            slo_availability=args.slo_availability,
                            slo_goodput=args.slo_goodput))
     hb = Heartbeat(os.path.join(args.workdir, "heartbeats"), args.host_id)
-    trainer = make_trainer(rc, args.workdir, inj_spec=inj, autotune=tuner)
-    dual, rep = trainer.run(args.steps)
+    with mesh if mesh is not None else contextlib.nullcontext():
+        trainer = make_trainer(rc, args.workdir, mesh=mesh, inj_spec=inj,
+                               autotune=tuner)
+        dual, rep = trainer.run(args.steps)
     hb.beat(rep.steps_completed)
     print(rep.summary())
     for e in rep.detections:

@@ -61,7 +61,6 @@ def moe_mlp_ep(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
     explicit. Used whenever a mesh ctx is present and E % TP == 0."""
     import numpy as _np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
@@ -126,18 +125,11 @@ def moe_mlp_ep(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
         return out, aux, jax.lax.pmean(drop, token_axes)
 
     tok_spec = P(token_axes if len(token_axes) > 1 else token_axes[0], None)
-    try:
-        sm = shard_map(body, mesh=mesh,
+    sm = jax.shard_map(body, mesh=mesh,
                        in_specs=(tok_spec, P(), P(model_axis, None, None),
                                  P(model_axis, None, None),
                                  P(model_axis, None, None)),
                        out_specs=(tok_spec, P(), P()), check_vma=False)
-    except TypeError:
-        sm = shard_map(body, mesh=mesh,
-                       in_specs=(tok_spec, P(), P(model_axis, None, None),
-                                 P(model_axis, None, None),
-                                 P(model_axis, None, None)),
-                       out_specs=(tok_spec, P(), P()), check_rep=False)
     out, aux, drop = sm(x.reshape(T, D), p["router"], p["w_gate"],
                         p["w_up"], p["w_down"])
     return out.reshape(B, S, D), {"moe_aux": aux, "moe_drop_frac": drop}

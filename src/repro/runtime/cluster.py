@@ -215,10 +215,10 @@ def plan_elastic_remesh(data_axis: int, global_batch: int,
 
 
 def rebuild_mesh(shape, axes, devices=None):
-    """Version-compat mesh reconstruction for the elastic planner (the
-    AxisType shim lives in launch/mesh.py; this is the cluster-side entry)."""
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat(tuple(shape), tuple(axes), devices=devices)
+    """Mesh reconstruction for the elastic planner (the cluster-side entry
+    to `launch.mesh.make_mesh`)."""
+    from repro.launch.mesh import make_mesh
+    return make_mesh(tuple(shape), tuple(axes), devices=devices)
 
 
 def data_axis_index(mesh_cfg, name: str = "data") -> int:

@@ -391,9 +391,7 @@ class SedarServer:
         ring = SlotRing(slots_per_key=4)
         recovery = SlotRecovery(ring, max_retries=self.max_retries)
         fp_tree = self._fp_tree
-        step = self._make_packed_decode(slots)
-        if self.backend in ("sequential", "fused"):
-            step = jax.jit(step)
+        step = jax.jit(self._make_packed_decode(slots))
         eng = make_engine(
             self.cfg.sedar,
             backend=self.backend,

@@ -337,15 +337,19 @@ class SedarTrainer:
                      self.data.batch(step).items()}
             with obs.span("train_step", step=step):
                 outcome = eng.run_protected_step(dual, batch, step)
-            dual = outcome.dual
+            # unpacked and dropped: kept, the outcome would hold the
+            # pre-recovery state on the device through the next step
+            dual, aux, event = outcome.dual, outcome.aux, outcome.event
+            committed = outcome.committed
+            del outcome
             # aux is None when the executor refused the step before running
             # it (hybrid resident-state check) — there is no loss to record
-            if outcome.committed and outcome.aux is not None:
-                aux_buf.append(outcome.aux)
+            if committed and aux is not None:
+                aux_buf.append(aux)
                 step += 1
-            if outcome.event is not None:
+            if event is not None:
                 try:
-                    dual = eng.on_detection(outcome.event, dual)
+                    dual = eng.on_detection(event, dual)
                 except SedarSafeStop:
                     rep.stopped = True
                     break
@@ -353,8 +357,8 @@ class SedarTrainer:
                 # keep the loss record aligned with committed steps
                 if (eng.recoveries
                         and eng.recoveries[-1]["kind"] == "abft_correct"
-                        and outcome.aux is not None):
-                    aux_buf.append(outcome.aux)
+                        and aux is not None):
+                    aux_buf.append(aux)
                 step = self._host_step(dual)
                 truncate_to(step - step0)
             elif len(aux_buf) >= 4096 and not eng.pending_validation:

@@ -3,7 +3,9 @@
 These are the system-level analogues of the paper's Sec. 4.2 experiments:
 the recovered trajectory must be bitwise identical to a fault-free run."""
 import dataclasses
+import gc
 import shutil
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +59,33 @@ def test_l3_tdc_single_rollback_bitexact(tmp_workdir):
     assert rep.recoveries[0]["kind"] == "restore"
     assert rep.recoveries[0]["rollbacks"] == 1          # Alg. 2: at most one
     assert np.array_equal(rep.final_state_fp[:, :2], clean[:, :2])
+
+
+def test_recovery_drops_the_pre_recovery_state(tmp_workdir):
+    """The training loop holds no reference to the state a recovery
+    replaced: the next step runs with the restored state and the ring
+    copy alone (a third stacked copy is what does not fit the chip)."""
+    spec = InjectionSpec(leaf_idx=3, flat_idx=5, bit=20, step=2, replica=1,
+                         target="grads")
+    tr = _trainer(tmp_workdir, 2, inj=spec, replication="fused",
+                  ckpt_tiers="device", device_ring_slots=1)
+    run_step = tr.engine.run_protected_step
+    replaced, checked = [], []
+
+    def watched(dual, batch, step):
+        gc.collect()
+        if replaced:
+            checked.append([r() is None for r in replaced])
+            replaced.clear()
+        out = run_step(dual, batch, step)
+        if out.event is not None:
+            replaced.extend(weakref.ref(x) for x in jax.tree.leaves(out.dual))
+        return out
+
+    tr.engine.run_protected_step = watched
+    _, rep = tr.run(4)
+    assert [e.step for e in rep.detections] == [2]
+    assert checked and all(all(c) for c in checked), checked
 
 
 def test_l2_dirty_checkpoint_double_rollback(tmp_workdir):

@@ -5,7 +5,10 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.fingerprint import (fingerprints_equal, pytree_fingerprint,
+from repro.core.fingerprint import (fingerprints_equal, pack_tree_u32,
+                                    packed_fingerprint, pytree_fingerprint,
+                                    pytree_fingerprint_fused,
+                                    pytree_fingerprint_lanes,
                                     tensor_fingerprint)
 from repro.kernels import ops, ref
 
@@ -89,3 +92,38 @@ def test_kernel_block_size_invariance():
     a = np.asarray(ops.fingerprint(x, block_rows=8))[:2]
     b = np.asarray(ops.fingerprint(x, block_rows=16))[:2]
     assert np.array_equal(a, b)
+
+
+def _mixed_tree():
+    rs = np.random.RandomState(2)
+    return {"a": jnp.asarray(rs.randn(5).astype(np.float32)),
+            "b": jnp.asarray(rs.randn(40, 128).astype(np.float32)),
+            "c": jnp.asarray(rs.randn(3, 12, 256).astype(np.float32)),
+            "d": jnp.asarray(rs.randn(37, 2688).astype(np.float32)),
+            "e": jnp.asarray(rs.randn(6, 7).astype(np.float32)
+                             ).astype(jnp.bfloat16),
+            "f": jnp.arange(7, dtype=jnp.int32),
+            "g": jnp.zeros((0,), jnp.float32),
+            "h": jnp.float32(3.5)}
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_fused_fingerprint_equals_packed_reference(use_pallas):
+    """The in-place fused hash equals the hash of the packed copy."""
+    tree = _mixed_tree()
+    got = np.asarray(pytree_fingerprint_fused(tree, use_pallas=use_pallas))
+    want = np.asarray(packed_fingerprint(pack_tree_u32(tree)))
+    np.testing.assert_array_equal(got[:2], want[:2])
+
+
+@pytest.mark.parametrize("n_lanes", [1, 2, 3, 7, 64])
+def test_lane_fingerprints_equal_padded_packed_reference(n_lanes):
+    """Lane i is the hash of words [i*W, (i+1)*W) of the zero-padded
+    packed state, computed without packing it."""
+    tree = _mixed_tree()
+    u = pack_tree_u32(tree)
+    width = -(-u.shape[0] // n_lanes)
+    u = jnp.pad(u, (0, n_lanes * width - u.shape[0]))
+    want = np.asarray(jax.vmap(packed_fingerprint)(u.reshape(n_lanes, width)))
+    got = np.asarray(pytree_fingerprint_lanes(tree, n_lanes))
+    np.testing.assert_array_equal(got[:, :2], want[:, :2])

@@ -241,8 +241,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.dryrun import (parse_collective_bytes,
                                  parse_collective_bytes_loopaware)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"), devices=jax.devices())
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"), devices=jax.devices())
 def step(w, x):
     def body(c, wl):
         h = jnp.einsum('bd,de->be', c, wl)

@@ -300,6 +300,28 @@ def test_l2_deferred_window_fault_restores_from_ring(tmp_workdir):
         np.asarray(ref.executor.peek(dual_ref, "x")))
 
 
+def test_fused_step_donates_state_and_ring_keeps_its_copies(tmp_workdir):
+    """The fused executor donates its stacked state on every backend, so
+    the CPU runs the chip's aliasing: each step deletes the previous
+    state, and every version the device ring holds stays restorable."""
+    eng = _toy_engine(tmp_workdir, 2, backend="fused", tiers="device",
+                      slots=4)
+    dual = eng.init_dual()
+    eng.reset()
+    for step in range(4):
+        before = dual["s"]["x"]
+        dual = eng.run_protected_step(
+            dual, jnp.full((16,), float(step + 1), jnp.float32), step).dual
+        assert before.is_deleted()
+    ring = eng.recovery.tiers.device
+    assert ring.versions() == [1, 2, 3, 4]
+    for version in ring.versions():
+        got = ring.restore(version)
+        assert np.isfinite(np.asarray(got["s"]["x"])).all()
+    np.testing.assert_array_equal(np.asarray(ring.restore(4)["s"]["x"]),
+                                  np.asarray(dual["s"]["x"]))
+
+
 def test_l2_ring_too_short_falls_to_disk(tmp_workdir):
     """With a 1-slot ring at a cadence that leaves no slot <= k, the
     planner falls through to the disk tier (and recovery still succeeds)."""

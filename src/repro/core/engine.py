@@ -577,6 +577,11 @@ class FusedSequentialExecutor(ReplicaExecutor):
             fps = jax.vmap(self.fast_state_fp_fn)(stacked)
             return fingerprints_equal(fps[0], fps[1])
 
+        # fixed program names (`jit_sedar_fused_decode_step` when serving):
+        # the profiler's `XLA Modules` line names each launch by them
+        step = getattr(step_fn, "__name__", "step").removeprefix("sedar_")
+        _gated.__name__ = _gated.__qualname__ = f"sedar_fused_{step}"
+        _validate.__name__ = _validate.__qualname__ = "sedar_fused_validate"
         # the same donation on every backend: the CPU tests run the aliasing
         # the chip runs, so a stale reference to a donated buffer fails there
         self._step_gated = jax.jit(_gated,
@@ -842,6 +847,14 @@ class VoteExecutor(PodExecutor):
 # The engine
 # ---------------------------------------------------------------------------
 
+def _deliver(emis, vals) -> None:
+    """Hand a flushed token window, already on the host, to the emission
+    ring's sink (the `token_deliver` span: the host work after the flush's
+    readback, while the device waits for the next step)."""
+    with obs.span("token_deliver", rows=len(emis)):
+        emis.deliver(vals)
+
+
 class SedarEngine:
     """Composes executor × schedule × recovery × watchdog × injection behind
     `run_protected_step()` + `on_detection()` (DESIGN.md §1).
@@ -1082,7 +1095,7 @@ class SedarEngine:
                 # proven clean by an earlier flush — pure delivery
                 with obs.span("token_drain", rows=len(emis)):
                     vals = hostsync.batched_get(drain, label="token_emit")
-                emis.deliver(vals)
+                _deliver(emis, vals)
             return None
         steps_, preds = zip(*self._ring)
         drain_vals = None
@@ -1102,7 +1115,7 @@ class SedarEngine:
             self.validated_frontier = steps_[-1] + 1
             self._ring.clear()
             if drain_vals is not None:
-                emis.deliver(drain_vals)
+                _deliver(emis, drain_vals)
             return None
         vals = hostsync.batched_get(list(preds), label="deferred_ring")
         bad = [s for s, v in zip(steps_, vals) if not bool(np.all(v))]
@@ -1127,7 +1140,7 @@ class SedarEngine:
         if emis is not None:
             emis.truncate(slot_first, global_bad=bad[0])
             if drain_vals is not None:
-                emis.deliver(drain_vals)
+                _deliver(emis, drain_vals)
         return DetectionEvent(step=bad[0], boundary="deferred", effect="TDC",
                               detail=detail)
 

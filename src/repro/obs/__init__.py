@@ -55,7 +55,7 @@ __all__ = [
     "note_detection", "note_recovery", "note_checkpoint",
     "note_tier_save", "note_tier_restore", "note_tier_event",
     "note_rejection", "note_heartbeat_anomaly", "note_tokens",
-    "note_drain", "note_alert", "note_reconfig",
+    "note_alert", "note_reconfig",
     "Observability",
 ]
 
@@ -265,13 +265,6 @@ def note_heartbeat_anomaly(host_id: int, gap_s: float,
 def note_tokens(n: int) -> None:
     if _metrics_on and n:
         metrics.inc("serve_tokens_emitted_total", n)
-
-
-def note_drain(rows: int) -> None:
-    """One lag-aligned emission-ring drain batch (DESIGN.md §18)."""
-    if _metrics_on:
-        metrics.inc("serve_drain_batches_total")
-        metrics.inc("serve_drained_rows_total", rows)
 
 
 def note_alert(record: Dict[str, Any]) -> None:

@@ -2,19 +2,35 @@
 
 Spans cover the protected pipeline's host-visible stages — prefill pack,
 decode tick, train step, deferred flush, validate, checkpoint (per tier),
-rollback, restore plan — as "X" (complete) events. Load the output at
-https://ui.perfetto.dev or chrome://tracing.
+rollback, restore plan, and each host stage of a serving call (start,
+admission, slot snapshots, slot release, token delivery, finish) — as "X"
+(complete) events. Load the output at https://ui.perfetto.dev or
+chrome://tracing.
 
 Timing uses `time.monotonic()` only: a span brackets work the host was
 already blocking on, so tracing adds zero device syncs by construction.
+
+Each span also enters `jax.profiler.TraceAnnotation(name)`, so a JAX
+profiler running at the same time shows every span by name in its host
+plane, on the same clock as the device's operations. jax is imported when
+the first recorder is made, so `repro.obs` stays importable without it.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation`, or a no-op where jax is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return lambda name: nullcontext()
+    return TraceAnnotation
 
 
 class TraceRecorder:
@@ -22,12 +38,14 @@ class TraceRecorder:
         self.events: List[Dict[str, Any]] = []
         self._t0 = time.monotonic()
         self._lock = threading.Lock()
+        self._annotate = _profiler_annotation()
 
     @contextmanager
     def span(self, name: str, cat: str = "sedar", **args):
         start = time.monotonic()
         try:
-            yield
+            with self._annotate(name):
+                yield
         finally:
             end = time.monotonic()
             ev = {

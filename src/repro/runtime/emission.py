@@ -157,7 +157,6 @@ class TokenRing:
             dead_all=[e.dead_all for e in self._entries])
         self._entries.clear()
         self.drains += 1
-        obs.note_drain(len(batch.steps))
         if self.sink is not None:
             self.sink(batch)
         else:
@@ -247,8 +246,6 @@ class DetokenizeConsumer:
         depth = self._q.qsize()
         if depth > self.backlog_peak:
             self.backlog_peak = depth
-        if obs.metrics_enabled():
-            obs.metrics.set_gauge("sedar_serve_consumer_backlog", depth)
 
     def _consume(self, batch: DrainBatch) -> None:
         with obs.span("detokenize", rows=len(batch.steps)):
@@ -268,9 +265,6 @@ class DetokenizeConsumer:
                 self.errors.append(exc)
             finally:
                 self._q.task_done()
-                if obs.metrics_enabled():
-                    obs.metrics.set_gauge("sedar_serve_consumer_backlog",
-                                          self._q.qsize())
 
     def quiesce(self) -> None:
         """Block until every submitted batch has been fully delivered."""

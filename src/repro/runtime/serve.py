@@ -110,8 +110,11 @@ class BatchServeReport:
         return self.tokens_emitted / max(self.steps, 1)
 
 
+# The serving loop's programs carry fixed names: jit names each module
+# `jit_<function name>`, and the profiler's `XLA Modules` line shows it.
+
 @jax.jit
-def _slot_write_jit(state, slot, cache_sl, tok_sl, pos_sl, active):
+def sedar_slot_write(state, slot, cache_sl, tok_sl, pos_sl, active):
     """One fused scatter of a slot slice into the packed state (dynamic
     slot index). Jitted module-level so admissions/rollbacks cost one
     dispatch per replica instead of one per cache leaf."""
@@ -125,16 +128,16 @@ def _slot_write_jit(state, slot, cache_sl, tok_sl, pos_sl, active):
 
 
 @jax.jit
-def _set_active_jit(state, slot, value):
+def sedar_set_active(state, slot, value):
     return {**state, "active": state["active"].at[slot].set(value)}
 
 
 @jax.jit
-def _pack_insert_jit(state, slots, sel, rows, toks, poss):
+def sedar_pack_insert(state, slots, sel, rows, toks, poss):
     """Vectorized admission scatter: pack rows `sel` of a protected prefill
     launch land in slots `slots` of the packed state in ONE fused program
     (maxtext's prefill_insert_batch shape) — cache rows, first tokens,
-    positions and the active mask together, instead of one `_slot_write_jit`
+    positions and the active mask together, instead of one `sedar_slot_write`
     dispatch per admitted request."""
     cache = jax.tree.map(
         lambda full, r: full.at[slots].set(r[sel].astype(full.dtype)),
@@ -147,7 +150,7 @@ def _pack_insert_jit(state, slots, sel, rows, toks, poss):
 
 
 @jax.jit
-def _slot_slice_jit(cache, tok, pos, slot):
+def sedar_slot_slice(cache, tok, pos, slot):
     """Extract one slot's {cache, tok, pos} image (Tier-0 snapshot source)."""
     return {"cache": jax.tree.map(lambda x: x[slot], cache),
             "tok": tok[slot], "pos": pos[slot]}
@@ -194,7 +197,15 @@ class SedarServer:
         fp_tree = ((lambda s: {"cache": s["cache"], "tok": s["tok"]})
                    if backend in ("abft", "hybrid")
                    else (lambda s: {"tok": s["tok"]}))
-        self._fp_tree = fp_tree
+
+        def sedar_state_fp(s):
+            return pytree_fingerprint(fp_tree(s))
+
+        def sedar_state_fp_fused(s):
+            return pytree_fingerprint_fused(fp_tree(s))
+
+        self._state_fp = jax.jit(sedar_state_fp)
+        self._fast_state_fp = jax.jit(sedar_state_fp_fused)
         # continuous-batching engines, keyed (slots, max_len, lag): the
         # packed decode program depends on all three, and reusing the
         # engine across serve() calls reuses its jit cache
@@ -204,9 +215,8 @@ class SedarServer:
             run_cfg.sedar,
             backend=backend,
             step_fn=self._decode,
-            state_fp_fn=jax.jit(lambda s: pytree_fingerprint(fp_tree(s))),
-            fast_state_fp_fn=jax.jit(lambda s: pytree_fingerprint_fused(
-                fp_tree(s))),
+            state_fp_fn=self._state_fp,
+            fast_state_fp_fn=self._fast_state_fp,
             schedule=BoundarySchedule(
                 commit_interval=1, validate_interval=fsc_interval,
                 checkpoint_interval=0,
@@ -340,7 +350,7 @@ class SedarServer:
         abft_guard = self.backend in ("abft", "hybrid")
         model = self.model
 
-        def step(state, params, replica_id, armed):
+        def sedar_decode_step(state, params, replica_id, armed):
             t = state["t"]
             if spec is not None and spec.target not in (
                     "kernel", "slot", "prefill", "prefill_kernel"):
@@ -380,7 +390,7 @@ class SedarServer:
                 return cand, fp, (tok, cand["pos"]), report
             return cand, fp, (tok, cand["pos"])
 
-        return step
+        return sedar_decode_step
 
     def _batch_engine(self, slots: int, max_len: int, lag: int
                       ) -> Tuple[SedarEngine, Any, SlotRecovery]:
@@ -390,15 +400,13 @@ class SedarServer:
         from repro.checkpoint.tiers import SlotRing
         ring = SlotRing(slots_per_key=4)
         recovery = SlotRecovery(ring, max_retries=self.max_retries)
-        fp_tree = self._fp_tree
         step = jax.jit(self._make_packed_decode(slots))
         eng = make_engine(
             self.cfg.sedar,
             backend=self.backend,
             step_fn=step,
-            state_fp_fn=jax.jit(lambda s: pytree_fingerprint(fp_tree(s))),
-            fast_state_fp_fn=jax.jit(
-                lambda s: pytree_fingerprint_fused(fp_tree(s))),
+            state_fp_fn=self._state_fp,
+            fast_state_fp_fn=self._fast_state_fp,
             schedule=BoundarySchedule(
                 commit_interval=1, validate_interval=self._fsc_interval,
                 checkpoint_interval=0,
@@ -423,8 +431,8 @@ class SedarServer:
         pos_sl = jnp.asarray(sl["pos"])
         act = jnp.asarray(active, jnp.bool_)
         dual = eng.executor.map_state(
-            lambda st: _slot_write_jit(st, slot_d, cache_sl, tok_sl,
-                                       pos_sl, act), dual)
+            lambda st: sedar_slot_write(st, slot_d, cache_sl, tok_sl,
+                                        pos_sl, act), dual)
         eng.executor.note_external_update()
         return dual
 
@@ -432,17 +440,18 @@ class SedarServer:
         slot_d = jnp.asarray(slot, jnp.int32)
         val = jnp.asarray(value, jnp.bool_)
         dual = eng.executor.map_state(
-            lambda st: _set_active_jit(st, slot_d, val), dual)
+            lambda st: sedar_set_active(st, slot_d, val), dual)
         eng.executor.note_external_update()
         return dual
 
     def _slot_slice(self, eng, dual, slot: int):
-        return _slot_slice_jit(eng.executor.peek(dual, "cache"),
-                               eng.executor.peek(dual, "tok"),
-                               eng.executor.peek(dual, "pos"),
-                               jnp.asarray(slot, jnp.int32))
+        return sedar_slot_slice(eng.executor.peek(dual, "cache"),
+                                eng.executor.peek(dual, "tok"),
+                                eng.executor.peek(dual, "pos"),
+                                jnp.asarray(slot, jnp.int32))
 
-    def _snapshot_slots(self, eng, dual, sched, ring, version: int) -> None:
+    def _snapshot_slots(self, eng, dual, sched, ring, version: int,
+                        slot_arrays: int) -> None:
         """Tier-0 per-slot snapshots at the deferred-validation cadence:
         every RUNNING slot's {cache, tok, pos} image enters its keyed
         device ring right after a clean flush — pure `jnp.copy`, zero disk
@@ -450,11 +459,16 @@ class SedarServer:
         per-request checkpointing, asserted by tests). One `save_many`
         batch per flush: the snapshot versions land exactly on the drain
         edges the emission ring delivers at, so a rollback target never
-        predates a delivered token (DESIGN.md §18)."""
-        slices = {slot: self._slot_slice(eng, dual, slot)
-                  for slot, _req in sched.running_items()}
-        if slices:
-            ring.save_many(version, slices)
+        predates a delivered token (DESIGN.md §18). `slot_arrays` is the
+        number of device arrays in one slot's image."""
+        running = sched.running_items()
+        if not running:
+            return
+        with obs.span("slot_snapshot", at="flush", step=version,
+                      slots=len(running), arrays=len(running) * slot_arrays):
+            ring.save_many(version, {
+                slot: self._slot_slice(eng, dual, slot)
+                for slot, _req in running})
 
     def _admit_slot(self, eng, dual, params, slot: int, req, t: int,
                     ring, ring_on: bool, max_len: int):
@@ -472,7 +486,9 @@ class SedarServer:
         ring.evict(slot)           # never resurrect a previous tenant
         dual = self._write_slot(eng, dual, slot, sl, active=True)
         if ring_on:
-            ring.save(slot, t, sl)
+            with obs.span("slot_snapshot", at="admit", step=t, slots=1,
+                          arrays=len(jax.tree.leaves(sl))):
+                ring.save(slot, t, sl)
         req.pos0 = req.prompt_len
         # the prefill token is single-execution (like generate()): the
         # replica-validated stream starts at the first decode step
@@ -517,16 +533,20 @@ class SedarServer:
                 sel = jnp.asarray(good, jnp.int32)
                 slots_d = jnp.asarray([pairs[i][0] for i in good], jnp.int32)
                 dual = eng.executor.map_state(
-                    lambda st: _pack_insert_jit(st, slots_d, sel, rows,
-                                                toks_d, poss), dual)
+                    lambda st: sedar_pack_insert(st, slots_d, sel, rows,
+                                                 toks_d, poss), dual)
                 eng.executor.note_external_update()
                 if ring_on:
-                    ring.save_many(t, {
-                        pairs[i][0]: {
-                            "cache": jax.tree.map(
-                                lambda x, j=i: x[j], rows),
-                            "tok": toks_d[i], "pos": poss[i]}
-                        for i in good})
+                    with obs.span("slot_snapshot", at="admit", step=t,
+                                  slots=len(good),
+                                  arrays=len(good)
+                                  * (len(jax.tree.leaves(rows)) + 2)):
+                        ring.save_many(t, {
+                            pairs[i][0]: {
+                                "cache": jax.tree.map(
+                                    lambda x, j=i: x[j], rows),
+                                "tok": toks_d[i], "pos": poss[i]}
+                            for i in good})
                 now_wall = time.time()
                 for i in good:
                     _slot, req = pairs[i]
@@ -585,10 +605,28 @@ class SedarServer:
         req = sched.release(slot)
         rep.completed.append(req.rid)
 
+    @staticmethod
+    def _validated(eng, sched) -> List[int]:
+        """Draining slots whose last step a flush has validated."""
+        return [slot for slot, req in sched.draining_items()
+                if eng.validated_frontier >= req.finish_step]
+
+    @classmethod
+    def _releasable(cls, eng, sched) -> List[int]:
+        """Draining slots to release once no slot runs: the validated ones
+        and, when no predicate awaits validation, every other drainer too
+        (quiescence: their evidence either flushed clean or was consumed by
+        an event that did not implicate them — nothing will ever re-examine
+        them, and holding them would spin forever)."""
+        ready = cls._validated(eng, sched)
+        if not eng.pending_validation:
+            ready += [slot for slot, _req in sched.draining_items()
+                      if slot not in ready]
+        return ready
+
     def _release_drained(self, eng, sched, rep: BatchServeReport) -> None:
-        for slot, req in list(sched.draining_items()):
-            if eng.validated_frontier >= req.finish_step:
-                self._finish(sched, slot, rep)
+        for slot in self._validated(eng, sched):
+            self._finish(sched, slot, rep)
 
     def _handle_event(self, eng, recovery, sched, ring, event, dual,
                       rep: BatchServeReport, notify=None, expected=None,
@@ -682,7 +720,7 @@ class SedarServer:
         call."""
         from repro.runtime.emission import DetokenizeConsumer, TokenRing
         from repro.runtime.prefill import group_packs
-        from repro.runtime.scheduler import (DRAINING, RUNNING, RequestQueue,
+        from repro.runtime.scheduler import (RUNNING, RequestQueue,
                                              SlotScheduler)
         if self.cfg.model.frontend:
             raise NotImplementedError(
@@ -690,62 +728,69 @@ class SedarServer:
                 "(VLM/audio) prompts need per-request embed plumbing")
         rep = BatchServeReport()
         t0 = time.time()
-        for r in requests:
-            r.status, r.slot = "pending", None
-            r.tokens, r.token_times = [], []
-            r.pos0, r.admit_step, r.finish_step = 0, None, None
-            r.truncated_tokens, r.reject_reason = 0, ""
-            r.arrival_time = None
-        max_prompt = max((r.prompt_len for r in requests), default=8)
-        max_new = max((r.max_new_tokens for r in requests), default=8)
-        max_len = max_len or (max_prompt + max_new + 8)
-        lag = int(validate_lag
-                  if validate_lag is not None
-                  else getattr(self.cfg.sedar, "validate_lag", 1))
-        eng, ring, recovery = self._batch_engine(slots, max_len, max(lag, 1))
-        eng.reset()
-        recovery.reset()
-        self.inj_flag.reset()
-        recovery.merge = lambda dual, slot, sl: self._write_slot(
-            eng, dual, slot, sl, active=True)
-        ring_on = eng.validate_lag > 1   # clamped lag => pre-commit gating
-        # lag-aligned batched drain (DESIGN.md §18): tokens leave the
-        # device through flush_deferred's fused readback and reach the
-        # request streams via the consumer thread. Per-tick emission
-        # survives as `drain_cadence=1` (and as the only mode at lag 1,
-        # where every commit is already a sync point).
-        drain_on = ring_on and (drain_cadence is None
-                                or int(drain_cadence) > 1)
-        tokring = consumer = None
-        expected: Dict[int, int] = {}   # slot -> optimistic token count
-        if drain_on:
-            consumer = DetokenizeConsumer(on_token=on_token,
-                                          max_queue=consumer_depth).start()
-            tokring = TokenRing(
-                cadence=(int(drain_cadence) if drain_cadence
-                         else eng.validate_lag),
-                sink=consumer.submit)
-            eng.emission_ring = tokring
+        # every host stage below opens its own span only when it has work;
+        # no span covers a whole call, the tick loop or a whole tick
+        with obs.span("serve_start", slots=slots,
+                      requests=len(requests)):
+            for r in requests:
+                r.status, r.slot = "pending", None
+                r.tokens, r.token_times = [], []
+                r.pos0, r.admit_step, r.finish_step = 0, None, None
+                r.truncated_tokens, r.reject_reason = 0, ""
+                r.arrival_time = None
+            max_prompt = max((r.prompt_len for r in requests), default=8)
+            max_new = max((r.max_new_tokens for r in requests), default=8)
+            max_len = max_len or (max_prompt + max_new + 8)
+            lag = int(validate_lag
+                      if validate_lag is not None
+                      else getattr(self.cfg.sedar, "validate_lag", 1))
+            eng, ring, recovery = self._batch_engine(slots, max_len,
+                                                     max(lag, 1))
+            eng.reset()
+            recovery.reset()
+            self.inj_flag.reset()
+            recovery.merge = lambda dual, slot, sl: self._write_slot(
+                eng, dual, slot, sl, active=True)
+            ring_on = eng.validate_lag > 1   # clamped lag => pre-commit gate
+            # lag-aligned batched drain (DESIGN.md §18): tokens leave the
+            # device through flush_deferred's fused readback and reach the
+            # request streams via the consumer thread. Per-tick emission
+            # survives as `drain_cadence=1` (and as the only mode at lag 1,
+            # where every commit is already a sync point).
+            drain_on = ring_on and (drain_cadence is None
+                                    or int(drain_cadence) > 1)
+            tokring = consumer = None
+            expected: Dict[int, int] = {}   # slot -> optimistic token count
+            if drain_on:
+                consumer = DetokenizeConsumer(on_token=on_token,
+                                              max_queue=consumer_depth).start()
+                tokring = TokenRing(
+                    cadence=(int(drain_cadence) if drain_cadence
+                             else eng.validate_lag),
+                    sink=consumer.submit)
+                eng.emission_ring = tokring
 
-        sched = SlotScheduler(slots, RequestQueue(queue_depth))
-        pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
-        cache1, _ = self.model.init_cache(1, max_len)
-        state = {"cache": jax.tree.map(
-                     lambda x: jnp.stack([x] * slots), cache1),
-                 "tok": jnp.zeros((slots, 1), jnp.int32),
-                 "pos": jnp.zeros((slots,), jnp.int32),
-                 "active": jnp.zeros((slots,), jnp.bool_),
-                 "t": jnp.asarray(0, jnp.int32)}
-        dual = eng.executor.init_dual(state)
+            sched = SlotScheduler(slots, RequestQueue(queue_depth))
+            pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
+            cache1, _ = self.model.init_cache(1, max_len)
+            state = {"cache": jax.tree.map(
+                         lambda x: jnp.stack([x] * slots), cache1),
+                     "tok": jnp.zeros((slots, 1), jnp.int32),
+                     "pos": jnp.zeros((slots,), jnp.int32),
+                     "active": jnp.zeros((slots,), jnp.bool_),
+                     "t": jnp.asarray(0, jnp.int32)}
+            # device arrays in one slot's snapshot image: cache, tok, pos
+            slot_arrays = len(jax.tree.leaves(state["cache"])) + 2
+            dual = eng.executor.init_dual(state)
 
-        # packed_prefill=False keeps the legacy one-launch-per-request
-        # admission — the equality oracle (and bench baseline) for the
-        # bucketed pack path
-        use_packed = packed_prefill and self.prefiller.supported
-        prefill_events: List[DetectionEvent] = []
-        t = 0
-        cap = max_steps or (sum(r.max_new_tokens for r in requests)
-                            + len(requests)) * 4 + 64
+            # packed_prefill=False keeps the legacy one-launch-per-request
+            # admission — the equality oracle (and bench baseline) for the
+            # bucketed pack path
+            use_packed = packed_prefill and self.prefiller.supported
+            prefill_events: List[DetectionEvent] = []
+            t = 0
+            cap = max_steps or (sum(r.max_new_tokens for r in requests)
+                                + len(requests)) * 4 + 64
         while t < cap and (pending or len(sched.queue) or sched.busy):
             # the autotuner may have moved the lag at the last boundary
             ring_on = eng.validate_lag > 1
@@ -755,36 +800,35 @@ class SedarServer:
                 if not sched.queue.offer(req):
                     rep.rejected.append(req.rid)   # backpressure shed
             pairs = sched.admit(t)
-            if pairs and use_packed:
-                packs, overflow = group_packs(
+            if pairs:
+                packs, overflow = (group_packs(
                     pairs, [req.prompt_len for _, req in pairs],
                     self.prefiller.usable_buckets(max_len),
-                    self.prefiller.max_pack)
-                for _bucket, chunk in packs:
-                    dual = self._admit_pack(eng, dual, params, chunk, t,
-                                            ring, ring_on, max_len, rep,
-                                            sched, notify_reject,
-                                            prefill_events)
-                for slot, req in overflow:   # longer than the ladder
-                    dual = self._admit_slot(eng, dual, params, slot, req, t,
-                                            ring, ring_on, max_len)
-            else:
-                for slot, req in pairs:
-                    dual = self._admit_slot(eng, dual, params, slot, req, t,
-                                            ring, ring_on, max_len)
-            for slot, req in pairs:
-                if req.status == RUNNING and drain_on:
-                    # the prefill token was validated and delivered at
-                    # admission; the optimistic count starts there
-                    expected[slot] = 1
-                if (req.status == RUNNING
-                        and len(req.tokens) >= req.max_new_tokens):
-                    # budget of 1: the prefill token already fills it —
-                    # its validation (if any) happened at admission, so
-                    # release immediately
-                    dual = self._set_active(eng, dual, slot, False)
-                    sched.drain(slot, finish_step=t)
-                    self._finish(sched, slot, rep)
+                    self.prefiller.max_pack) if use_packed else ([], pairs))
+                with obs.span("admit", step=t, admitted=len(pairs),
+                              packs=len(packs), overflow=len(overflow)):
+                    for _bucket, chunk in packs:
+                        dual = self._admit_pack(eng, dual, params, chunk, t,
+                                                ring, ring_on, max_len, rep,
+                                                sched, notify_reject,
+                                                prefill_events)
+                    # longer than the ladder, or the legacy path
+                    for slot, req in overflow:
+                        dual = self._admit_slot(eng, dual, params, slot, req,
+                                                t, ring, ring_on, max_len)
+                    for slot, req in pairs:
+                        if req.status == RUNNING and drain_on:
+                            # the prefill token was validated and delivered
+                            # at admission; the optimistic count starts there
+                            expected[slot] = 1
+                        if (req.status == RUNNING
+                                and len(req.tokens) >= req.max_new_tokens):
+                            # budget of 1: the prefill token already fills
+                            # it — its validation (if any) happened at
+                            # admission, so release immediately
+                            dual = self._set_active(eng, dual, slot, False)
+                            sched.drain(slot, finish_step=t)
+                            self._finish(sched, slot, rep)
             if not sched.running_items():
                 if sched.draining_items():
                     ev = eng.flush_deferred()
@@ -794,16 +838,12 @@ class SedarServer:
                             notify_reject,
                             expected=expected if drain_on else None,
                             consumer=consumer)
-                    self._release_drained(eng, sched, rep)
-                    # quiescence: no runners, no parked predicates — the
-                    # remaining drainers were never proven bad (their
-                    # evidence either flushed clean or was consumed by an
-                    # event that did not implicate them) and nothing will
-                    # ever re-examine them; holding them would spin forever
-                    if not eng.pending_validation and \
-                            not sched.running_items():
-                        for slot, _req in list(sched.draining_items()):
-                            self._finish(sched, slot, rep)
+                    ready = self._releasable(eng, sched)
+                    if ready:
+                        with obs.span("slot_release", step=t, drained=0,
+                                      released=len(ready)):
+                            for slot in ready:
+                                self._finish(sched, slot, rep)
                     continue
                 if pending or len(sched.queue):
                     # idle tick awaiting arrivals: advance the DEVICE decode
@@ -839,7 +879,8 @@ class SedarServer:
                     consumer=consumer)
             elif ring_on and not eng.pending_validation:
                 # clean flush boundary: cut the Tier-0 per-slot snapshots
-                self._snapshot_slots(eng, dual, sched, ring, version=t + 1)
+                self._snapshot_slots(eng, dual, sched, ring, t + 1,
+                                     slot_arrays)
             if autotune is not None:
                 autotune.maybe_tune(eng, t + 1)
                 if drain_on and eng.validate_lag == 1:
@@ -856,70 +897,77 @@ class SedarServer:
                 # count, tokens surface through the consumer at the drain
                 # cadence, and drained slots release when a flush moved
                 # the validated frontier past their finish step
-                for slot, req in sched.running_items():
-                    if expected.get(slot, 1) >= req.max_new_tokens:
-                        sched.drain(slot, finish_step=t + 1)
-                        dual = self._set_active(eng, dual, slot, False)
-                if not eng.pending_validation:
-                    self._release_drained(eng, sched, rep)
+                done = [slot for slot, req in sched.running_items()
+                        if expected.get(slot, 1) >= req.max_new_tokens]
+                for slot in done:
+                    sched.drain(slot, finish_step=t + 1)
+                ready = ([] if eng.pending_validation
+                         else self._validated(eng, sched))
+                if done or ready:
+                    with obs.span("slot_release", step=t, drained=len(done),
+                                  released=len(ready)):
+                        for slot in done:
+                            dual = self._set_active(eng, dual, slot, False)
+                        for slot in ready:
+                            self._finish(sched, slot, rep)
             else:
                 # per-tick emission (lag 1, or drain_cadence=1 baseline):
                 # tok + pos fetched in a single transfer batch; per-slot
                 # position deltas drive emission, so partial commits
                 # (faulty slot frozen) and rollbacks (position regressed)
                 # need no special-casing here
-                toks, poss = hostsync.batched_get(
-                    [eng.executor.peek(dual, "tok"),
-                     eng.executor.peek(dual, "pos")], label="token_emit")
-                now_wall = time.time()
-                for slot, req in sched.running_items():
-                    target = int(poss[slot]) - req.pos0 + 1
-                    if target == len(req.tokens) + 1:
-                        req.tokens.append(int(toks[slot, 0]))
-                        req.token_times.append(now_wall)
-                        obs.note_tokens(1)
-                        if on_token is not None:
-                            on_token(req, req.tokens[-1],
-                                     len(req.tokens) - 1)
-                    if len(req.tokens) >= req.max_new_tokens:
-                        sched.drain(slot, finish_step=t + 1)
-                        dual = self._set_active(eng, dual, slot, False)
-                        if eng.validate_lag == 1:
-                            # immediate mode: every emitted token passed
-                            # the commit gate (emission follows committed
-                            # position deltas), so the stream is already
-                            # validated even if ANOTHER slot's event kept
-                            # the global frontier behind — release now
-                            self._finish(sched, slot, rep)
-                self._release_drained(eng, sched, rep)
+                running = sched.running_items()
+                with obs.span("token_emit", step=t, slots=len(running)):
+                    toks, poss = hostsync.batched_get(
+                        [eng.executor.peek(dual, "tok"),
+                         eng.executor.peek(dual, "pos")], label="token_emit")
+                    now_wall = time.time()
+                    for slot, req in running:
+                        target = int(poss[slot]) - req.pos0 + 1
+                        if target == len(req.tokens) + 1:
+                            req.tokens.append(int(toks[slot, 0]))
+                            req.token_times.append(now_wall)
+                            obs.note_tokens(1)
+                            if on_token is not None:
+                                on_token(req, req.tokens[-1],
+                                         len(req.tokens) - 1)
+                        if len(req.tokens) >= req.max_new_tokens:
+                            sched.drain(slot, finish_step=t + 1)
+                            dual = self._set_active(eng, dual, slot, False)
+                            if eng.validate_lag == 1:
+                                # immediate mode: every emitted token passed
+                                # the commit gate (emission follows committed
+                                # position deltas), so the stream is already
+                                # validated even if ANOTHER slot's event kept
+                                # the global frontier behind — release now
+                                self._finish(sched, slot, rep)
+                    self._release_drained(eng, sched, rep)
             t += 1
 
-        # final flush: validates (and in drain mode DRAINS) the partial
-        # window left when the loop exits — `final=True` forces the drain
-        # below the cadence so no token stays parked past the run
-        ev = eng.flush_deferred(final=True)
-        if ev is not None:
-            dual = self._handle_event(
-                eng, recovery, sched, ring, ev, dual, rep, notify_reject,
-                expected=expected if drain_on else None, consumer=consumer)
-        self._release_drained(eng, sched, rep)
-        # quiescence: drainers whose evidence was consumed by an event they
-        # were not implicated in (ring cleared, frontier regressed) have no
-        # pending predicates left and were never proven bad — release.
-        # `_finish` skips slots already released by the final flush's
-        # delivery path, so a drainer finishing inside the final partial
-        # window releases exactly once (no duplicate, none stranded).
-        if not eng.pending_validation:
-            for slot, req in list(sched.draining_items()):
-                if req.status == DRAINING:
-                    self._finish(sched, slot, rep)
-        if consumer is not None:
-            consumer.quiesce()
-            consumer.close()
-            eng.emission_ring = None
-            # ring retraction replaced driver-side truncation: aggregate
-            # the per-request counts the consumer accumulated
-            rep.truncated_tokens = sum(r.truncated_tokens for r in requests)
+        with obs.span("serve_finish", step=t,
+                      draining=len(sched.draining_items())):
+            # final flush: validates (and in drain mode DRAINS) the partial
+            # window left when the loop exits — `final=True` forces the
+            # drain below the cadence so no token stays parked past the run
+            ev = eng.flush_deferred(final=True)
+            if ev is not None:
+                dual = self._handle_event(
+                    eng, recovery, sched, ring, ev, dual, rep, notify_reject,
+                    expected=expected if drain_on else None,
+                    consumer=consumer)
+            # `_finish` skips slots already released by the final flush's
+            # delivery path, so a drainer finishing inside the final partial
+            # window releases exactly once (no duplicate, none stranded)
+            for slot in self._releasable(eng, sched):
+                self._finish(sched, slot, rep)
+            if consumer is not None:
+                consumer.quiesce()
+                consumer.close()
+                eng.emission_ring = None
+                # ring retraction replaced driver-side truncation: aggregate
+                # the per-request counts the consumer accumulated
+                rep.truncated_tokens = sum(r.truncated_tokens
+                                           for r in requests)
 
         rep.detections = prefill_events + list(eng.detections)
         rep.retries = sum(1 for r in eng.recoveries if r["kind"] == "retry")

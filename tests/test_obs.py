@@ -487,6 +487,88 @@ def test_trace_spans_chrome_format(tmp_path):
     assert doc["traceEvents"][1]["args"]["step"] == 3
 
 
+def test_span_lands_in_the_profiler_host_plane(tmp_path):
+    """Every program span is also a profiler annotation: a JAX profile
+    taken while tracing is on holds it by name, on the device ops' clock."""
+    import glob
+    import sys
+
+    import jax
+    from jax.profiler import ProfileData
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from perf.trace_reduce import host_events
+
+    tr = obs.enable_trace()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("x", step=1):
+            jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    [(start, end)] = host_events(ProfileData.from_file(path), "x")
+    assert end > start
+    # the recorder's Chrome-trace event is unchanged by the annotation
+    [ev] = tr.by_name("x")
+    assert ev["args"] == {"step": 1} and ev["ph"] == "X"
+
+
+SERVE_STAGES = ("serve_start", "admit", "slot_snapshot", "slot_release",
+                "token_deliver", "serve_finish")
+
+
+def test_serve_spans_cover_the_host_stages_of_a_call():
+    """A fused lag-8 serve() names each host stage in a span of its own:
+    no span lasts as long as the call, and the main thread's outermost
+    spans cover nearly all of the call's host time."""
+    import time
+
+    import jax
+    from repro.configs import RunConfig, TrainConfig, get_config, \
+        reduce_for_smoke
+    from repro.runtime.scheduler import Request
+    from repro.runtime.serve import SedarServer
+
+    rc = RunConfig(model=reduce_for_smoke(get_config("qwen2-0.5b")),
+                   train=TrainConfig(global_batch=2, seq_len=8))
+    srv = SedarServer(rc, backend="fused")
+    params = srv.model.init(jax.random.PRNGKey(0))
+    rs = np.random.RandomState(0)
+
+    def backlog():
+        # due at once, more requests than slots, budgets from 1 up: slots
+        # free and refill mid-call, and a one-token budget releases at once
+        return [Request(rid=i, prompt=rs.randint(0, 200, (L,)).astype(
+                    np.int32), max_new_tokens=n)
+                for i, (L, n) in enumerate([(8, 12), (4, 1), (8, 20),
+                                            (4, 9), (8, 6), (4, 14)])]
+
+    srv.serve(params, backlog(), slots=3, validate_lag=8)   # compile
+    tr = obs.enable_trace()
+    a = time.monotonic()
+    out, rep = srv.serve(params, backlog(), slots=3, validate_lag=8)
+    b = time.monotonic()
+    assert len(rep.completed) == len(out) and not rep.detections
+
+    assert set(SERVE_STAGES) <= {e["name"] for e in tr.events}
+    assert {e["args"]["at"] for e in tr.by_name("slot_snapshot")} == {
+        "admit", "flush"}
+    call_us = (b - a) * 1e6
+    assert max(e["dur"] for e in tr.events) < 0.5 * call_us
+    main = threading.get_ident() & 0xFFFF
+    lo, hi = (a - tr._t0) * 1e6, (b - tr._t0) * 1e6
+    covered, reach = 0.0, lo
+    for e in sorted((e for e in tr.events if e["tid"] == main),
+                    key=lambda e: e["ts"]):
+        s, f = max(e["ts"], reach), min(e["ts"] + e["dur"], hi)
+        if f > s:
+            covered += f - s
+            reach = f
+    assert covered >= 0.9 * call_us, covered / call_us
+
+
 def test_global_span_noop_until_enabled():
     ctx = obs.span("anything")
     with ctx:

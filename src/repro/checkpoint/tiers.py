@@ -147,7 +147,7 @@ class SlotRing:
     SLOT, holding that slot's {cache slice, token, position} image.
 
     Same storage contract as `DeviceRing` — saves and restores are pure
-    `jnp.copy`, ZERO disk reads and ZERO host syncs — but keyed by slot so
+    device work, ZERO disk reads and ZERO host syncs — but keyed by slot so
     a detected fault restores ONLY the affected sequence's state while the
     other slots' rings (and live state) are untouched. Versions are decode
     ticks; `restore(slot, max_step=k)` returns the newest snapshot at or
@@ -164,21 +164,25 @@ class SlotRing:
         self.saves = 0
         self.restores = 0
 
-    def save(self, key: int, step: int, state_slice) -> None:
+    def _put(self, key: int, step: int, state_slice) -> None:
         ring = self._rings.setdefault(int(key), _Ring(self.slots_per_key))
-        ring._put(step, jax.tree.map(jnp.copy, state_slice), keep_floor=None)
+        ring._put(step, state_slice, keep_floor=None)
         self.saves += 1
+
+    def save(self, key: int, step: int, state_slice) -> None:
+        self._put(key, step, jax.tree.map(jnp.copy, state_slice))
 
     def save_many(self, step: int, slices: "Dict[int, Any]") -> None:
         """Batched snapshots at one shared version: a whole prefill pack's
         slot slices at admission (DESIGN.md §14), or every live slot at a
         clean flush edge under lag-aligned drain (DESIGN.md §18 — flush
         edges are the only points where the optimistic window is fully
-        validated, so drain-mode versions always land there). The copies
-        are issued together before any is awaited — still pure `jnp.copy`,
-        zero disk, zero host syncs."""
+        validated, so drain-mode versions always land there). The ring
+        takes the slices as they are, with no copy: callers pass fresh
+        buffers that no live (donated) state aliases, the outputs of the
+        one-launch snapshot program. Zero disk, zero host syncs."""
         for key, sl in slices.items():
-            self.save(key, step, sl)
+            self._put(key, step, sl)
 
     def newest_version(self, key: int) -> Optional[int]:
         """Newest recorded version for `key` (None when the slot has no

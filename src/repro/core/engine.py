@@ -48,6 +48,7 @@ a clean flush, so every stored version predates the oldest unvalidated step.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -223,6 +224,13 @@ class ReplicaExecutor:
         tokens/step counters through (cheaper than `primary()`, which slices
         every leaf)."""
         return dual["r0"][key]
+
+    def slot_images(self, dual, keys: Tuple[str, ...]) -> Tuple[Any, ...]:
+        """Replica 0's entries `keys`, split along their leading slot axis
+        into one image per slot, in ONE launch (`sedar_slot_snapshot`):
+        the serving loop's Tier-0 snapshot source (DESIGN.md §13). Every
+        image is a fresh buffer that no donated state aliases."""
+        return sedar_slot_snapshot({k: dual["r0"][k] for k in keys}, False)
 
     def map_state(self, fn, dual, *others):
         """Apply `fn` to EVERY replica's logical state (driver-side state
@@ -441,6 +449,19 @@ def _slot_mismatch_event(eq, step: int,
                           detail=detail)
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def sedar_slot_snapshot(entries, stacked: bool):
+    """Split `entries` along the slot axis into one image per slot. Under a
+    stacked layout (replica axis first) replica 0 is selected inside the
+    program, so XLA fuses the replica index into each slot's slice and no
+    whole-replica image is materialized. Every slot is extracted, so the
+    program has one shape for any number of running slots."""
+    lead = (0,) if stacked else ()
+    n = jax.tree.leaves(entries)[0].shape[len(lead)]
+    return tuple(jax.tree.map(lambda x, i=i: x[lead + (i,)], entries)
+                 for i in range(n))
+
+
 def slot_select(mask, new, old, n_slots: int, axis: int = 0):
     """Per-slot pytree merge: `where(mask)` along the slot axis for leaves
     that carry it (shape[axis] == n_slots); leaves WITHOUT a slot axis
@@ -599,6 +620,9 @@ class FusedSequentialExecutor(ReplicaExecutor):
 
     def peek(self, dual, key: str):
         return jax.tree.map(lambda x: x[0], dual["s"][key])
+
+    def slot_images(self, dual, keys: Tuple[str, ...]) -> Tuple[Any, ...]:
+        return sedar_slot_snapshot({k: dual["s"][k] for k in keys}, True)
 
     def _beat(self, step: int) -> None:
         if self.watchdog is not None:

@@ -149,13 +149,6 @@ def sedar_pack_insert(state, slots, sel, rows, toks, poss):
                 jnp.ones(slots.shape, jnp.bool_))}
 
 
-@jax.jit
-def sedar_slot_slice(cache, tok, pos, slot):
-    """Extract one slot's {cache, tok, pos} image (Tier-0 snapshot source)."""
-    return {"cache": jax.tree.map(lambda x: x[slot], cache),
-            "tok": tok[slot], "pos": pos[slot]}
-
-
 def _logits_checksum_guard(logits, spec: Optional[InjectionSpec],
                            step, armed):
     """ABFT output guard over one decode step's logits block — shared with
@@ -444,18 +437,20 @@ class SedarServer:
         eng.executor.note_external_update()
         return dual
 
-    def _slot_slice(self, eng, dual, slot: int):
-        return sedar_slot_slice(eng.executor.peek(dual, "cache"),
-                                eng.executor.peek(dual, "tok"),
-                                eng.executor.peek(dual, "pos"),
-                                jnp.asarray(slot, jnp.int32))
+    @staticmethod
+    def _save_images(eng, dual, ring, version: int, slots) -> None:
+        """ONE program launch cuts every slot's {cache, tok, pos} image out
+        of the executor's resident state; the ring keeps the images of
+        `slots` as they are (fresh buffers) and the rest are dropped."""
+        images = eng.executor.slot_images(dual, ("cache", "tok", "pos"))
+        ring.save_many(version, {slot: images[slot] for slot in slots})
 
     def _snapshot_slots(self, eng, dual, sched, ring, version: int,
                         slot_arrays: int) -> None:
         """Tier-0 per-slot snapshots at the deferred-validation cadence:
         every RUNNING slot's {cache, tok, pos} image enters its keyed
-        device ring right after a clean flush — pure `jnp.copy`, zero disk
-        reads, zero host syncs (the zero-sync property extends through
+        device ring right after a clean flush — one program launch, zero
+        disk reads, zero host syncs (the zero-sync property extends through
         per-request checkpointing, asserted by tests). One `save_many`
         batch per flush: the snapshot versions land exactly on the drain
         edges the emission ring delivers at, so a rollback target never
@@ -465,10 +460,10 @@ class SedarServer:
         if not running:
             return
         with obs.span("slot_snapshot", at="flush", step=version,
-                      slots=len(running), arrays=len(running) * slot_arrays):
-            ring.save_many(version, {
-                slot: self._slot_slice(eng, dual, slot)
-                for slot, _req in running})
+                      slots=len(running), arrays=len(running) * slot_arrays,
+                      launches=1):
+            self._save_images(eng, dual, ring, version,
+                              [slot for slot, _req in running])
 
     def _admit_slot(self, eng, dual, params, slot: int, req, t: int,
                     ring, ring_on: bool, max_len: int):
@@ -486,8 +481,9 @@ class SedarServer:
         ring.evict(slot)           # never resurrect a previous tenant
         dual = self._write_slot(eng, dual, slot, sl, active=True)
         if ring_on:
+            arrays = len(jax.tree.leaves(sl))
             with obs.span("slot_snapshot", at="admit", step=t, slots=1,
-                          arrays=len(jax.tree.leaves(sl))):
+                          arrays=arrays, launches=arrays):
                 ring.save(slot, t, sl)
         req.pos0 = req.prompt_len
         # the prefill token is single-execution (like generate()): the
@@ -537,16 +533,15 @@ class SedarServer:
                                                  toks_d, poss), dual)
                 eng.executor.note_external_update()
                 if ring_on:
+                    # the flush edges' program, on the state the rows were
+                    # just inserted into: no compile for the pack's size
                     with obs.span("slot_snapshot", at="admit", step=t,
                                   slots=len(good),
                                   arrays=len(good)
-                                  * (len(jax.tree.leaves(rows)) + 2)):
-                        ring.save_many(t, {
-                            pairs[i][0]: {
-                                "cache": jax.tree.map(
-                                    lambda x, j=i: x[j], rows),
-                                "tok": toks_d[i], "pos": poss[i]}
-                            for i in good})
+                                  * (len(jax.tree.leaves(rows)) + 2),
+                                  launches=1):
+                        self._save_images(eng, dual, ring, t,
+                                          [pairs[i][0] for i in good])
                 now_wall = time.time()
                 for i in good:
                     _slot, req = pairs[i]

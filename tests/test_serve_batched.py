@@ -34,6 +34,13 @@ def _requests():
                               max_new_choices=(4, 8), seed=1)
 
 
+def _long_requests():
+    """Budgets long enough that lag-4 and lag-8 flush edges fall while
+    several slots run (the Tier-0 flush snapshots carry the rollbacks)."""
+    return synthetic_requests(5, arrival_rate=2.0, prompt_lengths=(4, 8),
+                              max_new_choices=(16, 24), seed=1)
+
+
 def _serve(srv, params, **kw):
     reqs, rep = srv.serve(params, _requests(), slots=SLOTS, **kw)
     return {r.rid: r for r in reqs}, rep
@@ -42,8 +49,9 @@ def _serve(srv, params, **kw):
 def _slot_spec(**kw):
     """Transient SDC localized to FAULT_SLOT's logits on replica 1."""
     kw.setdefault("target", "slot")
+    kw.setdefault("step", FAULT_STEP)
     return InjectionSpec(leaf_idx=FAULT_SLOT, flat_idx=7, bit=30,
-                         step=FAULT_STEP, replica=1, **kw)
+                         replica=1, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -255,15 +263,16 @@ def test_single_token_budget_delivers_exactly_one(setup):
 # zero-sync / zero-disk hot path (acceptance property)
 # ---------------------------------------------------------------------------
 
-def test_fault_free_deferred_path_is_sync_and_disk_free(setup):
+@pytest.mark.parametrize("backend", ["sequential", "fused"])
+def test_fault_free_deferred_path_is_sync_and_disk_free(setup, backend):
     """With validate_lag >= 8 the fault-free decode path performs NO host
     syncs AT ALL between flushes: tokens park in the emission ring
     (DESIGN.md §18) and leave fused with the combined predicate in ONE
     3-item `token_emit` batch per window (+ the per-PACK prefill read),
     with NO disk reads — asserted via the hostsync and checkpoint counting
-    hooks, Tier-0 snapshots included."""
+    hooks, Tier-0 snapshots (one launch a flush edge) included."""
     rc, params, _ = setup
-    srv = SedarServer(rc, dual=True)
+    srv = SedarServer(rc, backend=backend)
     _serve(srv, params, validate_lag=8)            # warm the jit caches
     with hostsync.count_transfers() as st, count_disk_reads() as dr:
         out, rep = _serve(srv, params, validate_lag=8)
@@ -284,6 +293,89 @@ def test_fault_free_deferred_path_is_sync_and_disk_free(setup):
     assert st.by_label["prefill_emit"] <= 2 * len(out)
     assert st.by_label.get("deferred_flush", 0) <= windows
     assert dr.reads == 0
+
+
+@pytest.mark.parametrize("backend", ["sequential", "fused"])
+def test_flush_snapshot_is_one_launch_of_primary_slot_images(
+        setup, backend, monkeypatch):
+    """At every clean flush edge ONE snapshot program (`slot_images`)
+    writes each running slot's {cache, tok, pos} image into the ring,
+    bitwise equal to that slot's slice of the primary replica at the edge;
+    no other slot gets that version, and the `slot_snapshot` spans of the
+    flush edges and of the packed admissions say `launches == 1`."""
+    from repro import obs
+    rc, params, _ = setup
+    srv = SedarServer(rc, backend=backend)
+    real_snapshot = SedarServer._snapshot_slots
+    checked = []
+
+    def spy(self, eng, dual, sched, ring, version, slot_arrays):
+        # host copies now: the next decode step may donate `dual`
+        prim = eng.executor.primary(dual)
+        want = {k: jax.tree.map(np.asarray, prim[k])
+                for k in ("cache", "tok", "pos")}
+        launches = []
+        real_images = eng.executor.slot_images
+        eng.executor.slot_images = (
+            lambda *a: launches.append(a) or real_images(*a))
+        eng.executor.peek = None          # the edge peeks at nothing
+        try:
+            real_snapshot(self, eng, dual, sched, ring, version, slot_arrays)
+        finally:
+            del eng.executor.slot_images, eng.executor.peek
+        assert len(launches) == 1
+        running = {slot for slot, _req in sched.running_items()}
+        for slot in range(SLOTS):
+            if slot not in running:
+                assert ring.newest_version(slot) != version
+                continue
+            got_v, got = ring.restore(slot, max_step=version)
+            assert got_v == version
+            ref = jax.tree.map(lambda x: x[slot], want)
+            assert jax.tree.structure(got) == jax.tree.structure(ref)
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+                a = np.asarray(a)
+                assert a.dtype == b.dtype and a.shape == b.shape
+                assert a.tobytes() == b.tobytes(), (slot, version)
+        checked.append(version)
+
+    monkeypatch.setattr(SedarServer, "_snapshot_slots", spy)
+    tr = obs.enable_trace()
+    try:
+        out, rep = srv.serve(params, _long_requests(), slots=SLOTS,
+                             validate_lag=4)
+    finally:
+        obs.shutdown()
+    assert not rep.detections and len(checked) >= 4
+    assert all(r.status == "done" for r in out)
+    flush = [e["args"] for e in tr.by_name("slot_snapshot")
+             if e["args"]["at"] == "flush"]
+    assert [a["step"] for a in flush] == checked
+    assert all(a["launches"] == 1 for a in flush)
+    assert {a["slots"] for a in flush} == {1, 2, SLOTS}
+    # a packed admission cuts its snapshots with the same one program
+    admit = [e["args"] for e in tr.by_name("slot_snapshot")
+             if e["args"]["at"] == "admit"]
+    assert admit and all(a["launches"] == 1 for a in admit)
+
+
+def test_fused_rollback_restores_a_flush_edge_image(setup):
+    """Lag 8, a slot fault after the first flush edge: the faulty slot
+    rolls back to the one-launch snapshot cut at that edge (not to its
+    admission image), and every stream equals the fault-free run."""
+    rc, params, _ = setup
+    clean, _ = SedarServer(rc, backend="fused").serve(
+        params, _long_requests(), slots=SLOTS, validate_lag=8)
+    srv = SedarServer(rc, backend="fused", inj_spec=_slot_spec(step=10))
+    out, rep = srv.serve(params, _long_requests(), slots=SLOTS,
+                         validate_lag=8)
+    assert len(rep.detections) == 1 and rep.rollbacks == 1
+    ev = rep.detections[0]
+    assert ev.boundary == "deferred" and ev.detail["slots"] == [FAULT_SLOT]
+    [(_eng, _ring, recovery)] = srv._batch_engines.values()
+    # the tenants were all admitted at tick 0; the first flush edge is 8
+    assert recovery.last_restore_info["slots"][FAULT_SLOT]["version"] == 8
+    assert [r.tokens for r in out] == [r.tokens for r in clean]
 
 
 def test_rollback_performs_zero_disk_reads(setup):
@@ -315,10 +407,14 @@ def test_fused_backend_equality_under_fault(setup):
     _assert_streams_equal(out, clean_toks)
 
 
-def test_fused_backend_deferred_equality(setup):
+@pytest.mark.parametrize("lag", [4, 8])
+def test_fused_backend_deferred_equality(setup, lag):
+    """Fused deferred serving at lag 4 and 8: the flush localizes the
+    faulty slot, it rolls back from the Tier-0 ring, and every stream
+    still equals the fault-free run."""
     rc, params, clean_toks = setup
     srv = SedarServer(rc, backend="fused", inj_spec=_slot_spec())
-    out, rep = _serve(srv, params, validate_lag=4)
+    out, rep = _serve(srv, params, validate_lag=lag)
     assert rep.detections[0].boundary == "deferred"
     assert rep.detections[0].detail["slots"] == [FAULT_SLOT]
     assert rep.rollbacks == 1

@@ -1,8 +1,11 @@
-"""The comparison that decides `correct` for a served model.
+"""The comparison that decides `correct` for a served model, shared by
+every architecture.
 
 A served token is compared by its logit under the plain float32 reference
-(`reference/qwen2.py`): at the position that produced it, the gap between
-the reference's best logit and the reference's logit of the served token.
+(`ref`, the module that the configuration's `reference` key names, with
+the whole reference weight dict `w`): at the position that produced it,
+the gap between the reference's best logit and the reference's logit of
+the served token.
 A greedy server that computes what the reference computes serves the
 reference's best token, or one whose logit lies within rounding of it; a
 server that alters a token, skips a layer or reads a stale cache serves
@@ -24,27 +27,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from perf.reference import qwen2
-
 ROW_BLOCK = 256
 
 
-@functools.partial(jax.jit, static_argnums=(3,))
-def _block_gaps(embed, h_ref, h_ctl, quant, served, valid):
+@functools.partial(jax.jit, static_argnums=(0, 4))
+def _block_gaps(logits, w, h_ref, h_ctl, quant, served, valid):
     """Gaps of one block of rows: (served gap, control gap), -inf on
-    invalid rows."""
-    ref = qwen2.logits({"embed": embed}, h_ref)
+    invalid rows. The leaves of `w` that `logits` does not read are
+    pruned from the compiled program."""
+    ref = logits(w, h_ref)
     best = jnp.max(ref, axis=-1)
     got = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
     served_gap = jnp.where(valid, best - got, -jnp.inf)
     if h_ctl is None:
         return served_gap, jnp.full_like(served_gap, -jnp.inf)
-    pick = jnp.argmax(qwen2.logits({"embed": embed}, h_ctl, quant), axis=-1)
+    pick = jnp.argmax(logits(w, h_ctl, quant), axis=-1)
     ctl = jnp.take_along_axis(ref, pick[:, None], axis=-1)[:, 0]
     return served_gap, jnp.where(valid, best - ctl, -jnp.inf)
 
 
-def request_gaps(w, cfg, prompt: np.ndarray, served: Sequence[int],
+def request_gaps(ref, w, cfg, prompt: np.ndarray, served: Sequence[int],
                  pad_to: int, control: Optional[str] = None
                  ) -> Tuple[float, Optional[float]]:
     """(widest served gap, widest control gap or None) of one request.
@@ -60,8 +62,8 @@ def request_gaps(w, cfg, prompt: np.ndarray, served: Sequence[int],
         raise ValueError(f"sequence of {len(seq)} exceeds pad_to={pad_to}")
     toks = np.zeros((pad_to,), np.int32)
     toks[:len(seq)] = seq
-    h_ref = qwen2.hidden(w, cfg, toks)
-    h_ctl = qwen2.hidden(w, cfg, toks, control) if control else None
+    h_ref = ref.hidden(w, cfg, toks)
+    h_ctl = ref.hidden(w, cfg, toks, control) if control else None
     # rows p-1 .. p+n-2 produced served tokens 0 .. n-1
     rows = np.arange(p - 1, p - 1 + n)
     nb = -(-n // ROW_BLOCK) * ROW_BLOCK
@@ -75,7 +77,7 @@ def request_gaps(w, cfg, prompt: np.ndarray, served: Sequence[int],
         sl = slice(b, b + ROW_BLOCK)
         hr = h_ref[idx[sl]]
         hc = h_ctl[idx[sl]] if h_ctl is not None else None
-        g, gc = _block_gaps(w["embed"], hr, hc, control,
+        g, gc = _block_gaps(ref.logits, w, hr, hc, control,
                             jnp.asarray(tgt[sl]), jnp.asarray(valid[sl]))
         worst = max(worst, float(jnp.max(g)))
         worst_ctl = max(worst_ctl, float(jnp.max(gc)))
@@ -96,7 +98,7 @@ def sample_requests(done: List[Tuple[np.ndarray, List[int]]], seed: int,
     return [longest] + [rest[int(i)] for i in pick]
 
 
-def gap_readings(w, cfg, sample: List[Tuple[np.ndarray, List[int]]],
+def gap_readings(ref, w, cfg, sample: List[Tuple[np.ndarray, List[int]]],
                  pad_to: int, control: Optional[str] = None
                  ) -> Dict[str, float]:
     """The compared numbers over a sample of (prompt, served tokens)."""
@@ -104,7 +106,7 @@ def gap_readings(w, cfg, sample: List[Tuple[np.ndarray, List[int]]],
     if control:
         out["control_max_logit_gap"] = -np.inf
     for prompt, served in sample:
-        g, gc = request_gaps(w, cfg, prompt, served, pad_to, control)
+        g, gc = request_gaps(ref, w, cfg, prompt, served, pad_to, control)
         out["max_logit_gap"] = max(out["max_logit_gap"], g)
         out["served_tokens"] += len(served)
         if control:

@@ -1,8 +1,10 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""Operations and bytes a qwen2 decoder needs, from shapes alone: the
+`flops` module that `configs/qwen2-0.5b.json` names.
 
 Model FLOPs count each multiply-add as 2 and count what the model needs,
 once: not per replica, not padding, not recomputation. `cfg` is a
-configuration file's dict (published Qwen2 keys).
+configuration file's dict (published Qwen2 keys). The harness calls
+`serving_flops` and `prefill_lane_bytes`; the rest are their terms.
 """
 from __future__ import annotations
 

@@ -45,7 +45,7 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
-from perf import check, flops, weights
+from perf import check
 from perf.compiles import CompileClock
 
 CALL_ANNOTATION = "perf_serve_call"
@@ -58,31 +58,16 @@ def percentile(values, q: float) -> float:
     return float(vals[min(max(rank, 1), len(vals)) - 1])
 
 
-def model_config(cfg: Dict[str, Any]):
-    """The program's model configuration, from the configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=True, tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        mlp_act="swiglu" if cfg["hidden_act"] == "silu" else cfg["hidden_act"],
-        dtype=cfg["dtype"], param_dtype=cfg["param_dtype"])
-
-
 class Server:
     """The program under test with the traffic's settings."""
 
-    def __init__(self, cfg, traffic, params):
+    def __init__(self, model, traffic, params):
         from repro.configs import RunConfig, SedarConfig, TrainConfig
         from repro.core.policy import make_server
         self.traffic = traffic
         self.params = params
         self.lag = int(traffic["validate_lag"])
-        rc = RunConfig(model=model_config(cfg), train=TrainConfig(),
+        rc = RunConfig(model=model, train=TrainConfig(),
                        sedar=SedarConfig(validate_lag=self.lag))
         self.srv = make_server(rc, backend=traffic["backend"],
                                prefill_buckets=traffic["buckets"],
@@ -156,15 +141,15 @@ def run(ctx) -> SimpleNamespace:
     import jax
     from repro import obs
 
-    cfg, traffic, gen = ctx.cfg, ctx.traffic, ctx.gen
+    cfg, arch, traffic, gen = ctx.cfg, ctx.arch, ctx.traffic, ctx.gen
     vocab = int(cfg["vocab_size"])
     max_len = gen.max_len(traffic)
     mk = _request_maker()
     clock = CompileClock()
     notes: List[str] = []
 
-    params = weights.program_weights(cfg, ctx.seed)
-    server = Server(cfg, traffic, params)
+    params = arch.weights.program_weights(cfg, ctx.seed)
+    server = Server(arch.weights.model_config(cfg), traffic, params)
     n_prog = server.srv.warmup_prefill(params, max_len, plain_batches=())
     warm = gen.requests(traffic, vocab, ctx.seed, -1, mk)
     server.serve(warm, max_len)
@@ -253,9 +238,9 @@ def run(ctx) -> SimpleNamespace:
     sample_idx = check.sample_requests(done, ctx.seed,
                                        int(traffic["check_requests"]))
     sample = [done[i] for i in sample_idx]
-    w = weights.reference_weights(cfg, ctx.seed)
-    reading = check.gap_readings(w, cfg, sample, pad_to=max_len,
-                                 control=ctx.control)
+    w = arch.weights.reference_weights(cfg, ctx.seed)
+    reading = check.gap_readings(arch.reference, w, cfg, sample,
+                                 pad_to=max_len, control=ctx.control)
     del w
     gap = reading["max_logit_gap"]
     if ctx.control:
@@ -291,8 +276,9 @@ def _peak(devs) -> int:
 def _layer_data(ctx, cfg, traffic, call, rec, rec_mono0, max_len
                 ) -> SimpleNamespace:
     """What the per-layer readers read: the reduced trace of the traced
-    call, the program's spans in it on the trace's clock, and the call's
-    work counted from shapes."""
+    call, the program's spans in it on the trace's clock, the call's work
+    (prompt length, served tokens) and the configuration's `flops` module
+    that counts it from shapes."""
     from jax.profiler import ProfileData
     from perf import trace_reduce
     paths = [os.path.join(dp, f) for dp, _, fs in os.walk(ctx.trace_dir)
@@ -317,4 +303,4 @@ def _layer_data(ctx, cfg, traffic, call, rec, rec_mono0, max_len
         reduction=red, spans=spans, cfg=cfg, traffic=traffic,
         peak=ctx.peak, chips=len(ctx.devices), max_len=max_len,
         replicas=replicas, call=call_stats(call, int(traffic["slots"])),
-        work=work, model_flops=flops.serving_flops(cfg, work))
+        work=work, flops=ctx.arch.flops)

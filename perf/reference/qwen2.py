@@ -1,11 +1,13 @@
-"""Plain float32 Qwen2 decoder: the yardstick that decides `correct`.
+"""Plain float32 Qwen2 decoder: the yardstick that decides `correct`, and
+the `reference` module that `configs/qwen2-0.5b.json` names.
 
 Written from the published description of Qwen2 (arXiv:2407.10671 and
 the `Qwen2ForCausalLM` config keys in `configs/*.json`) in straightforward
 `jax.numpy`: one sequence at a time, full causal attention over the whole
 sequence, no KV cache, no batching, no kernels, every matrix product at
 `precision="highest"`. It imports nothing of the program under test and
-takes its weights from `weights.reference_weights`.
+takes its weights from `weights/qwen2.py`'s `reference_weights`. The
+harness calls `hidden` and `logits` (`check.py`).
 
 Per layer:  x += O(attn(rope(Q(n1(x))), rope(K(n1(x))), V(n1(x))))
             x += W_down(silu(W_gate(n2(x))) * W_up(n2(x)))

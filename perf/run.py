@@ -3,12 +3,18 @@
     python3 perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from `BENCHMARK.json`: the cell (`workloads`)
-names a configuration (`perf/configs/<config>.json`) and a traffic mix or
+names a configuration (the config entry's `file`) and a traffic mix or
 job (`perf/traffic/<traffic>.json`). The traffic file's `job` names the
 driver in `perf/jobs/` and its `kind` the generator in `perf/traffic/`;
 the cell's correctness limits are `perf/limits/<workload>.json`, and each
 per-layer metric is read by `perf/metrics/<metric>.py`. A new cell is one
 entry in `BENCHMARK.json` plus such files.
+
+The configuration file names, by paths from the checkout's root, the
+three modules that know its architecture (`ARCH`): its plain `reference`,
+its `weights` and its `flops`. No other file of the harness knows one. A
+new architecture is a configuration naming its three modules, plus those
+modules.
 
 The run exits non-zero and prints no result when JAX finds no TPU, fewer
 chips than the cell asks for, or a device kind that `perf/peaks.json` does
@@ -40,6 +46,17 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
         sys.path.insert(0, _p)
 
 
+# What each module that a configuration names gives the harness: the
+# reference's `hidden(w, cfg, tokens, quant=None)` and `logits(w, h,
+# quant=None)` over its whole weight dict `w`; `reference_weights(cfg,
+# seed)`, `program_weights(cfg, seed)` and the program's `model_config(cfg)`;
+# `serving_flops(cfg, work)` and `prefill_lane_bytes(cfg, max_len, rows,
+# replicas)`.
+ARCH = {"reference": ("hidden", "logits"),
+        "weights": ("reference_weights", "program_weights", "model_config"),
+        "flops": ("serving_flops", "prefill_lane_bytes")}
+
+
 class CellError(RuntimeError):
     """The cell cannot run here; no result is printed."""
 
@@ -56,6 +73,23 @@ def load_module(path: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_arch(root: str, cfg: Dict[str, Any]) -> SimpleNamespace:
+    """The modules that a configuration names for its architecture."""
+    mods = {}
+    for key, names in ARCH.items():
+        if key not in cfg:
+            raise CellError(f"configuration {cfg['name']!r} names no {key} "
+                            "module")
+        mod = load_module(os.path.join(root, cfg[key]),
+                          f"perf_{key}_{cfg['name']}".replace("-", "_")
+                          .replace(".", "_"))
+        lacks = [n for n in names if not callable(getattr(mod, n, None))]
+        if lacks:
+            raise CellError(f"{cfg[key]} lacks {', '.join(lacks)}")
+        mods[key] = mod
+    return SimpleNamespace(**mods)
 
 
 def cell_metrics(bench: Dict[str, Any], workload: str, section: str
@@ -81,9 +115,10 @@ def load_cell(root: str, workload: str) -> SimpleNamespace:
     centry = next(c for c in bench["configs"] if c["name"] == wl["config"])
     perf = os.path.join(root, "perf")
     try:
+        cfg = load_json(os.path.join(root, centry["file"]))
         return SimpleNamespace(
-            bench=bench, workload=wl, name=workload,
-            cfg=load_json(os.path.join(root, centry["file"])),
+            bench=bench, workload=wl, name=workload, cfg=cfg,
+            arch=load_arch(root, cfg),
             traffic=load_json(os.path.join(perf, "traffic",
                                            wl["traffic"] + ".json")),
             limits=load_json(os.path.join(perf, "limits",
@@ -156,7 +191,8 @@ def main(argv: Optional[List[str]] = None, *, root: str = ROOT,
     gen = load_module(os.path.join(PERF, "traffic", traffic["kind"] + ".py"),
                       "perf_traffic_" + traffic["kind"])
     ctx = SimpleNamespace(
-        cfg=cell.cfg, traffic=traffic, gen=gen, limits=cell.limits,
+        cfg=cell.cfg, arch=cell.arch, traffic=traffic, gen=gen,
+        limits=cell.limits,
         seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
         control=args.control,
         devices=devs[:cell.workload["chips"]], peak=peak, t_start=t_start,

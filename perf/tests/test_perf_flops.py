@@ -1,11 +1,11 @@
-"""Operation and byte counts of `perf/flops.py` against hand counts."""
+"""Operation and byte counts of `perf/flops/qwen2.py` against hand counts."""
 import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from perf import flops  # noqa: E402
+from perf.flops import qwen2 as flops  # noqa: E402
 
 # 2 layers, hidden 8, 2 query heads and 1 KV head of 4, MLP 16, vocab 10
 CFG = {"num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,

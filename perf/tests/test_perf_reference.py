@@ -1,6 +1,7 @@
 """The plain float32 Qwen2 reference against `repro.models` at a small size
 on the CPU: prefill then decode through the KV cache, and the training loss
-with its gradient. Both sides get the same weights from `perf/weights.py`."""
+with its gradient. Both sides get the same weights from
+`perf/weights/qwen2.py`."""
 import os
 import sys
 
@@ -13,9 +14,8 @@ sys.path.insert(0, ROOT)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from perf import weights  # noqa: E402
-from perf.jobs.serve import model_config  # noqa: E402
 from perf.reference import qwen2  # noqa: E402
+from perf.weights import qwen2 as weights  # noqa: E402
 
 CFG = {"name": "tiny", "hidden_act": "silu", "hidden_size": 64,
        "intermediate_size": 96, "num_hidden_layers": 2,
@@ -30,7 +30,7 @@ PROMPT, DECODE = 7, 5
 @pytest.fixture(scope="module")
 def model():
     from repro.models import build_model
-    return build_model(model_config(CFG))
+    return build_model(weights.model_config(CFG))
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +91,7 @@ def test_training_loss_and_gradient(model, tokens):
     """The program's loss is the reference's cross-entropy plus its z-loss
     regularizer (1e-4 * mean(logsumexp^2), a departure of the program);
     the gradient through the program's parameter tree agrees leaf by leaf."""
-    from perf.weights import _to_program
+    from perf.weights.qwen2 import _to_program
     w = weights.reference_weights(CFG, SEED)
     inp, tgt = tokens[:-1], tokens[1:]
     batch = {"tokens": jnp.asarray(inp[None]), "targets": jnp.asarray(tgt[None])}

@@ -1,4 +1,6 @@
-"""Qwen2 weights made from a seed, on the device, in one jitted call.
+"""Weights of the qwen2 configurations, made from a seed on the device in
+one jitted call, and the program's model configuration for them: the
+`weights` module that `configs/qwen2-0.5b.json` names.
 
 The benchmark makes the weights; neither the program under test nor the
 plain reference makes its own. `reference_weights` gives them in the
@@ -6,7 +8,8 @@ published layout (the layout of `reference/qwen2.py`), and
 `program_weights` gives the same numbers rearranged into the parameter
 tree of `repro.models` (stacked layers, per-head projections, norm scales
 stored as `scale - 1`). Both are drawn from the same key, so the two sides
-compute with the same model.
+compute with the same model. `model_config` maps the published keys onto
+the program's `ModelConfig`.
 
 Scales: the projections are normal with std 1/sqrt(fan_in), the embedding
 0.02, the biases 0.02 and the norm scales 1 + 0.1 * normal, so that every
@@ -21,12 +24,22 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from perf.weights import seed_key
 
-def seed_key(seed: int):
-    """A PRNG key that keeps every bit of a seed of up to 64 bits."""
-    seed = int(seed)
-    key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
-    return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
+
+def model_config(cfg: Dict[str, Any]):
+    """The program's model configuration, from the configuration file."""
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name=cfg["name"], family="dense",
+        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        qkv_bias=True, tie_embeddings=cfg["tie_word_embeddings"],
+        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
+        mlp_act="swiglu" if cfg["hidden_act"] == "silu" else cfg["hidden_act"],
+        dtype=cfg["dtype"], param_dtype=cfg["param_dtype"])
 
 
 def _draw(cfg: Dict[str, Any], key) -> Dict[str, Any]:

@@ -44,9 +44,27 @@ class ModelConfig:
     norm_eps: float = 1e-5
     mlp_act: str = "swiglu"           # swiglu | gelu
 
+    # YaRN rope scaling as (key, value) pairs with the published keys
+    # (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale, mscale_all_dim); () -> plain rope
+    rope_scaling: Tuple[Tuple[str, float], ...] = ()
+
+    # --- latent attention (MLA, DeepSeek-V2) -------------------------------
+    kv_lora_rank: int = 0             # >0 -> MLA over a latent KV cache this wide
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0         # one rope key, shared by every head
+    v_head_dim: int = 0
+
     # --- MoE ---------------------------------------------------------------
-    num_experts: int = 0
+    num_experts: int = 0              # routed experts held here
     experts_per_token: int = 0
+    router_experts: int = 0           # router width over every chip (0 -> num_experts)
+    expert_offset: int = 0            # router index of the first expert held here
+    moe_d_ff: int = 0                 # routed expert width (0 -> d_ff)
+    shared_d_ff: int = 0              # shared experts, as one MLP this wide (0 -> none)
+    moe_raw_topk: bool = False        # top-k weights as the softmax gives them (no renorm)
+    first_dense_layers: int = 0       # leading layers with a dense MLP (moe family)
+    dense_d_ff: int = 0               # their width (0 -> d_ff)
 
     # --- hybrid (recurrentgemma-style) --------------------------------------
     # Repeating block pattern, e.g. ("recurrent", "recurrent", "attention").
@@ -99,6 +117,11 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        """Width that rope rotates: the shared rope key under MLA."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
 
     def param_count(self) -> int:
         """Total parameter count N (exact, mirrors the builders in models/)."""

@@ -51,7 +51,7 @@ import dataclasses
 import functools
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -922,6 +922,7 @@ class SedarEngine:
         # emission refs and flush_deferred fuses the drained window into
         # the SAME readback as the combined commit predicate
         self.emission_ring = None
+        self.extra_values: List[Any] = []        # flush_deferred(extra=...)
         # -- live reconfiguration (DESIGN.md §17) ---------------------------
         # autotuner transitions are per-run: reset() restores the configured
         # baseline so a cached engine (serve's _batch_engines) never leaks a
@@ -944,6 +945,7 @@ class SedarEngine:
         self._ring.clear()
         self.validated_frontier = 0
         self.emission_ring = None     # drivers re-attach per run
+        self.extra_values = []
         self.reconfigs.clear()
         self.schedule = self._base_schedule
         self.validate_lag = self._base_lag
@@ -1097,7 +1099,8 @@ class SedarEngine:
         event = self._maybe_checkpoint(dual2, new_step)
         return StepOutcome(dual=dual2, aux=aux, event=event)
 
-    def flush_deferred(self, final: bool = False) -> Optional[DetectionEvent]:
+    def flush_deferred(self, final: bool = False, extra: Sequence = ()
+                       ) -> Optional[DetectionEvent]:
         """Force the deferred-window readback: ONE host read of the combined
         ring predicate; only a failed flush pays a second read to localize
         the first mismatched step. Clean flush advances the validated
@@ -1110,27 +1113,44 @@ class SedarEngine:
         per-tick emission reads); a failed flush truncates the ring at
         `slot_first_bad` BEFORE delivery, so rolled-back slots retract
         their un-drained tokens by construction. `final=True` forces the
-        drain even below the ring's cadence (end of run)."""
+        drain even below the ring's cadence (end of run). `extra` device
+        arrays ride in the same readback (a caller's end-of-run counters);
+        their host values land in `extra_values`, read alone only when the
+        flush has nothing else to read."""
         emis = self.emission_ring
         drain = emis.provide(final=final) if emis is not None else None
+        extra = list(extra)
+        n_extra = len(extra)
+
+        def split(vals):
+            if n_extra:
+                self.extra_values = list(vals[len(vals) - n_extra:])
+                return vals[:len(vals) - n_extra]
+            return vals
+
         if not self._ring:
             if drain is not None:
                 # nothing pending validation: every parked row was already
                 # proven clean by an earlier flush — pure delivery
                 with obs.span("token_drain", rows=len(emis)):
-                    vals = hostsync.batched_get(drain, label="token_emit")
+                    vals = split(hostsync.batched_get(drain + extra,
+                                                      label="token_emit"))
                 _deliver(emis, vals)
+            elif extra:
+                split(hostsync.batched_get(extra, label="run_counters"))
             return None
         steps_, preds = zip(*self._ring)
         drain_vals = None
-        if drain is not None:
+        if drain is not None or extra:
             with obs.span("deferred_flush", steps=len(self._ring),
-                          drain_rows=len(emis)):
-                vals = hostsync.batched_get(
-                    [jnp.all(jnp.stack(list(preds)))] + drain,
-                    label="token_emit")
+                          drain_rows=len(emis) if drain is not None else 0):
+                vals = split(hostsync.batched_get(
+                    [jnp.all(jnp.stack(list(preds)))] + (drain or []) + extra,
+                    label="token_emit" if drain is not None
+                    else "deferred_flush"))
             ok = bool(np.all(vals[0]))
-            drain_vals = vals[1:]
+            if drain is not None:
+                drain_vals = vals[1:]
         else:
             with obs.span("deferred_flush", steps=len(self._ring)):
                 ok = hostsync.read_bool(jnp.all(jnp.stack(list(preds))),

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -65,12 +66,63 @@ def layer_norm(x, scale, bias, eps: float):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_tables(positions, head_dim: int, theta: float):
-    """positions: (...,) int32 -> (sin, cos) of shape (..., head_dim//2), f32."""
+def rope_tables(positions, head_dim: int, theta: float, scaling=()):
+    """positions: (...,) int32 -> (sin, cos) of shape (..., head_dim//2), f32.
+    `scaling` (ModelConfig.rope_scaling) applies YaRN's frequencies and
+    magnitude."""
     half = head_dim // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling:
+        freq, mscale = yarn_frequencies(head_dim, theta, dict(scaling))
+    else:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freq  # (..., half)
+    if scaling and mscale != 1.0:
+        return jnp.sin(ang) * mscale, jnp.cos(ang) * mscale
     return jnp.sin(ang), jnp.cos(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_ramp(head_dim: int, theta: float, s) -> Tuple[int, int]:
+    """Rope pairs over which YaRN blends from the original frequencies (pairs
+    below `low`) to the interpolated ones (pairs above `high`)."""
+    def dim(rotations):
+        return (head_dim * math.log(s["original_max_position_embeddings"]
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim(s["beta_fast"])), 0),
+            min(math.ceil(dim(s["beta_slow"])), head_dim - 1))
+
+
+def yarn_frequencies(head_dim: int, theta: float, s):
+    """YaRN (arXiv:2309.00071, as DeepSeek-V2 configures it): per rope pair,
+    the original frequency below the ramp, the frequency divided by `factor`
+    above it, linearly blended across it; and the sin/cos magnitude
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    half = head_dim // 2
+    extra = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim)
+    low, high = yarn_ramp(head_dim, theta, s)
+    ramp = np.clip((np.arange(half, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    freq = extra / s["factor"] * ramp + extra * (1 - ramp)
+    mscale = (yarn_mscale(s["factor"], s.get("mscale", 1.0))
+              / yarn_mscale(s["factor"], s.get("mscale_all_dim", 0.0)))
+    return jnp.asarray(freq, jnp.float32), float(mscale)
+
+
+def attention_scale(cfg) -> float:
+    """Softmax scale: 1/sqrt(head size), times YaRN's mscale(factor,
+    mscale_all_dim) squared where the configuration scales rope so."""
+    if not cfg.kv_lora_rank:
+        return 1.0 / math.sqrt(cfg.head_dim)
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    s = dict(cfg.rope_scaling)
+    if s.get("mscale_all_dim"):
+        scale *= yarn_mscale(s["factor"], s["mscale_all_dim"]) ** 2
+    return scale
 
 
 def apply_rope(x, sin, cos):
@@ -144,6 +196,99 @@ def out_project(cfg, p, o):
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2 arXiv:2405.04434), no query compression
+# ---------------------------------------------------------------------------
+
+def init_mla(key, cfg, layers: Optional[int] = None):
+    """MLA params: the query, the joint down-projection to the latent and the
+    shared rope key (`wkv_a`), the latent's RMSNorm, the latent's per-head
+    key and value up-projections (`wk_b`, `wv_b`) and the output."""
+    ks = jax.random.split(key, 5)
+    D, H, R = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    L = (layers,) if layers else ()
+    pdt = _pdt(cfg)
+
+    def mk(k, shape, fan_in):
+        return normal_init(k, L + shape, pdt, 1.0 / math.sqrt(fan_in))
+
+    p = {"wq": mk(ks[0], (D, H, dn + dr), D),
+         "wkv_a": mk(ks[1], (D, R + dr), D),
+         "kv_norm": jnp.zeros(L + (R,), pdt),
+         "wk_b": mk(ks[2], (R, H, dn), R),
+         "wv_b": mk(ks[3], (R, H, dv), R),
+         "wo": mk(ks[4], (H, dv, D), H * dv)}
+    lax_pref = ("layers",) if layers else ()
+    ax = {"wq": lax_pref + ("embed", "heads", "head_dim"),
+          "wkv_a": lax_pref + ("embed", None),
+          "kv_norm": lax_pref + (None,),
+          "wk_b": lax_pref + (None, "heads", "head_dim"),
+          "wv_b": lax_pref + (None, "heads", "head_dim"),
+          "wo": lax_pref + ("heads", "head_dim", "embed")}
+    return p, ax
+
+
+def mla_query(cfg, p, x, sin, cos):
+    """x: (B,S,D) -> (q_nope (B,S,H,dn), roped q_pe (B,S,H,dr))."""
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    dn = cfg.qk_nope_head_dim
+    return q[..., :dn], apply_rope(q[..., dn:], sin, cos)
+
+
+def mla_latent(cfg, p, x, sin, cos):
+    """x: (B,S,D) -> the latent cache rows of its tokens: the normed latent
+    `c_kv` (B,S,R) and the roped key `k_pe` (B,S,dr) shared by all heads."""
+    R = cfg.kv_lora_rank
+    ckv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(x.dtype))
+    c = rms_norm(ckv[..., :R], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(ckv[..., None, R:], sin, cos)[:, :, 0]
+    return {"c_kv": c, "k_pe": k_pe}
+
+
+def mla_expand(p, q_nope, q_pe, rows):
+    """Prefill form: up-project the latent into per-head keys and values.
+    Returns (q, k, v) for the attention core: q, k (B,S,H,dn+dr) with the
+    shared rope key broadcast over heads, v (B,S,H,dv)."""
+    c = rows["c_kv"]
+    k_nope = jnp.einsum("bsr,rhk->bshk", c, p["wk_b"].astype(c.dtype))
+    v = jnp.einsum("bsr,rhk->bshk", c, p["wv_b"].astype(c.dtype))
+    k_pe = jnp.broadcast_to(rows["k_pe"][:, :, None, :],
+                            k_nope.shape[:3] + rows["k_pe"].shape[-1:])
+    return (jnp.concatenate([q_nope, q_pe], axis=-1),
+            jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], axis=-1), v)
+
+
+def mla_absorbed_attention(p, q_nope, q_pe, c_cache, pe_cache, pos,
+                           scale: float):
+    """Decode form over the latent cache (B,T,R) and rope-key cache (B,T,dr):
+    W_uk folds into the query, q_lat = W_uk^T q_nope (B,1,H,R), so scores are
+    q_lat . c + q_pe . k_pe; W_uv folds into the output, applied once to the
+    attention-weighted latent. Positions > pos are masked."""
+    f32 = jnp.float32
+    dt = q_nope.dtype
+    T = c_cache.shape[1]
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wk_b"].astype(dt),
+                       preferred_element_type=f32)
+    cf = c_cache.astype(f32)
+    logits = (jnp.einsum("bshr,btr->bhst", q_lat, cf)
+              + jnp.einsum("bshk,btk->bhst", q_pe.astype(f32),
+                           pe_cache.astype(f32))) * scale
+    valid = jnp.arange(T) <= pos
+    logits = jnp.where(valid[None, None, None, :], logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1)
+    ctx = jnp.einsum("bhst,btr->bshr", w, cf)
+    return jnp.einsum("bshr,rhk->bshk", ctx.astype(dt),
+                      p["wv_b"].astype(dt))
+
+
+def latent_cache_update(cache, rows, pos):
+    """Insert one token's latent rows ({c_kv, k_pe}: (B,1,...)) at `pos`."""
+    return {name: jax.lax.dynamic_update_slice(
+        cache[name], rows[name].astype(cache[name].dtype), (0, pos, 0))
+        for name in cache}
+
+
+# ---------------------------------------------------------------------------
 # Attention cores
 # ---------------------------------------------------------------------------
 
@@ -165,9 +310,11 @@ def _gqa_out(w, v, out_dtype):
 
 
 def causal_attention(q, k, v, *, causal: bool = True,
-                     positions_q=None, positions_k=None):
-    """Exact attention with f32 softmax. q:(B,S,H,hd) k,v:(B,T,KV,hd)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+                     positions_q=None, positions_k=None, scale=None):
+    """Exact attention with f32 softmax. q:(B,S,H,hd) k:(B,T,KV,hd)
+    v:(B,T,KV,hd_v). `scale` defaults to 1/sqrt(hd)."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     logits = _gqa_scores(q, k, scale)          # (B,KV,G,S,T)
     if causal:
         S, T = logits.shape[-2], logits.shape[-1]
@@ -242,7 +389,7 @@ def _flash_kv_body(carry, xs, scale):
 
 
 def chunked_causal_attention(q, k, v, *, q_chunk: int = _CHUNK_Q,
-                             k_chunk: int = _CHUNK_K):
+                             k_chunk: int = _CHUNK_K, scale=None):
     """Flash attention expressed in XLA scans (GSPMD-shardable): outer scan
     over q chunks, inner scan over k chunks, online-softmax carry. Memory is
     O(q_chunk * k_chunk) per step instead of O(S^2).
@@ -250,11 +397,17 @@ def chunked_causal_attention(q, k, v, *, q_chunk: int = _CHUNK_Q,
     q: (B,S,H,hd); k/v: (B,S,KV,hd). Exact vs mha oracle. NB: the inner scan
     visits every k block (no causal block skipping in XLA) — the compiled
     FLOPs overcount causal attention ~2x; the roofline report corrects for
-    this analytically and the Pallas kernel path skips for real on TPU."""
+    this analytically and the Pallas kernel path skips for real on TPU.
+    Values narrower than the keys (MLA) are zero-padded to their width and
+    the output cut back. `scale` defaults to 1/sqrt(hd)."""
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    hd_v = v.shape[-1]
+    if hd_v != hd:
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, hd - hd_v)))
     qc = min(q_chunk, S)
     kc = min(k_chunk, S)
     pS = (-S) % qc
@@ -307,7 +460,7 @@ def chunked_causal_attention(q, k, v, *, q_chunk: int = _CHUNK_Q,
         q_body, policy=jax.checkpoint_policies.nothing_saveable)
     _, outs = jax.lax.scan(q_body, None, (qblocks, qpos))          # (nq,B,H,qc,hd)
     out = outs.transpose(1, 2, 0, 3, 4).reshape(B, H, Sq, hd)
-    return out[:, :, :S, :].transpose(0, 2, 1, 3)
+    return out[:, :, :S, :hd_v].transpose(0, 2, 1, 3)
 
 
 def chunked_window_attention(q, k, v, window: int, *, q_chunk: int = _CHUNK_Q):
@@ -460,8 +613,9 @@ def cache_update(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0):
 # MLP
 # ---------------------------------------------------------------------------
 
-def init_mlp(key, cfg, layers: Optional[int] = None):
-    D, F = cfg.d_model, cfg.d_ff
+def init_mlp(key, cfg, layers: Optional[int] = None,
+             d_ff: Optional[int] = None):
+    D, F = cfg.d_model, d_ff or cfg.d_ff
     L = (layers,) if layers else ()
     lax_pref = ("layers",) if layers else ()
     pdt = _pdt(cfg)

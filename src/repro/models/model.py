@@ -6,6 +6,9 @@ API:
     model.loss(params, batch, ctx)        -> (loss, metrics)
     model.prefill(params, batch, max_len, ctx) -> (logits, cache)
     model.decode_step(params, cache, tokens, pos, ctx) -> (logits, cache)
+                                            (both take `stats=True` on the
+                                             decoder-only families and then
+                                             add the expert counters)
     model.init_cache(batch, max_len)      -> (cache, logical-axes)
     model.probes(shape)                   -> scan-cost-correction probes (see
                                              DESIGN.md §7 / launch/dryrun.py)
@@ -40,24 +43,30 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
                           cfg.head_dim, cfg.d_ff, cfg.vocab_size)
 
     def attn():
+        if cfg.kv_lora_rank:
+            R, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                             cfg.qk_rope_head_dim, cfg.v_head_dim)
+            return (D * H * (dn + dr) + D * (R + dr) + R
+                    + R * H * (dn + dv) + H * dv * D)
         n = D * H * hd + 2 * D * KV * hd + H * hd * D
         if cfg.qkv_bias:
             n += H * hd + 2 * KV * hd
         return n
 
-    def mlp():
+    def mlp(width=F):
         if cfg.mlp_act == "swiglu":
-            return 3 * D * F
-        return 2 * D * F + F + D
+            return 3 * D * width
+        return 2 * D * width + width + D
 
     def moe():
         E = cfg.num_experts
         k = cfg.experts_per_token
-        per_expert = 3 * D * F
-        router = D * E
+        per_expert = 3 * D * (cfg.moe_d_ff or F)
+        router = D * (cfg.router_experts or E)
+        shared = mlp(cfg.shared_d_ff) if cfg.shared_d_ff else 0
         if active_only:
-            return router + k * per_expert
-        return router + E * per_expert
+            return router + shared + k * per_expert
+        return router + shared + E * per_expert
 
     def recurrent():
         R, W = cfg.d_rnn, cfg.conv_width
@@ -101,7 +110,9 @@ def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
 
     per_layer = attn() + 2 * D
     per_layer += moe() if (cfg.family == "moe" and cfg.num_experts) else mlp()
-    total += cfg.num_layers * per_layer
+    Ld = cfg.first_dense_layers if cfg.family == "moe" else 0
+    total += (cfg.num_layers - Ld) * per_layer
+    total += Ld * (attn() + 2 * D + mlp(cfg.dense_d_ff or F))
     return total
 
 
@@ -193,11 +204,16 @@ class Model:
     def loss(self, params, batch, ctx=None):
         return self._loss(params, batch, ctx)
 
-    def prefill(self, params, batch, max_len, ctx=None):
-        return self._prefill(params, batch, max_len, ctx)
+    def prefill(self, params, batch, max_len, ctx=None, **kw):
+        return self._prefill(params, batch, max_len, ctx, **kw)
 
-    def decode_step(self, params, cache, tokens, pos, ctx=None):
-        return self._decode(params, cache, tokens, pos, ctx)
+    def decode_step(self, params, cache, tokens, pos, ctx=None, **kw):
+        return self._decode(params, cache, tokens, pos, ctx, **kw)
+
+    @property
+    def counts_experts(self) -> bool:
+        """Whether prefill and decode_step give expert counters."""
+        return self.cfg.family == "moe" and self.cfg.num_experts > 0
 
     def init_cache(self, batch, max_len, cache_dtype=jnp.bfloat16):
         return self._init_cache(batch, max_len, cache_dtype)
@@ -224,13 +240,13 @@ def _build_lm(cfg: ModelConfig) -> Model:
     def loss(params, batch, ctx):
         return tfm.lm_loss(cfg, params, batch, ctx)
 
-    def prefill(params, batch, max_len, ctx):
+    def prefill(params, batch, max_len, ctx, **kw):
         return tfm.lm_prefill(cfg, params, batch["tokens"], max_len, ctx,
                               batch.get("frontend_embeds"),
-                              lengths=batch.get("lengths"))
+                              lengths=batch.get("lengths"), **kw)
 
-    def decode(params, cache, tokens, pos, ctx):
-        return tfm.lm_decode_step(cfg, params, cache, tokens, pos, ctx)
+    def decode(params, cache, tokens, pos, ctx, **kw):
+        return tfm.lm_decode_step(cfg, params, cache, tokens, pos, ctx, **kw)
 
     def init_cache(batch, max_len, cache_dtype):
         return tfm.init_cache(cfg, batch, max_len, cache_dtype)
@@ -398,10 +414,11 @@ def _lm_probes(cfg: ModelConfig, shape: ShapeSpec) -> List[Probe]:
             vax = _slice_axes(cache_axes["v"])
 
             def layer_dec(lp, kcache, vcache, x, sin, cos, pos):
-                y, kc2, vc2 = tfm._attn_decode(cfg, lp, x, kcache, vcache,
-                                               sin, cos, pos, None)
+                y, c2 = tfm._attn_decode(cfg, lp, x,
+                                         {"k": kcache, "v": vcache},
+                                         sin, cos, pos, None)
                 y, _ = tfm._mlp_sub(cfg, lp, y, None)
-                return y, kc2, vc2
+                return y, c2["k"], c2["v"]
 
             out.append(Probe("layer_dec", layer_dec,
                              (lspecs, kc, vc, x_spec, sin_spec, sin_spec, pos_spec),
@@ -556,10 +573,9 @@ def _decode_group_body(cfg, pat):
             name = f"b{i}_{kind}"
             lp, c = gp[name], gc[name]
             if kind == "attention":
-                y, _, _ = tfm._attn_decode(
+                y, _ = tfm._attn_decode(
                     cfg, {"ln": lp["ln"], "core": lp["core"]},
-                    y, c["k"], c["v"], sin, cos, pos, None,
-                    window=cfg.window_size)
+                    y, c, sin, cos, pos, None, window=cfg.window_size)
                 if "mlp" in lp:
                     y, _ = tfm._mlp_sub(cfg, lp, y, None)
             elif kind == "recurrent":

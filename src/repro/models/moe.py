@@ -1,20 +1,24 @@
-"""Mixture-of-Experts layer: top-k routing with capacity-based dispatch.
+"""Mixture-of-Experts layer: softmax router, top-k, and the experts held here.
 
-Design (TPU-native, HLO-FLOPs-honest):
-  * router: dense (D, E) matmul + top-k.
-  * dispatch: tokens are scattered into per-expert buffers (E, C, D) where
-    C = capacity = ceil(k * T / E) * capacity_factor. Scatter/gather are
-    memory ops, NOT one-hot matmuls, so HLO FLOPs reflect only the *active*
-    expert compute (2*k*T*D*F-ish) — keeping MODEL_FLOPS/HLO_FLOPs meaningful.
-  * expert compute: batched einsum over the expert axis; experts shard over
-    the "model" mesh axis (expert parallelism). GSPMD inserts the
-    dispatch/combine collectives (all-to-all / all-gather depending on the
-    token sharding) — these show up in the collective roofline term.
-  * determinism: top-k on identical inputs is bitwise deterministic, so SEDAR
-    replicas stay in lockstep (DESIGN.md §4); no routing jitter under SEDAR.
+Two paths:
+  * one device (and a mesh without a model axis): DROPLESS. The router
+    keeps its full width (`router_experts`, every expert of every chip of
+    an expert-parallel deployment); this layer holds `num_experts` of them,
+    starting at router index `expert_offset`, and computes the part of the
+    result its held experts give: dense over the held experts, each
+    expert's rows weighted by the token's gate for it (zero for a token
+    not routed there). No token is dropped, so right-padded pack rows
+    cannot take a real token's place.
+  * expert parallel on a mesh (`moe_mlp_ep`): capacity-based dispatch with
+    an all_to_all exchange over the model axis; tokens over capacity are
+    dropped (standard "token dropping"). It holds every expert of the
+    router across the mesh.
 
-Dropped tokens (over capacity) fall back to the residual path (standard
-"token dropping" semantics, loss-free at the framework level).
+Shared experts (`shared_d_ff`) are one SwiGLU MLP every token passes
+through, added to the routed part. Top-k weights are renormalised unless
+`moe_raw_topk`. Determinism: the router's logits are float32 products of
+the bf16 hidden state at HIGHEST precision and top-k on identical inputs is
+bitwise deterministic, so SEDAR replicas route identically (DESIGN.md §4).
 """
 from __future__ import annotations
 
@@ -24,17 +28,23 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import normal_init
+from repro.models.layers import init_mlp, mlp, normal_init
+
+
+def router_width(cfg) -> int:
+    return cfg.router_experts or cfg.num_experts
 
 
 def init_moe(key, cfg, layers: Optional[int] = None):
-    D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+    D, E = cfg.d_model, cfg.num_experts
+    F = cfg.moe_d_ff or cfg.d_ff
     L = (layers,) if layers else ()
     lax_pref = ("layers",) if layers else ()
     pdt = jnp.dtype(cfg.param_dtype)
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     p = {
-        "router": normal_init(ks[0], L + (D, E), pdt, 1.0 / math.sqrt(D)),
+        "router": normal_init(ks[0], L + (D, router_width(cfg)), pdt,
+                              1.0 / math.sqrt(D)),
         "w_gate": normal_init(ks[1], L + (E, D, F), pdt, 1.0 / math.sqrt(D)),
         "w_up":   normal_init(ks[2], L + (E, D, F), pdt, 1.0 / math.sqrt(D)),
         "w_down": normal_init(ks[3], L + (E, F, D), pdt, 1.0 / math.sqrt(F)),
@@ -45,7 +55,53 @@ def init_moe(key, cfg, layers: Optional[int] = None):
         "w_up":   lax_pref + ("experts", "embed", "mlp"),
         "w_down": lax_pref + ("experts", "mlp", "embed"),
     }
+    if cfg.shared_d_ff:
+        p["shared"], ax["shared"] = init_mlp(ks[4], cfg, layers=layers,
+                                             d_ff=cfg.shared_d_ff)
     return p, ax
+
+
+def route(cfg, router, xt):
+    """Softmax router over its full width, float32 logits of the compute-
+    dtype hidden state, greedy top-k. xt: (T, D). Returns (probs (T, E_r),
+    gate weights (T, k) f32, expert indices (T, k))."""
+    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_w, gate_idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    if not cfg.moe_raw_topk:
+        gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
+    return probs, gate_w, gate_idx
+
+
+def balance_loss(probs, gate_idx, n_experts: int):
+    """Switch-style load-balance loss over the router's full width."""
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(gate_idx, n_experts,
+                                         dtype=jnp.float32), axis=1), axis=0)
+    return n_experts * jnp.sum(me * ce)
+
+
+def held_experts(cfg, p, xt, gate_w, gate_idx):
+    """The held experts' part of the routed result, dropless. xt: (T, D).
+    Every held expert runs on every token (T x num_experts rows); a row's
+    output is weighted by the token's gate for that expert, zero where the
+    token was not routed there. Returns (out (T, D), routes held per token
+    (T,) int32)."""
+    E = cfg.num_experts
+    dt = xt.dtype
+    local = gate_idx - cfg.expert_offset                      # (T, k)
+    here = (local >= 0) & (local < E)
+    gates = jnp.sum(jnp.where(here[..., None],
+                              jax.nn.one_hot(local, E, dtype=jnp.float32)
+                              * gate_w[..., None], 0.0), axis=1)   # (T, E)
+    h_g = jnp.einsum("td,edf->etf", xt, p["w_gate"].astype(dt))
+    h_u = jnp.einsum("td,edf->etf", xt, p["w_up"].astype(dt))
+    h = jax.nn.silu(h_g.astype(jnp.float32)) * h_u.astype(jnp.float32)
+    h = (h * gates.T[:, :, None]).astype(dt)
+    out = jnp.einsum("etf,efd->td", h, p["w_down"].astype(dt))
+    return out, jnp.sum(here, axis=-1, dtype=jnp.int32)
 
 
 def moe_mlp_ep(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
@@ -82,12 +138,9 @@ def moe_mlp_ep(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
                             ).astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         gate_w, gate_idx = jax.lax.top_k(probs, k)
-        gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
-        me = jnp.mean(probs, axis=0)
-        ce = jnp.mean(jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.float32),
-                              axis=1), axis=0)
-        aux = E * jnp.sum(me * ce)
-        aux = jax.lax.pmean(aux, token_axes)
+        if not cfg.moe_raw_topk:
+            gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
+        aux = jax.lax.pmean(balance_loss(probs, gate_idx, E), token_axes)
 
         flat_e = gate_idx.reshape(Tl * k)
         onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
@@ -136,92 +189,31 @@ def moe_mlp_ep(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
 
 
 def moe_mlp(cfg, p, x, *, capacity_factor: float = 1.25, ctx=None):
-    """x: (B, S, D) -> (B, S, D), plus aux losses dict.
+    """x: (B, S, D) -> (B, S, D), plus an aux dict: the load-balance loss
+    `moe_aux` and, on the dropless path, `moe_held` (B, S) int32, the
+    routes of each token that land on experts held here (the expert
+    counters of `runtime/serve.py` read it).
 
-    Group-local dispatch: tokens are viewed as (G, T/G, ...) with G = the
-    data-parallel degree, the leading dim pinned to the data axis. Routing
-    positions (cumsum) and the dispatch scatter are then LOCAL per data
-    shard — per-group capacity, the standard EP formulation — and the only
-    cross-device movement is the intended token->expert exchange over the
-    model axis (all-to-all in the compiled HLO). Without the grouping GSPMD
-    must treat the scatter as global and falls back to replicating the
-    (E, C, D) buffers, which at 1M tokens is tens of GB per device.
-    """
-    def act(t, *logical):
-        return ctx.act(t, *logical) if ctx is not None else t
-
+    The expert-parallel path runs when a mesh with a model axis holds every
+    expert of the router; otherwise the held experts run dropless here."""
     B, S, D = x.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-    T = B * S
-    dt = x.dtype
-
-    # production path: explicit expert parallelism when a mesh is present
-    if ctx is not None:
+    shared = (mlp(cfg, p["shared"], x) if "shared" in p else None)
+    if ctx is not None and router_width(cfg) == cfg.num_experts:
         r = ctx.resolver.rules
         tp = r.axis_size(ctx.mesh, r.model_axes)
         nsh = r.axis_size(ctx.mesh, tuple(r.data_axes) + tuple(r.model_axes))
-        if E % tp == 0 and T % nsh == 0 and tp > 1:
-            return moe_mlp_ep(cfg, p, x, capacity_factor=capacity_factor,
-                              ctx=ctx)
+        if cfg.num_experts % tp == 0 and (B * S) % nsh == 0 and tp > 1:
+            out, aux = moe_mlp_ep(cfg, p, x, capacity_factor=capacity_factor,
+                                  ctx=ctx)
+            return (out if shared is None else out + shared), aux
 
-    # dispatch group count = data-parallel degree (1 when mesh-free)
-    G = 1
+    xt = x.reshape(B * S, D)
     if ctx is not None:
-        r = ctx.resolver.rules
-        G = r.axis_size(ctx.mesh, r.data_axes)
-        if T % G != 0:
-            G = 1
-    Tg = T // G
-
-    xt = act(x.reshape(T, D), "batch", None)
-
-    # ---- route ---------------------------------------------------------------
-    logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-    gate_w, gate_idx = jax.lax.top_k(probs, k)                  # (T, k)
-    gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)   # renormalize
-
-    # load-balance aux loss (Switch-style)
-    me = jnp.mean(probs, axis=0)                                # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(gate_idx, E, dtype=jnp.float32), axis=1), axis=0)
-    aux_loss = E * jnp.sum(me * ce)
-
-    # ---- group-local dispatch ---------------------------------------------------
-    Cg = int(math.ceil(k * Tg / E * capacity_factor))
-    Cg = max(Cg, 4)
-    flat_e = gate_idx.reshape(G, Tg * k)                         # (G, Tkg)
-    flat_e = act(flat_e, "batch", None)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)          # (G, Tkg, E)
-    pos_in_e = jnp.cumsum(onehot, axis=1) - onehot               # per-group cumsum
-    pos = jnp.take_along_axis(pos_in_e, flat_e[..., None],
-                              axis=2)[..., 0]                    # (G, Tkg)
-    keep = pos < Cg
-
-    src = (jnp.repeat(xt, k, axis=0) if k > 1 else xt).reshape(G, Tg * k, D)
-    src = act(src, "batch", None, None)
-    gi = jnp.broadcast_to(jnp.arange(G)[:, None], flat_e.shape)  # (G, Tkg)
-    e_idx = jnp.where(keep, flat_e, 0)
-    c_idx = jnp.where(keep, pos, Cg - 1)
-    contrib = jnp.where(keep[..., None], src, 0).astype(dt)
-
-    buf = jnp.zeros((G, E, Cg, D), dt)
-    buf = buf.at[gi, e_idx, c_idx].add(contrib, mode="drop")
-    buf = act(buf, "batch", "experts", None, None)   # G->data, E->model (EP)
-
-    # ---- expert compute --------------------------------------------------------
-    h_g = jnp.einsum("gecd,edf->gecf", buf, p["w_gate"].astype(dt))
-    h_u = jnp.einsum("gecd,edf->gecf", buf, p["w_up"].astype(dt))
-    h = jax.nn.silu(h_g.astype(jnp.float32)).astype(dt) * h_u
-    out_buf = jnp.einsum("gecf,efd->gecd", h, p["w_down"].astype(dt))
-    out_buf = act(out_buf, "batch", "experts", None, None)
-
-    # ---- combine ----------------------------------------------------------------
-    gathered = out_buf[gi, e_idx, c_idx]                         # (G, Tkg, D)
-    gathered = jnp.where(keep[..., None], gathered, 0)
-    w = gate_w.reshape(G, Tg * k).astype(jnp.float32)
-    out = (gathered.astype(jnp.float32) * w[..., None]) \
-        .reshape(G, Tg, k, D).sum(axis=2)
-    out = out.reshape(B, S, D).astype(dt)
-    return out, {"moe_aux": aux_loss,
-                 "moe_drop_frac": 1.0 - jnp.mean(keep.astype(jnp.float32))}
+        xt = ctx.act(xt, "batch", None)
+    probs, gate_w, gate_idx = route(cfg, p["router"], xt)
+    out, held = held_experts(cfg, p, xt, gate_w, gate_idx)
+    out = out.reshape(B, S, D)
+    if shared is not None:
+        out = out + shared
+    return out, {"moe_aux": balance_loss(probs, gate_idx, router_width(cfg)),
+                 "moe_held": held.reshape(B, S)}

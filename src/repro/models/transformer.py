@@ -53,13 +53,19 @@ def _act(ctx, x, *logical):
 # Layer init (per family)
 # ---------------------------------------------------------------------------
 
-def _init_dense_layer_stack(key, cfg, L):
+def _init_dense_layer_stack(key, cfg, L, dense_mlp: bool = False):
+    """L stacked layers: attention (MLA where configured) and the MoE MLP
+    of the moe family, or a dense MLP (`dense_mlp`: the leading dense
+    layers, `dense_d_ff` wide)."""
     ks = jax.random.split(key, 4)
-    attn_p, attn_ax = nn.init_attention(ks[0], cfg, layers=L)
-    if cfg.family == "moe" and cfg.num_experts:
+    init_attn = nn.init_mla if cfg.kv_lora_rank else nn.init_attention
+    attn_p, attn_ax = init_attn(ks[0], cfg, layers=L)
+    if cfg.family == "moe" and cfg.num_experts and not dense_mlp:
         mlp_p, mlp_ax = moe_lib.init_moe(ks[1], cfg, layers=L)
     else:
-        mlp_p, mlp_ax = nn.init_mlp(ks[1], cfg, layers=L)
+        mlp_p, mlp_ax = nn.init_mlp(
+            ks[1], cfg, layers=L,
+            d_ff=cfg.dense_d_ff if dense_mlp else None)
     pdt = jnp.dtype(cfg.param_dtype)
     p = {"attn": attn_p, "mlp": mlp_p,
          "ln1": jnp.zeros((L, cfg.d_model), pdt),
@@ -120,10 +126,25 @@ def init_lm(key, cfg):
             params["tail"] = tp
             axes["tail"] = tax
     else:
-        lp, lax_ = _init_dense_layer_stack(ks[1], cfg, cfg.num_layers)
+        Ld = _dense_lead(cfg)
+        if Ld:
+            dp, dax = _init_dense_layer_stack(ks[3], cfg, Ld, dense_mlp=True)
+            params["dense_layers"] = dp
+            axes["dense_layers"] = dax
+        lp, lax_ = _init_dense_layer_stack(ks[1], cfg, cfg.num_layers - Ld)
         params["layers"] = lp
         axes["layers"] = lax_
     return params, axes
+
+
+def _dense_lead(cfg) -> int:
+    """Leading dense-MLP layers before the MoE stack (moe family only)."""
+    return cfg.first_dense_layers if cfg.family == "moe" else 0
+
+
+def _rope(cfg, positions):
+    return nn.rope_tables(positions, cfg.rope_dim, cfg.rope_theta,
+                          cfg.rope_scaling)
 
 
 # ---------------------------------------------------------------------------
@@ -131,11 +152,19 @@ def init_lm(key, cfg):
 # ---------------------------------------------------------------------------
 
 def _attn_full(cfg, lp, x, sin, cos, ctx, window: int = 0):
-    """Pre-norm attention sub-block, full sequence."""
+    """Pre-norm attention sub-block, full sequence. Under MLA the latent is
+    up-projected into per-head keys and values (the expanded form)."""
     h = nn.rms_norm(x, lp["ln1"] if "ln1" in lp else lp["ln"], cfg.norm_eps)
     # sequence-parallel boundary: x stays seq-sharded, the norm runs locally
     # (per-token), and the all-gather moves the bf16 normed activations
     h = _act(ctx, h, "batch", None, None)
+    if cfg.kv_lora_rank:
+        ap = lp["attn"]
+        q_nope, q_pe = nn.mla_query(cfg, ap, h, sin, cos)
+        q, k, v = nn.mla_expand(ap, q_nope, q_pe,
+                                nn.mla_latent(cfg, ap, h, sin, cos))
+        o = _attention_dispatch(cfg, q, k, v, window)
+        return x + _act(ctx, nn.out_project(cfg, ap, o), "batch", "seq", None)
     q, k, v = nn.qkv_project(cfg, lp["attn"] if "attn" in lp else lp["core"], h)
     q = nn.apply_rope(q, sin, cos)
     k = nn.apply_rope(k, sin, cos)
@@ -173,6 +202,11 @@ def _attention_dispatch(cfg, q, k, v, window: int = 0):
     Larger S: flash-in-XLA chunked scans (O(chunk) memory, GSPMD-shardable).
     attention_impl="pallas": the Pallas flash kernel (TPU production path)."""
     S = q.shape[1]
+    if cfg.kv_lora_rank:
+        scale = nn.attention_scale(cfg)
+        if S > nn.CHUNKED_THRESHOLD:
+            return nn.chunked_causal_attention(q, k, v, scale=scale)
+        return nn.causal_attention(q, k, v, scale=scale)
     if cfg.attention_impl == "pallas":
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, causal=True, window=window)
@@ -244,17 +278,20 @@ def _remat(cfg, fn):
 # ---------------------------------------------------------------------------
 
 def lm_hidden(cfg, params, tokens, ctx=None, frontend_embeds=None,
-              collect_kv: bool = False):
+              collect_kv: bool = False, stats: bool = False):
     """tokens: (B, S_text) int32. frontend_embeds: (B, P, D) or None.
 
     Returns (hidden (B,S,D), kv_stack or None, aux dict). S = P + S_text.
-    kv_stack (dense families only): (k, v) each (L, B, S, KV, hd)."""
+    kv_stack (dense families only): the decode cache's leaves over every
+    layer, each (L, B, S, ...): {k, v} (L, B, S, KV, hd), or under MLA the
+    latent rows {c_kv, k_pe}. `stats` adds `moe_held` (B, S) to aux: each
+    token's routes to held experts, summed over the MoE layers."""
     x = nn.embed_tokens(cfg, params["embed"], tokens)
     if frontend_embeds is not None:
         x = jnp.concatenate([frontend_embeds.astype(x.dtype), x], axis=1)
     B, S, D = x.shape
     x = _act(ctx, x, "batch", "seq", None)
-    sin, cos = nn.rope_tables(jnp.arange(S), cfg.head_dim, cfg.rope_theta)
+    sin, cos = _rope(cfg, jnp.arange(S))
     aux_out: Dict[str, Any] = {}
 
     if cfg.block_pattern:
@@ -276,26 +313,28 @@ def lm_hidden(cfg, params, tokens, ctx=None, frontend_embeds=None,
             x, _ = jax.lax.scan(_remat(cfg, tbody), x, params["tail"])
         kv = None
     else:
-        is_moe = cfg.family == "moe" and cfg.num_experts > 0
-
         def body(carry, lp):
             y, aux = _dense_layer_full(cfg, lp, carry, sin, cos, ctx)
+            out = {}
             if collect_kv:
-                # re-derive this layer's K/V from the *input* activations to
-                # seed the decode cache (prefill path only)
+                # re-derive this layer's cache rows from the *input*
+                # activations to seed the decode cache (prefill path only)
                 hq = nn.rms_norm(carry, lp["ln1"], cfg.norm_eps)
-                _, k, v = nn.qkv_project(cfg, lp["attn"], hq)
-                k = nn.apply_rope(k, sin, cos)
-                out = (k, v)
-            elif is_moe:
-                out = aux
-            else:
-                out = None
-            return y, out
+                out["kv"] = _cache_rows(cfg, lp["attn"], hq, sin, cos)
+            if not stats:
+                aux.pop("moe_held", None)
+            if aux and (stats or not collect_kv):
+                out["aux"] = aux
+            return y, out or None
 
-        G = remat_group_size(cfg)
+        ys = []
+        if "dense_layers" in params:
+            x, y_dense = jax.lax.scan(_remat(cfg, body), x,
+                                      params["dense_layers"])
+            ys.append(y_dense)
+        G = remat_group_size(cfg) if "dense_layers" not in params else 1
         if collect_kv or G == 1:
-            x, ys = jax.lax.scan(_remat(cfg, body), x, params["layers"])
+            x, y_main = jax.lax.scan(_remat(cfg, body), x, params["layers"])
         else:
             # scan-of-scans remat: checkpoint GROUPS of G layers so the
             # saved residual-stream carries shrink L -> L/G (the standard
@@ -317,14 +356,46 @@ def lm_hidden(cfg, params, tokens, ctx=None, frontend_embeds=None,
                 return jax.lax.scan(inner, carry, gp)
 
             x, ys_g = jax.lax.scan(_remat(cfg, group_body), x, grouped)
-            ys = (jax.tree.map(lambda a: a.reshape((cfg.num_layers,)
-                                                   + a.shape[2:]), ys_g)
-                  if ys_g is not None and is_moe else None)
-        kv = ys if collect_kv else None
-        if is_moe and not collect_kv and ys is not None:
-            aux_out = {k: jnp.mean(v) for k, v in ys.items()}
+            y_main = jax.tree.map(
+                lambda a: a.reshape((cfg.num_layers,) + a.shape[2:]), ys_g)
+        ys.append(y_main)
+        if collect_kv:
+            kv = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0),
+                              *[y["kv"] for y in ys])
+        else:
+            kv = None
+        if y_main is not None and "aux" in y_main:
+            aux_out = {k: jnp.mean(v) for k, v in y_main["aux"].items()
+                       if k != "moe_held"}
+            if stats:
+                aux_out["moe_held"] = jnp.sum(y_main["aux"]["moe_held"],
+                                              axis=0)
     x = nn.rms_norm(x, params["final_ln"], cfg.norm_eps)
     return x, kv, aux_out
+
+
+def _cache_rows(cfg, ap, h, sin, cos):
+    """One layer's decode-cache rows of the tokens whose normed input is h."""
+    if cfg.kv_lora_rank:
+        return nn.mla_latent(cfg, ap, h, sin, cos)
+    _, k, v = nn.qkv_project(cfg, ap, h)
+    k = nn.apply_rope(k, sin, cos)
+    return {"k": k, "v": v}
+
+
+def expert_counts(cfg, held, real):
+    """Expert counters of a batch of tokens, summed over the MoE layers:
+    `routes`, the (token, expert) routes of real tokens over every routed
+    expert; `routes_held`, those that land on experts held here; `rows`, the
+    held-expert rows the layers ran (every token, pads included). held:
+    (B, S) routes held per token; real: (B, S) bool."""
+    n_moe = cfg.num_layers - _dense_lead(cfg)
+    return {"routes": jnp.sum(real, dtype=jnp.int32)
+            * (cfg.experts_per_token * n_moe),
+            "routes_held": jnp.sum(jnp.where(real, held, 0),
+                                   dtype=jnp.int32),
+            "rows": jnp.asarray(real.size * cfg.num_experts * n_moe,
+                                jnp.int32)}
 
 
 def remat_group_size(cfg) -> int:
@@ -418,6 +489,15 @@ def init_cache(cfg, batch: int, max_len: int, cache_dtype=jnp.bfloat16):
         return cache, axes
 
     L = cfg.num_layers
+    if cfg.kv_lora_rank:
+        # MLA: the normed latent and the roped rope key, per token and layer
+        cache = {"c_kv": jnp.zeros((L, batch, max_len, cfg.kv_lora_rank),
+                                   cache_dtype),
+                 "k_pe": jnp.zeros((L, batch, max_len, cfg.qk_rope_head_dim),
+                                   cache_dtype)}
+        axes = {"c_kv": ("layers", "batch", None, None),
+                "k_pe": ("layers", "batch", None, None)}
+        return cache, axes
     cache = {"k": jnp.zeros((L, batch, max_len, KV, hd), cache_dtype),
              "v": jnp.zeros((L, batch, max_len, KV, hd), cache_dtype)}
     axes = {"k": ("layers", "batch", None, "kv_heads", "head_dim"),
@@ -425,11 +505,20 @@ def init_cache(cfg, batch: int, max_len: int, cache_dtype=jnp.bfloat16):
     return cache, axes
 
 
-def _attn_decode(cfg, lp, x, kc, vc, sin, cos, pos, ctx, window: int = 0):
-    """One attention block, single token. kc/vc: (B,T,KV,hd). Returns
-    (y, kc_new, vc_new)."""
+def _attn_decode(cfg, lp, x, c, sin, cos, pos, ctx, window: int = 0):
+    """One attention block, single token, over one layer's cache c: {k, v}
+    (B,T,KV,hd), or under MLA the latent cache {c_kv, k_pe}, attended in the
+    absorbed form. Returns (y, c_new)."""
     h = nn.rms_norm(x, lp["ln1"] if "ln1" in lp else lp["ln"], cfg.norm_eps)
     ap = lp["attn"] if "attn" in lp else lp["core"]
+    if cfg.kv_lora_rank:
+        q_nope, q_pe = nn.mla_query(cfg, ap, h, sin, cos)
+        c = nn.latent_cache_update(c, nn.mla_latent(cfg, ap, h, sin, cos),
+                                   pos)
+        o = nn.mla_absorbed_attention(ap, q_nope, q_pe, c["c_kv"], c["k_pe"],
+                                      pos, nn.attention_scale(cfg))
+        return x + nn.out_project(cfg, ap, o), c
+    kc, vc = c["k"], c["v"]
     q, k, v = nn.qkv_project(cfg, ap, h)
     q = nn.apply_rope(q, sin, cos)
     k = nn.apply_rope(k, sin, cos)
@@ -450,16 +539,18 @@ def _attn_decode(cfg, lp, x, kc, vc, sin, cos, pos, ctx, window: int = 0):
     o = nn.decode_attention(q, kc, vc, pos, window=window)
     o = _act(ctx, o, "batch", None, None, None)
     o = nn.out_project(cfg, ap, o)
-    return x + _act(ctx, o, "batch", None, None), kc, vc
+    return x + _act(ctx, o, "batch", None, None), {"k": kc, "v": vc}
 
 
-def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None):
+def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None,
+                   stats: bool = False):
     """One serve step. tokens: (B,) int32; pos: scalar int32 (0-based absolute
-    position of this token). Returns (logits (B,V), new_cache)."""
+    position of this token). Returns (logits (B,V), new_cache), and with
+    `stats` the expert counters of the step's tokens (`expert_counts`)."""
     x = nn.embed_tokens(cfg, params["embed"], tokens[:, None])   # (B,1,D)
     x = _act(ctx, x, "batch", None, None)
-    sin, cos = nn.rope_tables(pos[None] if jnp.ndim(pos) == 0 else pos,
-                              cfg.head_dim, cfg.rope_theta)
+    sin, cos = _rope(cfg, pos[None] if jnp.ndim(pos) == 0 else pos)
+    held = None
 
     if cfg.block_pattern:
         pat = tuple(cfg.block_pattern)
@@ -473,11 +564,10 @@ def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None):
                     name = f"b{i}_{kind}"
                     lp, c = gp[name], gc[name]
                     if kind == "attention":
-                        y, kc, vc = _attn_decode(
+                        y, gc_new[name] = _attn_decode(
                             cfg, {"ln": lp["ln"], "core": lp["core"]},
-                            y, c["k"], c["v"], sin, cos, pos,
-                            ctx, window=cfg.window_size)
-                        gc_new[name] = {"k": kc, "v": vc}
+                            y, c, sin, cos, pos, ctx,
+                            window=cfg.window_size)
                         if "mlp" in lp:
                             y, _ = _mlp_sub(cfg, lp, y, ctx)
                     elif kind == "recurrent":
@@ -521,29 +611,37 @@ def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None):
         # The KV cache is a loop CARRY updated in place with
         # dynamic_update_index (single buffer), NOT a scan xs->ys pair —
         # the xs/ys form double-buffers the multi-GB cache (§Perf C8).
-        L = cfg.num_layers
+        # Layer li of the cache is dense layer li for li < Ld, else MoE
+        # layer li - Ld.
+        L, Ld = cfg.num_layers, _dense_lead(cfg)
 
         def body(carry, sl):
-            y, kcache, vcache = carry
+            y, cc = carry
             lp, li = sl
-            kc = jax.lax.dynamic_index_in_dim(kcache, li, 0, keepdims=False)
-            vc = jax.lax.dynamic_index_in_dim(vcache, li, 0, keepdims=False)
-            y, kc2, vc2 = _attn_decode(cfg, lp, y, kc, vc, sin, cos, pos, ctx)
-            y, _ = _mlp_sub(cfg, lp, y, ctx)
-            kcache = jax.lax.dynamic_update_index_in_dim(
-                kcache, kc2.astype(kcache.dtype), li, 0)
-            vcache = jax.lax.dynamic_update_index_in_dim(
-                vcache, vc2.astype(vcache.dtype), li, 0)
-            return (y, kcache, vcache), None
+            c = {n: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
+                 for n, a in cc.items()}
+            y, c2 = _attn_decode(cfg, lp, y, c, sin, cos, pos, ctx)
+            y, aux = _mlp_sub(cfg, lp, y, ctx)
+            cc = {n: jax.lax.dynamic_update_index_in_dim(
+                a, c2[n].astype(a.dtype), li, 0) for n, a in cc.items()}
+            return (y, cc), (aux.get("moe_held") if stats else None)
 
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["layers"], jnp.arange(L)))
-        cache_new = {"k": k_new, "v": v_new}
+        carry = (x, cache)
+        if "dense_layers" in params:
+            carry, _ = jax.lax.scan(body, carry, (params["dense_layers"],
+                                                  jnp.arange(Ld)))
+        (x, cache_new), held = jax.lax.scan(
+            body, carry, (params["layers"], jnp.arange(Ld, L)))
 
     x = nn.rms_norm(x, params["final_ln"], cfg.norm_eps)
     logits = nn.logits_from_hidden(cfg, params["embed"], x)[:, 0, :]
     logits = _act(ctx, logits, "batch", "vocab")
+    if stats:
+        B = tokens.shape[0]
+        held = (jnp.zeros((B, 1), jnp.int32) if held is None
+                else jnp.sum(held, axis=0))
+        return logits, cache_new, expert_counts(
+            cfg, held, jnp.ones((B, 1), jnp.bool_))
     return logits, cache_new
 
 
@@ -592,9 +690,10 @@ def _hybrid_group_prefill(cfg, gp, x, sin, cos, ctx, pattern, cache_dtype):
 
 def lm_prefill(cfg, params, tokens, max_len: int, ctx=None,
                frontend_embeds=None, cache_dtype=jnp.bfloat16,
-               lengths=None):
+               lengths=None, stats: bool = False):
     """Prefill: run the trunk over the prompt and build the decode cache.
-    Returns (last_logits (B,V), cache).
+    Returns (last_logits (B,V), cache), and with `stats` the expert counters
+    of the prompts (`expert_counts`; positions past `lengths` are pads).
 
     `lengths` (B,) enables RIGHT-PADDED prompts (runtime/prefill.py bucket
     padding): the last-hidden gather happens at each row's true final
@@ -616,7 +715,7 @@ def lm_prefill(cfg, params, tokens, max_len: int, ctx=None,
         x = nn.embed_tokens(cfg, params["embed"], tokens)
         x = _act(ctx, x, "batch", "seq", None)
         S = x.shape[1]
-        sin, cos = nn.rope_tables(jnp.arange(S), cfg.head_dim, cfg.rope_theta)
+        sin, cos = _rope(cfg, jnp.arange(S))
         pat = tuple(cfg.block_pattern)
 
         def make_gbody(pattern):
@@ -639,14 +738,12 @@ def lm_prefill(cfg, params, tokens, max_len: int, ctx=None,
         raise NotImplementedError(
             "length-gathered prefill is incompatible with ring-buffer "
             "window caches: pad entries would wrap onto real slots")
-    h, kv, _ = lm_hidden(cfg, params, tokens, ctx, frontend_embeds,
-                         collect_kv=True)
+    h, kv, aux = lm_hidden(cfg, params, tokens, ctx, frontend_embeds,
+                           collect_kv=True, stats=stats)
     cache, _ = init_cache(cfg, B, max_len, cache_dtype)
-    k, v = kv   # (L, B, S, KV, hd)
-    cache["k"] = jax.lax.dynamic_update_slice(
-        cache["k"], k.astype(cache_dtype), (0, 0, 0, 0, 0))
-    cache["v"] = jax.lax.dynamic_update_slice(
-        cache["v"], v.astype(cache_dtype), (0, 0, 0, 0, 0))
+    # kv leaves (L, B, S, ...) fill the first S positions of the cache
+    cache = {n: jax.lax.dynamic_update_slice(
+        a, kv[n].astype(cache_dtype), (0,) * a.ndim) for n, a in cache.items()}
     if lengths is None:
         h_last = h[:, -1:, :]
     else:
@@ -655,6 +752,12 @@ def lm_prefill(cfg, params, tokens, max_len: int, ctx=None,
                        h.shape[1] - 1)
         h_last = jnp.take_along_axis(h, idx[:, None, None], axis=1)
     logits = nn.logits_from_hidden(cfg, params["embed"], h_last)[:, 0, :]
+    if stats:
+        S = tokens.shape[1]
+        real = (jnp.ones((B, S), jnp.bool_) if lengths is None else
+                jnp.arange(S)[None, :] < jnp.asarray(lengths)[:, None])
+        held = aux.get("moe_held", jnp.zeros((B, S), jnp.int32))
+        return logits, cache, expert_counts(cfg, held, real)
     return logits, cache
 
 

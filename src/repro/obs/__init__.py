@@ -152,8 +152,8 @@ class _MetricSpan:
     def __enter__(self):
         self._t0 = time.monotonic()
         if self.inner is not None:
-            self.inner.__enter__()
-        return self
+            return self.inner.__enter__()
+        return None
 
     def __exit__(self, *exc):
         if self.inner is not None:
@@ -167,7 +167,8 @@ def span(name: str, **args):
     """Stage span context manager: a Chrome-trace event when tracing is
     on, a stage-duration histogram sample when metrics are on (these are
     what the PR-9 estimator calibrates t_step/t_sync/tier costs from),
-    and the shared no-op when both are off."""
+    and the shared no-op when both are off. Entering gives the trace
+    event's args dict when tracing is on, else None."""
     tr = _trace
     if tr is None and not _metrics_on:
         return _NULL_SPAN

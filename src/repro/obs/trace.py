@@ -42,10 +42,12 @@ class TraceRecorder:
 
     @contextmanager
     def span(self, name: str, cat: str = "sedar", **args):
+        """Entering gives the span's args dict: a stage may add to it what
+        it learns before it ends (the event takes the args at its end)."""
         start = time.monotonic()
         try:
             with self._annotate(name):
-                yield
+                yield args
         finally:
             end = time.monotonic()
             ev = {

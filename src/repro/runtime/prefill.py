@@ -195,6 +195,9 @@ class BucketedPrefill:
       lanes   (K, 4) uint32  — per-prompt fused fingerprint over
                                {logits row, cache rows}
       verdict (K,) int32     — backend detection verdict (VERDICT_*)
+      moe     dict of int32  — expert counters of the pack (models that
+                               count experts only; dummy rows and pads
+                               excluded from the routes)
 
     Faults: `InjectionSpec(target='prefill')` flips one bit of pack row
     `leaf_idx`'s logits on the chosen replica (the admission analogue of
@@ -251,8 +254,14 @@ class BucketedPrefill:
         model = self.model
 
         def fn(params, toks, lengths, replica_id, armed, tick):
-            logits, cache = model.prefill(
-                params, {"tokens": toks, "lengths": lengths}, max_len)
+            counts = None
+            if model.counts_experts:
+                logits, cache, counts = model.prefill(
+                    params, {"tokens": toks, "lengths": lengths}, max_len,
+                    stats=True)
+            else:
+                logits, cache = model.prefill(
+                    params, {"tokens": toks, "lengths": lengths}, max_len)
             K, V = logits.shape
             if (spec is not None and spec.target == "prefill"
                     and spec.leaf_idx < K):
@@ -284,8 +293,11 @@ class BucketedPrefill:
             lanes = jax.vmap(lambda lg, row: pytree_fingerprint_fused(
                 {"logits": lg, "cache": row}))(logits, rows)
             tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            return {"tok": tok, "rows": rows, "lanes": lanes,
-                    "verdict": verdict}
+            out = {"tok": tok, "rows": rows, "lanes": lanes,
+                   "verdict": verdict}
+            if counts is not None:
+                out["moe"] = counts
+            return out
 
         return fn
 
@@ -361,7 +373,7 @@ class BucketedPrefill:
             raise ValueError("prompt overflows the bucket ladder")
         k = pack_for(n, self.max_pack)
         toks = np.zeros((k, bucket), np.int32)
-        lens = np.ones((k,), np.int32)       # dummy rows: length-1 zeros
+        lens = np.zeros((k,), np.int32)      # dummy rows: no real token
         for i, p in enumerate(prompts):
             toks[i, :len(p)] = p
             lens[i] = len(p)
@@ -377,7 +389,8 @@ class BucketedPrefill:
             r1 = prog(params, toks_d, lens_d, jnp.asarray(1, jnp.int32), a, t)
             verdict = _lane_verdict_jit(r0["lanes"], r1["lanes"])
         return {"tok": r0["tok"], "rows": r0["rows"], "lengths": lens_d,
-                "verdict": verdict, "n": n, "pack_size": k}
+                "verdict": verdict, "n": n, "pack_size": k,
+                "moe": r0.get("moe")}
 
 
 @jax.jit

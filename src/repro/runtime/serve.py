@@ -97,6 +97,9 @@ class BatchServeReport:
     stopped: bool = False
     prefill_packs: int = 0         # packed prefill launches (incl. retries)
     prefill_retries: int = 0       # per-prompt prefill re-executions
+    # expert counters of models that count them (`serve()` docstring):
+    # {"prefill"|"decode": {"routes", "routes_held", "rows"}}
+    expert_counts: Dict[str, Dict[str, int]] = field(default_factory=dict)
 
     @property
     def tokens_per_s(self) -> float:
@@ -108,6 +111,20 @@ class BatchServeReport:
         continuous-batching figure of merit (a synchronous wave loop burns
         steps decoding slots whose requests already finished)."""
         return self.tokens_emitted / max(self.steps, 1)
+
+
+# expert counters, in this order wherever they travel as a flat list
+COUNTERS = ("routes", "routes_held", "rows")
+
+
+def _expert_counts(values) -> Dict[str, Dict[str, int]]:
+    """Host values of the final readback's counters (decode's three, then
+    each prefill launch's three) -> {"prefill", "decode"} totals."""
+    vals = [int(v) for v in values]
+    n = len(COUNTERS)
+    return {"decode": dict(zip(COUNTERS, vals[:n])),
+            "prefill": {k: sum(vals[n + i::n]) for i, k in
+                        enumerate(COUNTERS)}}
 
 
 # The serving loop's programs carry fixed names: jit names each module
@@ -349,9 +366,15 @@ class SedarServer:
                     "kernel", "slot", "prefill", "prefill_kernel"):
                 params = inject_tree(params, spec, step=t,
                                      replica_id=replica_id, armed=armed)
-            logits, cache = jax.vmap(
-                lambda c, tk, p: model.decode_step(params, c, tk, p)
-            )(state["cache"], state["tok"], state["pos"])
+            if model.counts_experts:
+                logits, cache, counts = jax.vmap(
+                    lambda c, tk, p: model.decode_step(params, c, tk, p,
+                                                       stats=True)
+                )(state["cache"], state["tok"], state["pos"])
+            else:
+                logits, cache = jax.vmap(
+                    lambda c, tk, p: model.decode_step(params, c, tk, p)
+                )(state["cache"], state["tok"], state["pos"])
             logits = logits.reshape(n_slots, -1)          # (N, V)
             if spec is not None and spec.target == "slot":
                 # slot-localized SDC: flip one bit of ONE slot's logits
@@ -376,6 +399,13 @@ class SedarServer:
             cand = {"cache": cache, "tok": tok,
                     "pos": jnp.where(act, state["pos"] + 1, state["pos"]),
                     "active": act, "t": t + 1}
+            if model.counts_experts:
+                # routes of inactive slots are not counted; their rows ran
+                cand["moe"] = {
+                    k: state["moe"][k] + jnp.sum(
+                        counts[k] if k == "rows"
+                        else jnp.where(act, counts[k], 0))
+                    for k in COUNTERS}
             # aux = the emission pair: the engine's TokenRing parks these
             # refs per tick (DESIGN.md §18) — outputs the step computes
             # anyway, so parking adds no launch and no readback
@@ -495,7 +525,8 @@ class SedarServer:
 
     def _admit_pack(self, eng, dual, params, pairs, t: int, ring,
                     ring_on: bool, max_len: int, rep: BatchServeReport,
-                    sched, notify, events: List[DetectionEvent]):
+                    sched, notify, events: List[DetectionEvent],
+                    counts: Optional[list] = None):
         """Protected packed admission (DESIGN.md §14): ONE prefill launch
         computes caches + first tokens + per-prompt lanes for the whole
         pack, ONE `batched_get` reads back {tokens, verdicts}, ONE fused
@@ -503,7 +534,8 @@ class SedarServer:
         snapshots cut in one batched pass. A faulty row (lane mismatch /
         uncorrectable checksum residual) is retried ALONE — the clean rows
         of the pack are admitted immediately — and a persistent fault
-        exhausts the retry budget into a per-request rejection."""
+        exhausts the retry budget into a per-request rejection. Each
+        launch's expert counters (replica 0's) are appended to `counts`."""
         spec = self.inj_spec
         for slot, _req in pairs:
             ring.evict(slot)       # never resurrect a previous tenant
@@ -520,6 +552,8 @@ class SedarServer:
                 res = self.prefiller.protected_pack(params, prompts,
                                                     max_len, t)
             rep.prefill_packs += 1
+            if counts is not None and res["moe"] is not None:
+                counts.append(res["moe"])
             toks, verdicts = hostsync.batched_get(
                 [res["tok"], res["verdict"]], label="prefill_emit")
             good = [i for i in need if int(verdicts[i]) != 0]
@@ -712,7 +746,17 @@ class SedarServer:
         immediate rejection). `autotune` (a policy.Autotuner with
         mode="serve") live-retunes the lag at clean flush boundaries; the
         engine's reset() restores the configured lag for the next serve()
-        call."""
+        call.
+
+        Expert counters (models that count experts, `model.counts_experts`):
+        on the device, packed prefill and decode each count the routes of
+        real tokens over every routed expert (`routes`; pads, dummy pack
+        rows and inactive slots left out), those that land on experts held
+        here (`routes_held`), and the held-expert rows the layers ran (`rows`,
+        pads and inactive slots included); replica 0's counts under a dual
+        backend, re-executions included, the legacy exact-shape admission
+        not. They ride in the final flush's readback, once per call, into
+        `rep.expert_counts` and the `serve_finish` span's args."""
         from repro.runtime.emission import DetokenizeConsumer, TokenRing
         from repro.runtime.prefill import group_packs
         from repro.runtime.scheduler import (RUNNING, RequestQueue,
@@ -726,7 +770,7 @@ class SedarServer:
         # every host stage below opens its own span only when it has work;
         # no span covers a whole call, the tick loop or a whole tick
         with obs.span("serve_start", slots=slots,
-                      requests=len(requests)):
+                      requests=len(requests)) as start_args:
             for r in requests:
                 r.status, r.slot = "pending", None
                 r.tokens, r.token_times = [], []
@@ -774,6 +818,17 @@ class SedarServer:
                      "pos": jnp.zeros((slots,), jnp.int32),
                      "active": jnp.zeros((slots,), jnp.bool_),
                      "t": jnp.asarray(0, jnp.int32)}
+            counting = self.model.counts_experts
+            prefill_counts: Optional[list] = [] if counting else None
+            if counting:
+                state["moe"] = {k: jnp.asarray(0, jnp.int32)
+                                for k in COUNTERS}
+            if start_args is not None:
+                start_args.update(
+                    cache_bytes_slot=sum(x.size * x.dtype.itemsize
+                                         for x in jax.tree.leaves(cache1)),
+                    experts_held=(self.cfg.model.num_experts
+                                  if counting else 0))
             # device arrays in one slot's snapshot image: cache, tok, pos
             slot_arrays = len(jax.tree.leaves(state["cache"])) + 2
             dual = eng.executor.init_dual(state)
@@ -806,7 +861,8 @@ class SedarServer:
                         dual = self._admit_pack(eng, dual, params, chunk, t,
                                                 ring, ring_on, max_len, rep,
                                                 sched, notify_reject,
-                                                prefill_events)
+                                                prefill_events,
+                                                prefill_counts)
                     # longer than the ladder, or the legacy path
                     for slot, req in overflow:
                         dual = self._admit_slot(eng, dual, params, slot, req,
@@ -940,11 +996,23 @@ class SedarServer:
             t += 1
 
         with obs.span("serve_finish", step=t,
-                      draining=len(sched.draining_items())):
+                      draining=len(sched.draining_items())) as finish_args:
             # final flush: validates (and in drain mode DRAINS) the partial
             # window left when the loop exits — `final=True` forces the
-            # drain below the cadence so no token stays parked past the run
-            ev = eng.flush_deferred(final=True)
+            # drain below the cadence so no token stays parked past the run;
+            # the expert counters ride in its readback
+            extra = []
+            if counting:
+                moe = eng.executor.peek(dual, "moe")
+                extra = [moe[k] for k in COUNTERS] + [
+                    c[k] for c in prefill_counts for k in COUNTERS]
+            ev = eng.flush_deferred(final=True, extra=extra)
+            if counting:
+                rep.expert_counts = _expert_counts(eng.extra_values)
+                if finish_args is not None:
+                    finish_args.update(
+                        {f"moe_{k}_{phase}": v for phase, c in
+                         rep.expert_counts.items() for k, v in c.items()})
             if ev is not None:
                 dual = self._handle_event(
                     eng, recovery, sched, ring, ev, dual, rep, notify_reject,

@@ -158,14 +158,8 @@ def phase_serve(cfg, params, seed: int, buckets=SERVE_BUCKETS):
 
     srv = servers["fused"]
     eng = srv._batch_engines[next(iter(srv._batch_engines))][0]
-    cache1, _ = srv.model.init_cache(1, max_len)
-    state = jax.eval_shape(lambda: eng.executor.init_dual({
-        "cache": jax.tree.map(lambda x: jax.numpy.stack([x] * SERVE_SLOTS),
-                              cache1),
-        "tok": jax.numpy.zeros((SERVE_SLOTS, 1), jax.numpy.int32),
-        "pos": jax.numpy.zeros((SERVE_SLOTS,), jax.numpy.int32),
-        "active": jax.numpy.zeros((SERVE_SLOTS,), jax.numpy.bool_),
-        "t": jax.numpy.asarray(0, jax.numpy.int32)}))
+    state = jax.eval_shape(lambda: eng.executor.init_dual(
+        srv.packed_state(SERVE_SLOTS, max_len)))
     return eng.executor._validate_jit.lower(state["s"]).compile().as_text()
 
 

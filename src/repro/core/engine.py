@@ -225,12 +225,13 @@ class ReplicaExecutor:
         every leaf)."""
         return dual["r0"][key]
 
-    def slot_images(self, dual, keys: Tuple[str, ...]) -> Tuple[Any, ...]:
-        """Replica 0's entries `keys`, split along their leading slot axis
-        into one image per slot, in ONE launch (`sedar_slot_snapshot`):
+    def slot_images(self, dual, axes: Dict[str, int]) -> Tuple[Any, ...]:
+        """Replica 0's entries `axes` names, split along each one's slot
+        axis into one image per slot, in ONE launch (`sedar_slot_snapshot`):
         the serving loop's Tier-0 snapshot source (DESIGN.md §13). Every
         image is a fresh buffer that no donated state aliases."""
-        return sedar_slot_snapshot({k: dual["r0"][k] for k in keys}, False)
+        return sedar_slot_snapshot({k: dual["r0"][k] for k in axes},
+                                   tuple(axes.items()), False)
 
     def map_state(self, fn, dual, *others):
         """Apply `fn` to EVERY replica's logical state (driver-side state
@@ -449,30 +450,46 @@ def _slot_mismatch_event(eq, step: int,
                           detail=detail)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def sedar_slot_snapshot(entries, stacked: bool):
-    """Split `entries` along the slot axis into one image per slot. Under a
-    stacked layout (replica axis first) replica 0 is selected inside the
-    program, so XLA fuses the replica index into each slot's slice and no
-    whole-replica image is materialized. Every slot is extracted, so the
-    program has one shape for any number of running slots."""
-    lead = (0,) if stacked else ()
-    n = jax.tree.leaves(entries)[0].shape[len(lead)]
-    return tuple(jax.tree.map(lambda x, i=i: x[lead + (i,)], entries)
-                 for i in range(n))
+def slot_image(x, i, axis: int):
+    """Slot `i`'s image of a packed-state leaf whose slot axis is `axis`. A
+    leading slot axis stacks whole per-slot entries and is dropped; a later
+    one is a batch axis inside the entry (a layer-major cache's (L, slots,
+    T, ...)) and is kept with size 1, so a cache image is (L, 1, T, ...) in
+    either layout: the layout of a one-row prefill's cache."""
+    return jax.lax.index_in_dim(x, i, axis, keepdims=axis > 0)
 
 
-def slot_select(mask, new, old, n_slots: int, axis: int = 0):
-    """Per-slot pytree merge: `where(mask)` along the slot axis for leaves
-    that carry it (shape[axis] == n_slots); leaves WITHOUT a slot axis
-    (e.g. the global decode tick) adopt `new` unconditionally."""
-    def sel(a, b):
-        if a.ndim > axis and a.shape[axis] == n_slots:
-            m = jnp.reshape(mask, (1,) * axis + (n_slots,)
-                            + (1,) * (a.ndim - axis - 1))
-            return jnp.where(m, a, b)
-        return a
-    return jax.tree.map(sel, new, old)
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def sedar_slot_snapshot(entries, axes: Tuple[Tuple[str, int], ...],
+                        stacked: bool):
+    """Split `entries` into one image per slot (`slot_image`; `axes`: the
+    slot axis of each entry, as (key, axis) pairs). Under a stacked layout
+    (replica axis first) replica 0 is selected inside the program, so XLA
+    fuses the replica index into each slot's slice and no whole-replica
+    image is materialized. Every slot is extracted, so the program has one
+    shape for any number of running slots."""
+    axes = dict(axes)
+    if stacked:
+        entries = jax.tree.map(lambda x: x[0], entries)
+    k0 = next(iter(entries))
+    n = jax.tree.leaves(entries[k0])[0].shape[axes[k0]]
+    return tuple({k: jax.tree.map(
+        lambda x, i=i, a=axes[k]: slot_image(x, i, a), v)
+        for k, v in entries.items()} for i in range(n))
+
+
+def slot_select(mask, new, old, axes: Dict[str, int]):
+    """Per-slot merge of two packed states: `where(mask)` along the slot
+    axis of each entry, which `axes` gives by top-level key (it differs
+    between entries: a layer-major cache carries the slot on axis 1, the
+    tokens and positions on axis 0). Entries without a slot axis (e.g. the
+    global decode tick) adopt `new` unconditionally."""
+    def sel(axis, a, b):
+        m = jnp.reshape(mask, (1,) * axis + mask.shape
+                        + (1,) * (a.ndim - axis - 1))
+        return jnp.where(m, a, b)
+    return {k: (jax.tree.map(functools.partial(sel, axes[k]), v, old[k])
+                if k in axes else v) for k, v in new.items()}
 
 
 class SlottedSequentialExecutor(SequentialExecutor):
@@ -488,9 +505,9 @@ class SlottedSequentialExecutor(SequentialExecutor):
 
     name = "slotted"
 
-    def __init__(self, *args, n_slots: int = 1, **kw):
+    def __init__(self, *args, slot_axes: Dict[str, int], **kw):
         super().__init__(*args, **kw)
-        self.n_slots = int(n_slots)
+        self.slot_axes = dict(slot_axes)
 
     def execute(self, dual, batch, step: int, armed, compare: bool):
         outs, toe = self._launch_with_toe(dual, batch, step, armed)
@@ -504,8 +521,8 @@ class SlottedSequentialExecutor(SequentialExecutor):
             return {"r0": c0, "r1": c1}, aux0, None
         # fault path: the matching slots commit and only the faulty ones
         # stay pre-step
-        merged = {"r0": slot_select(eq, c0, dual["r0"], self.n_slots),
-                  "r1": slot_select(eq, c1, dual["r1"], self.n_slots)}
+        merged = {"r0": slot_select(eq, c0, dual["r0"], self.slot_axes),
+                  "r1": slot_select(eq, c1, dual["r1"], self.slot_axes)}
         return merged, aux0, _slot_mismatch_event(eq, step)
 
     def execute_deferred(self, dual, batch, step: int, armed,
@@ -621,8 +638,9 @@ class FusedSequentialExecutor(ReplicaExecutor):
     def peek(self, dual, key: str):
         return jax.tree.map(lambda x: x[0], dual["s"][key])
 
-    def slot_images(self, dual, keys: Tuple[str, ...]) -> Tuple[Any, ...]:
-        return sedar_slot_snapshot({k: dual["s"][k] for k in keys}, True)
+    def slot_images(self, dual, axes: Dict[str, int]) -> Tuple[Any, ...]:
+        return sedar_slot_snapshot({k: dual["s"][k] for k in axes},
+                                   tuple(axes.items()), True)
 
     def _beat(self, step: int) -> None:
         if self.watchdog is not None:
@@ -698,8 +716,9 @@ class SlottedFusedExecutor(FusedSequentialExecutor):
     def __init__(self, step_fn: Callable, state_fp_fn: Callable,
                  fast_state_fp_fn: Optional[Callable] = None,
                  watchdog: Optional[Watchdog] = None, donate: bool = True,
-                 n_slots: int = 1):
-        self.n_slots = int(n_slots)     # before _build_programs traces
+                 *, slot_axes: Dict[str, int]):
+        # before _build_programs traces; the replica stack leads each leaf
+        self.slot_axes = {k: a + 1 for k, a in slot_axes.items()}
         super().__init__(step_fn, state_fp_fn,
                          fast_state_fp_fn=fast_state_fp_fn,
                          watchdog=watchdog, donate=donate)
@@ -708,12 +727,12 @@ class SlottedFusedExecutor(FusedSequentialExecutor):
         return _slot_eq(fps[0], fps[1])              # (n_slots,)
 
     def _commit_gate(self, commit, cands, stacked):
-        # per-slot gate; slot axis is 1 (leaves stacked (replica, slot, …)).
-        # lax.cond keeps the all-matched fault-free path free of the
-        # per-leaf slot_select pass
+        # per-slot gate along each entry's slot axis, one further in under
+        # the replica stack. lax.cond keeps the all-matched fault-free path
+        # free of the per-leaf slot_select pass
         return jax.lax.cond(
             jnp.all(commit), lambda c, s: c,
-            lambda c, s: slot_select(commit, c, s, self.n_slots, axis=1),
+            lambda c, s: slot_select(commit, c, s, self.slot_axes),
             cands, stacked)
 
     def execute(self, dual, batch, step: int, armed, compare: bool):

@@ -274,7 +274,8 @@ def make_engine(sedar_cfg, *, backend: Optional[str] = None,
                 init_fn: Optional[Callable] = None,
                 notify: Optional[Callable] = None,
                 delay_source: Optional[Callable[[], dict]] = None,
-                donate: bool = True, slots: Optional[int] = None):
+                donate: bool = True,
+                slots: Optional[Dict[str, int]] = None):
     """Assemble a `SedarEngine` for one workload.
 
     backend: "none" | "sequential" | "fused" | "pod" | "vote" | "abft" |
@@ -284,11 +285,13 @@ def make_engine(sedar_cfg, *, backend: Optional[str] = None,
     for vote). "fused" runs both time-redundant replicas in ONE vmapped jit
     with the compare predicate on device (the zero-sync hot path, DESIGN.md
     §11; `donate` controls stacked-state buffer donation); step_fn must be
-    vmappable over (state, replica_id). `slots=N` selects the SLOT-GRANULAR
-    variants of the sequential/fused backends (continuous-batching serving,
-    DESIGN.md §13): step_fn then returns a PER-SLOT fingerprint (N, 4) and
-    commit mismatches are localized to sequence slots and partially
-    committed. abft/hybrid run replica-free:
+    vmappable over (state, replica_id). `slots` (the slot axis of each
+    packed-state entry that a failed slot restores, by top-level key)
+    selects the SLOT-GRANULAR variants of the sequential/fused backends
+    (continuous-batching serving, DESIGN.md §13): step_fn then returns a
+    PER-SLOT fingerprint (N, 4) and commit mismatches are localized to
+    sequence slots and partially committed along those axes. abft/hybrid
+    run replica-free:
     step_fn may return a 4th element (an `abft.ref.AbftReport` from
     checksummed kernels) and hybrid additionally validates the commit-time
     state fingerprint at the FSC boundary. `recovery`/`schedule`/`watchdog`
@@ -335,7 +338,7 @@ def make_engine(sedar_cfg, *, backend: Optional[str] = None,
         if slots:
             executor = SlottedFusedExecutor(
                 step_fn, state_fp_fn, fast_state_fp_fn=fast_state_fp_fn,
-                watchdog=watchdog, donate=donate, n_slots=slots)
+                watchdog=watchdog, donate=donate, slot_axes=slots)
         else:
             executor = FusedSequentialExecutor(
                 step_fn, state_fp_fn, fast_state_fp_fn=fast_state_fp_fn,
@@ -346,7 +349,7 @@ def make_engine(sedar_cfg, *, backend: Optional[str] = None,
         executor = SlottedSequentialExecutor(
             step_fn, state_fp_fn, fast_state_fp_fn=fast_state_fp_fn,
             watchdog=watchdog, toe_timeout_s=schedule.toe_timeout_s,
-            delay_source=delay_source, n_slots=slots)
+            delay_source=delay_source, slot_axes=slots)
     else:
         executor = SequentialExecutor(
             step_fn, state_fp_fn, fast_state_fp_fn=fast_state_fp_fn,

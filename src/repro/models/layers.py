@@ -263,7 +263,8 @@ def mla_absorbed_attention(p, q_nope, q_pe, c_cache, pe_cache, pos,
     """Decode form over the latent cache (B,T,R) and rope-key cache (B,T,dr):
     W_uk folds into the query, q_lat = W_uk^T q_nope (B,1,H,R), so scores are
     q_lat . c + q_pe . k_pe; W_uv folds into the output, applied once to the
-    attention-weighted latent. Positions > pos are masked."""
+    attention-weighted latent. Positions > pos are masked; `pos` is a scalar
+    or (B,), one position per row."""
     f32 = jnp.float32
     dt = q_nope.dtype
     T = c_cache.shape[1]
@@ -273,19 +274,56 @@ def mla_absorbed_attention(p, q_nope, q_pe, c_cache, pe_cache, pos,
     logits = (jnp.einsum("bshr,btr->bhst", q_lat, cf)
               + jnp.einsum("bshk,btk->bhst", q_pe.astype(f32),
                            pe_cache.astype(f32))) * scale
-    valid = jnp.arange(T) <= pos
-    logits = jnp.where(valid[None, None, None, :], logits, -1e30)
+    valid = _valid_rows(pos, T)
+    logits = jnp.where(valid[:, None, None, :], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
     ctx = jnp.einsum("bhst,btr->bshr", w, cf)
     return jnp.einsum("bshr,rhk->bshk", ctx.astype(dt),
                       p["wv_b"].astype(dt))
 
 
-def latent_cache_update(cache, rows, pos):
-    """Insert one token's latent rows ({c_kv, k_pe}: (B,1,...)) at `pos`."""
-    return {name: jax.lax.dynamic_update_slice(
-        cache[name], rows[name].astype(cache[name].dtype), (0, pos, 0))
-        for name in cache}
+def latent_cache_update(cache, rows, pos, *, layer=None):
+    """Insert one token's latent rows ({c_kv, k_pe}: (B,1,...)) at `pos`
+    (see `write_rows` for `pos` and `layer`)."""
+    return {name: write_rows(cache[name], rows[name], pos, layer=layer)
+            for name in cache}
+
+
+def write_rows(cache, new, pos, *, layer=None):
+    """Write one token's row of each batch row, `new` (B,1,...), into
+    `cache` (B,T,...) or, given `layer`, into layer `layer` of a
+    layer-stacked cache (L,B,T,...). `pos` is a scalar (every row at that
+    position) or (B,) (row b at pos[b]). Only those B rows are written, so
+    on a loop carry the write is in place. Positions clamp into [0, T) as
+    `dynamic_update_slice` clamps them."""
+    new = new.astype(cache.dtype)
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(pos) == 0:
+        start = lead + (0, pos) + (0,) * (new.ndim - 2)
+        return jax.lax.dynamic_update_slice(
+            cache, new if layer is None else new[None], start)
+    rows = jnp.arange(new.shape[0])
+    return cache.at[lead + (rows, pos)].set(new[:, 0], mode="clip")
+
+
+def layer_view(cache, layer):
+    """Layer `layer` of a layer-stacked cache {name: (L,B,T,...)}, or the
+    cache itself when `layer` is None (it is one layer's already)."""
+    if layer is None:
+        return cache
+    return {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for n, a in cache.items()}
+
+
+def _valid_rows(pos, T: int, window: int = 0):
+    """(B|1, T) mask of the cache positions a decode row attends: those at
+    or below its position (`pos` scalar or (B,)). A ring buffer of
+    T == window slots holds a live entry in every slot once pos+1 >= T."""
+    p = jnp.reshape(pos, (-1, 1))
+    valid = jnp.arange(T)[None, :] <= p
+    if window:
+        valid = jnp.logical_or(valid, p + 1 >= T)
+    return valid
 
 
 # ---------------------------------------------------------------------------
@@ -573,9 +611,9 @@ def window_qbody_probe(qt, ktp, vtp, idx, window: int):
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
-    """Single-token decode. q: (B,1,H,hd); caches: (B,T,KV,hd); pos: scalar
-    int32 (current position, 0-based). ``window>0`` -> ring-buffer cache of
-    size ``window`` (local attention)."""
+    """Single-token decode. q: (B,1,H,hd); caches: (B,T,KV,hd); pos: int32
+    scalar or (B,), each row's current position (0-based). ``window>0`` ->
+    ring-buffer cache of size ``window`` (local attention)."""
     B, _, H, hd = q.shape
     T = k_cache.shape[1]
     scale = 1.0 / math.sqrt(hd)
@@ -584,29 +622,46 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
     qg = q.reshape(B, 1, KV, G, hd)
     logits = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
                         k_cache.astype(jnp.float32)) * scale  # (B,KV,G,1,T)
-    slots = jnp.arange(T)
-    if window:
-        # Ring buffer of T == window slots: once pos+1 >= window every slot
-        # holds a live entry from the last `window` positions; before that,
-        # only slots 0..pos have been written. (The current token is written
-        # to slot pos % window *before* attention, so it attends to itself.)
-        valid = jnp.where(pos + 1 >= T, jnp.ones((T,), bool), slots <= pos)
-    else:
-        valid = slots <= pos
-    logits = jnp.where(valid[None, None, None, None, :], logits, -1e30)
+    # The current token is written to its slot (pos % window under a
+    # window) *before* attention, so it attends to itself.
+    valid = _valid_rows(pos, T, window)
+    logits = jnp.where(valid[:, None, None, None, :], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgst,btkh->bskgh", w, v_cache.astype(jnp.float32))
     return o.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def cache_update(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0):
-    """Insert one token's k/v at ``pos`` (ring slot ``pos % window`` if local)."""
-    slot = jnp.where(window > 0, pos % jnp.maximum(window, 1), pos) if window else pos
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_new.astype(k_cache.dtype), (0, slot, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v_new.astype(v_cache.dtype), (0, slot, 0, 0))
-    return k_cache, v_cache
+def decode_attention_rows(q, k_rows, v_rows, pos, *, window: int = 0):
+    """Single-token decode over caches of one row per token, (B,T,KV*hd),
+    each row the token's KV heads side by side. Each query head is laid
+    over the whole row, zero outside its own KV head's span, so that both
+    contractions run over the row's lanes as the cache holds them, with no
+    relayout of the cache, at KV times the multiply-adds. q: (B,1,H,hd);
+    pos and ``window`` as in `decode_attention`."""
+    f32 = jnp.float32
+    B, _, H, hd = q.shape
+    T, C = k_rows.shape[1], k_rows.shape[2]
+    KV = C // hd
+    own = jnp.repeat(jnp.eye(KV, dtype=f32), H // KV, axis=0)   # (H, KV)
+    qx = (q[:, 0, :, None, :].astype(f32)
+          * own[None, :, :, None]).reshape(B, H, C)
+    logits = jnp.einsum("bhc,btc->bht", qx, k_rows.astype(f32)) * (
+        1.0 / math.sqrt(hd))
+    logits = jnp.where(_valid_rows(pos, T, window)[:, None, :], logits,
+                       -1e30)
+    w = jax.nn.softmax(logits, axis=-1)
+    o = jnp.einsum("bht,btc->bhc", w, v_rows.astype(f32))
+    o = jnp.einsum("bhkd,hk->bhd", o.reshape(B, H, KV, hd), own)
+    return o[:, None].astype(q.dtype)
+
+
+def cache_update(k_cache, v_cache, k_new, v_new, pos, *, window: int = 0,
+                 layer=None):
+    """Insert one token's k/v at ``pos`` (ring slot ``pos % window`` if
+    local); see `write_rows` for ``pos`` and ``layer``."""
+    slot = pos % window if window else pos
+    return (write_rows(k_cache, k_new, slot, layer=layer),
+            write_rows(v_cache, v_new, slot, layer=layer))
 
 
 # ---------------------------------------------------------------------------

@@ -380,7 +380,15 @@ def _cache_rows(cfg, ap, h, sin, cos):
         return nn.mla_latent(cfg, ap, h, sin, cos)
     _, k, v = nn.qkv_project(cfg, ap, h)
     k = nn.apply_rope(k, sin, cos)
-    return {"k": k, "v": v}
+    return {"k": _kv_rows(k), "v": _kv_rows(v)}
+
+
+def _kv_rows(x):
+    """(B,S,KV,hd) keys or values -> a dense cache's rows (B,S,KV*hd): one
+    row per token, its KV heads side by side, as wide as a lane tile for
+    narrow GQA (qwen2: 2 x 64), so that the cache keeps one layout at rest
+    and in the decode loop."""
+    return x.reshape(x.shape[:2] + (-1,))
 
 
 def expert_counts(cfg, held, real):
@@ -498,27 +506,31 @@ def init_cache(cfg, batch: int, max_len: int, cache_dtype=jnp.bfloat16):
         axes = {"c_kv": ("layers", "batch", None, None),
                 "k_pe": ("layers", "batch", None, None)}
         return cache, axes
-    cache = {"k": jnp.zeros((L, batch, max_len, KV, hd), cache_dtype),
-             "v": jnp.zeros((L, batch, max_len, KV, hd), cache_dtype)}
-    axes = {"k": ("layers", "batch", None, "kv_heads", "head_dim"),
-            "v": ("layers", "batch", None, "kv_heads", "head_dim")}
+    cache = {"k": jnp.zeros((L, batch, max_len, KV * hd), cache_dtype),
+             "v": jnp.zeros((L, batch, max_len, KV * hd), cache_dtype)}
+    axes = {"k": ("layers", "batch", None, "kv_heads"),
+            "v": ("layers", "batch", None, "kv_heads")}
     return cache, axes
 
 
-def _attn_decode(cfg, lp, x, c, sin, cos, pos, ctx, window: int = 0):
+def _attn_decode(cfg, lp, x, c, sin, cos, pos, ctx, window: int = 0,
+                 layer=None):
     """One attention block, single token, over one layer's cache c: {k, v}
-    (B,T,KV,hd), or under MLA the latent cache {c_kv, k_pe}, attended in the
-    absorbed form. Returns (y, c_new)."""
+    as rows (B,T,KV*hd) or per head (B,T,KV,hd), or under MLA the latent
+    cache {c_kv, k_pe}, attended in the absorbed form. With `layer`, c is
+    the layer-stacked cache (L,B,T,...): the block writes its token's rows
+    into layer `layer` in place and attends that layer. pos: scalar, or (B,)
+    one position per row. Returns (y, c_new)."""
     h = nn.rms_norm(x, lp["ln1"] if "ln1" in lp else lp["ln"], cfg.norm_eps)
     ap = lp["attn"] if "attn" in lp else lp["core"]
     if cfg.kv_lora_rank:
         q_nope, q_pe = nn.mla_query(cfg, ap, h, sin, cos)
         c = nn.latent_cache_update(c, nn.mla_latent(cfg, ap, h, sin, cos),
-                                   pos)
-        o = nn.mla_absorbed_attention(ap, q_nope, q_pe, c["c_kv"], c["k_pe"],
-                                      pos, nn.attention_scale(cfg))
+                                   pos, layer=layer)
+        cl = nn.layer_view(c, layer)
+        o = nn.mla_absorbed_attention(ap, q_nope, q_pe, cl["c_kv"],
+                                      cl["k_pe"], pos, nn.attention_scale(cfg))
         return x + nn.out_project(cfg, ap, o), c
-    kc, vc = c["k"], c["v"]
     q, k, v = nn.qkv_project(cfg, ap, h)
     q = nn.apply_rope(q, sin, cos)
     k = nn.apply_rope(k, sin, cos)
@@ -535,21 +547,34 @@ def _attn_decode(cfg, lp, x, c, sin, cos, pos, ctx, window: int = 0):
         q = _act(ctx, q, "batch", None, None, "head_dim")
         k = _act(ctx, k, "batch", None, None, "head_dim")
         v = _act(ctx, v, "batch", None, None, "head_dim")
-    kc, vc = nn.cache_update(kc, vc, k, v, pos, window=window)
-    o = nn.decode_attention(q, kc, vc, pos, window=window)
+    rows = c["k"].ndim == 3 + (layer is not None)
+    if rows:
+        k, v = _kv_rows(k), _kv_rows(v)
+    kc, vc = nn.cache_update(c["k"], c["v"], k, v, pos, window=window,
+                             layer=layer)
+    cl = nn.layer_view({"k": kc, "v": vc}, layer)
+    attend = nn.decode_attention_rows if rows else nn.decode_attention
+    o = attend(q, cl["k"], cl["v"], pos, window=window)
     o = _act(ctx, o, "batch", None, None, None)
     o = nn.out_project(cfg, ap, o)
     return x + _act(ctx, o, "batch", None, None), {"k": kc, "v": vc}
 
 
 def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None,
-                   stats: bool = False):
-    """One serve step. tokens: (B,) int32; pos: scalar int32 (0-based absolute
-    position of this token). Returns (logits (B,V), new_cache), and with
-    `stats` the expert counters of the step's tokens (`expert_counts`)."""
+                   stats: bool = False, real=None):
+    """One serve step. tokens: (B,) int32; pos: int32, the 0-based absolute
+    position of this token, a scalar for every row or (B,) one per row.
+    Returns (logits (B,V), new_cache), and with `stats` the expert counters
+    of the step's tokens (`expert_counts`; `real` (B,) bool marks the rows
+    whose routes count, default every row).
+
+    A layer-stacked cache (the dense families: leaves (L,B,T,...)) is the
+    layer loop's carry, and each layer writes one row per batch row into
+    it in place; the `block_pattern` families carry per-group states."""
     x = nn.embed_tokens(cfg, params["embed"], tokens[:, None])   # (B,1,D)
     x = _act(ctx, x, "batch", None, None)
-    sin, cos = _rope(cfg, pos[None] if jnp.ndim(pos) == 0 else pos)
+    sin, cos = _rope(cfg, jnp.reshape(pos, (-1, 1)) if jnp.ndim(pos)
+                     else pos[None])
     held = None
 
     if cfg.block_pattern:
@@ -608,9 +633,9 @@ def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None,
                                        (params["tail"], cache["tail"]))
             cache_new["tail"] = tail_new
     else:
-        # The KV cache is a loop CARRY updated in place with
-        # dynamic_update_index (single buffer), NOT a scan xs->ys pair —
-        # the xs/ys form double-buffers the multi-GB cache (§Perf C8).
+        # The KV cache is a loop CARRY that each layer writes one row per
+        # batch row into (single buffer), NOT a scan xs->ys pair — the
+        # xs/ys form double-buffers the multi-GB cache (§Perf C8).
         # Layer li of the cache is dense layer li for li < Ld, else MoE
         # layer li - Ld.
         L, Ld = cfg.num_layers, _dense_lead(cfg)
@@ -618,12 +643,9 @@ def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None,
         def body(carry, sl):
             y, cc = carry
             lp, li = sl
-            c = {n: jax.lax.dynamic_index_in_dim(a, li, 0, keepdims=False)
-                 for n, a in cc.items()}
-            y, c2 = _attn_decode(cfg, lp, y, c, sin, cos, pos, ctx)
+            y, cc = _attn_decode(cfg, lp, y, cc, sin, cos, pos, ctx,
+                                 layer=li)
             y, aux = _mlp_sub(cfg, lp, y, ctx)
-            cc = {n: jax.lax.dynamic_update_index_in_dim(
-                a, c2[n].astype(a.dtype), li, 0) for n, a in cc.items()}
             return (y, cc), (aux.get("moe_held") if stats else None)
 
         carry = (x, cache)
@@ -640,8 +662,9 @@ def lm_decode_step(cfg, params, cache, tokens, pos, ctx=None,
         B = tokens.shape[0]
         held = (jnp.zeros((B, 1), jnp.int32) if held is None
                 else jnp.sum(held, axis=0))
-        return logits, cache_new, expert_counts(
-            cfg, held, jnp.ones((B, 1), jnp.bool_))
+        real = (jnp.ones((B, 1), jnp.bool_) if real is None
+                else jnp.reshape(real, (B, 1)))
+        return logits, cache_new, expert_counts(cfg, held, real)
     return logits, cache_new
 
 

@@ -48,6 +48,7 @@ dies (the paper's L1 guarantee, re-scoped).
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -130,13 +131,17 @@ def _expert_counts(values) -> Dict[str, Dict[str, int]]:
 # The serving loop's programs carry fixed names: jit names each module
 # `jit_<function name>`, and the profiler's `XLA Modules` line shows it.
 
-@jax.jit
-def sedar_slot_write(state, slot, cache_sl, tok_sl, pos_sl, active):
-    """One fused scatter of a slot slice into the packed state (dynamic
-    slot index). Jitted module-level so admissions/rollbacks cost one
-    dispatch per replica instead of one per cache leaf."""
+@functools.partial(jax.jit, static_argnames=("cache_axis",))
+def sedar_slot_write(state, slot, cache_sl, tok_sl, pos_sl, active, *,
+                     cache_axis: int):
+    """One fused scatter of a slot's image (`slot_image`: cache leaves
+    (L, 1, T, ...)) into the packed state at a dynamic slot index, along
+    the cache's slot axis `cache_axis`. Jitted module-level so
+    admissions/rollbacks cost one dispatch per replica instead of one per
+    cache leaf."""
     cache = jax.tree.map(
-        lambda full, s: full.at[slot].set(s.astype(full.dtype)),
+        lambda full, s: jax.lax.dynamic_update_index_in_dim(
+            full, s.astype(full.dtype), slot, cache_axis),
         state["cache"], cache_sl)
     return {**state, "cache": cache,
             "tok": state["tok"].at[slot].set(tok_sl.astype(jnp.int32)),
@@ -149,16 +154,25 @@ def sedar_set_active(state, slot, value):
     return {**state, "active": state["active"].at[slot].set(value)}
 
 
-@jax.jit
-def sedar_pack_insert(state, slots, sel, rows, toks, poss):
+@functools.partial(jax.jit, static_argnames=("cache_axis",))
+def sedar_pack_insert(state, slots, sel, rows, toks, poss, *,
+                      cache_axis: int):
     """Vectorized admission scatter: pack rows `sel` of a protected prefill
     launch land in slots `slots` of the packed state in ONE fused program
     (maxtext's prefill_insert_batch shape) — cache rows, first tokens,
     positions and the active mask together, instead of one `sedar_slot_write`
-    dispatch per admitted request."""
-    cache = jax.tree.map(
-        lambda full, r: full.at[slots].set(r[sel].astype(full.dtype)),
-        state["cache"], rows)
+    dispatch per admitted request. The rows come as slot images (row i:
+    (L, 1, T, ...)); a layer-major cache takes them onto its slot axis
+    `cache_axis` inside this program."""
+    idx = (slice(None),) * cache_axis + (slots,)
+
+    def put(full, r):
+        r = r[sel].astype(full.dtype)
+        if cache_axis:
+            r = jnp.moveaxis(jnp.squeeze(r, cache_axis + 1), 0, cache_axis)
+        return full.at[idx].set(r)
+
+    cache = jax.tree.map(put, state["cache"], rows)
     return {**state, "cache": cache,
             "tok": state["tok"].at[slots].set(toks[sel].astype(jnp.int32)),
             "pos": state["pos"].at[slots].set(poss[sel].astype(jnp.int32)),
@@ -349,16 +363,88 @@ class SedarServer:
     # Continuous-batching protected decode (DESIGN.md §13)
     # ------------------------------------------------------------------
 
+    @functools.cached_property
+    def decode_layout(self) -> str:
+        """How the packed decode holds and steps its slots, read off the
+        cache structure the model builds (DESIGN.md §13). "layer_major": a
+        layer-stacked K/V or latent cache, whose leaves (L, B, T, ...) take
+        the slots as their batch axis; every slot decodes in one batch with
+        its own position, each layer writing its rows in place.
+        "slot_vmap": per-group recurrent or window states, stacked one
+        `init_cache(1, ...)` per slot and decoded under a vmap over slots."""
+        cache = jax.eval_shape(lambda: self.model.init_cache(1, 8)[0])
+        flat = all(not isinstance(v, dict) for v in cache.values())
+        return "layer_major" if flat else "slot_vmap"
+
+    @property
+    def slot_axes(self) -> Dict[str, int]:
+        """The slot axis of each packed-state entry that has one: the cache
+        carries it on axis 1 when layer-major, every other entry on 0."""
+        return {"cache": int(self.decode_layout == "layer_major"),
+                "tok": 0, "pos": 0, "active": 0}
+
+    def _gate_axes(self) -> Dict[str, int]:
+        """The entries a slotted commit gate restores for a slot that
+        failed. A layer-major cache is left out: a decode tick writes each
+        slot's cache at one row only, the row at the slot's position, and a
+        row at or past a slot's position is no part of its state (decode
+        writes it before attending it, as it does the pads of a packed
+        prefill). A failed slot keeps its position, so restoring `pos`
+        restores its cache; the gate never selects, nor keeps a second copy
+        of, the whole cache."""
+        axes = dict(self.slot_axes)
+        if self.decode_layout == "layer_major":
+            del axes["cache"]
+        return axes
+
+    def packed_state(self, slots: int, max_len: int) -> Dict[str, Any]:
+        """The empty packed decode state of `slots` sequence slots: the
+        cache (`decode_layout`), each slot's next token, position and
+        active flag, the decode tick, and the expert counters of models
+        that count them."""
+        if self.decode_layout == "layer_major":
+            cache, _ = self.model.init_cache(slots, max_len)
+        else:
+            cache1, _ = self.model.init_cache(1, max_len)
+            cache = jax.tree.map(lambda x: jnp.stack([x] * slots), cache1)
+        state = {"cache": cache,
+                 "tok": jnp.zeros((slots, 1), jnp.int32),
+                 "pos": jnp.zeros((slots,), jnp.int32),
+                 "active": jnp.zeros((slots,), jnp.bool_),
+                 "t": jnp.asarray(0, jnp.int32)}
+        if self.model.counts_experts:
+            state["moe"] = {k: jnp.asarray(0, jnp.int32) for k in COUNTERS}
+        return state
+
     def _make_packed_decode(self, n_slots: int):
         """Packed step_fn over N sequence slots, each with its own cache
-        slice / token / position. Returns per-slot fingerprints (N, 4) so
+        rows / token / position. Returns per-slot fingerprints (N, 4) so
         the slotted executors localize mismatches to slots. Inactive slots
         are excluded from the fingerprint (their rows are zeroed) and their
         positions are frozen; their cache garbage is unobservable — a
-        refill overwrites the whole slice at prefill."""
+        refill overwrites the whole slice at prefill. A layer-major cache
+        decodes every slot in ONE batched model step with a position per
+        row; other caches vmap the model step over slots."""
         spec = self.inj_spec
         abft_guard = self.backend in ("abft", "hybrid")
         model = self.model
+        batched = self.decode_layout == "layer_major"
+
+        def model_step(params, cache, tok, pos, act):
+            kw = {"stats": True} if model.counts_experts else {}
+            if batched:
+                if model.counts_experts:
+                    kw["real"] = act
+                return model.decode_step(params, cache, tok[:, 0], pos, **kw)
+            out = jax.vmap(lambda c, tk, p: model.decode_step(
+                params, c, tk, p, **kw))(cache, tok, pos)
+            if model.counts_experts:
+                # routes of inactive slots are not counted; their rows ran
+                counts = {k: jnp.sum(v if k == "rows"
+                                     else jnp.where(act, v, 0))
+                          for k, v in out[2].items()}
+                return out[0], out[1], counts
+            return out
 
         def sedar_decode_step(state, params, replica_id, armed):
             t = state["t"]
@@ -366,16 +452,10 @@ class SedarServer:
                     "kernel", "slot", "prefill", "prefill_kernel"):
                 params = inject_tree(params, spec, step=t,
                                      replica_id=replica_id, armed=armed)
-            if model.counts_experts:
-                logits, cache, counts = jax.vmap(
-                    lambda c, tk, p: model.decode_step(params, c, tk, p,
-                                                       stats=True)
-                )(state["cache"], state["tok"], state["pos"])
-            else:
-                logits, cache = jax.vmap(
-                    lambda c, tk, p: model.decode_step(params, c, tk, p)
-                )(state["cache"], state["tok"], state["pos"])
-            logits = logits.reshape(n_slots, -1)          # (N, V)
+            act = state["active"]
+            out = model_step(params, state["cache"], state["tok"],
+                             state["pos"], act)
+            logits, cache = out[0].reshape(n_slots, -1), out[1]   # (N, V)
             if spec is not None and spec.target == "slot":
                 # slot-localized SDC: flip one bit of ONE slot's logits
                 # (spec.leaf_idx doubles as the slot index) on the chosen
@@ -392,7 +472,6 @@ class SedarServer:
             if abft_guard:
                 logits, report = _logits_checksum_guard(logits, spec, t,
                                                         armed)
-            act = state["active"]
             fp = jax.vmap(tensor_fingerprint)(logits)     # (N, 4)
             fp = jnp.where(act[:, None], fp, jnp.zeros_like(fp))
             tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
@@ -400,12 +479,8 @@ class SedarServer:
                     "pos": jnp.where(act, state["pos"] + 1, state["pos"]),
                     "active": act, "t": t + 1}
             if model.counts_experts:
-                # routes of inactive slots are not counted; their rows ran
-                cand["moe"] = {
-                    k: state["moe"][k] + jnp.sum(
-                        counts[k] if k == "rows"
-                        else jnp.where(act, counts[k], 0))
-                    for k in COUNTERS}
+                cand["moe"] = {k: state["moe"][k] + out[2][k]
+                               for k in COUNTERS}
             # aux = the emission pair: the engine's TokenRing parks these
             # refs per tick (DESIGN.md §18) — outputs the step computes
             # anyway, so parking adds no launch and no readback
@@ -423,7 +498,12 @@ class SedarServer:
         from repro.checkpoint.tiers import SlotRing
         ring = SlotRing(slots_per_key=4)
         recovery = SlotRecovery(ring, max_retries=self.max_retries)
-        step = jax.jit(self._make_packed_decode(slots))
+        # the unprotected backend keeps no pre-step state, so its state is
+        # donated and the decode writes the cache in place; the replica
+        # backends keep the pre-step state for their gate (fused donates
+        # inside its own program)
+        step = jax.jit(self._make_packed_decode(slots),
+                       donate_argnums=(0,) if self.backend == "none" else ())
         eng = make_engine(
             self.cfg.sedar,
             backend=self.backend,
@@ -438,7 +518,8 @@ class SedarServer:
             recovery=recovery,
             inj_spec=self.inj_spec, inj_flag=self.inj_flag,
             notify=lambda e: None,
-            slots=slots if self.backend in ("sequential", "fused") else None)
+            slots=(self._gate_axes() if self.backend in ("sequential", "fused")
+                   else None))
         self._batch_engines[key] = (eng, ring, recovery)
         return eng, ring, recovery
 
@@ -453,9 +534,10 @@ class SedarServer:
         tok_sl = jnp.asarray(sl["tok"])
         pos_sl = jnp.asarray(sl["pos"])
         act = jnp.asarray(active, jnp.bool_)
+        axis = self.slot_axes["cache"]
         dual = eng.executor.map_state(
             lambda st: sedar_slot_write(st, slot_d, cache_sl, tok_sl,
-                                        pos_sl, act), dual)
+                                        pos_sl, act, cache_axis=axis), dual)
         eng.executor.note_external_update()
         return dual
 
@@ -467,12 +549,12 @@ class SedarServer:
         eng.executor.note_external_update()
         return dual
 
-    @staticmethod
-    def _save_images(eng, dual, ring, version: int, slots) -> None:
+    def _save_images(self, eng, dual, ring, version: int, slots) -> None:
         """ONE program launch cuts every slot's {cache, tok, pos} image out
         of the executor's resident state; the ring keeps the images of
         `slots` as they are (fresh buffers) and the rest are dropped."""
-        images = eng.executor.slot_images(dual, ("cache", "tok", "pos"))
+        images = eng.executor.slot_images(
+            dual, {k: self.slot_axes[k] for k in ("cache", "tok", "pos")})
         ring.save_many(version, {slot: images[slot] for slot in slots})
 
     def _snapshot_slots(self, eng, dual, sched, ring, version: int,
@@ -562,9 +644,11 @@ class SedarServer:
                 rows, toks_d, poss = res["rows"], res["tok"], res["lengths"]
                 sel = jnp.asarray(good, jnp.int32)
                 slots_d = jnp.asarray([pairs[i][0] for i in good], jnp.int32)
+                axis = self.slot_axes["cache"]
                 dual = eng.executor.map_state(
                     lambda st: sedar_pack_insert(st, slots_d, sel, rows,
-                                                 toks_d, poss), dual)
+                                                 toks_d, poss,
+                                                 cache_axis=axis), dual)
                 eng.executor.note_external_update()
                 if ring_on:
                     # the flush edges' program, on the state the rows were
@@ -811,24 +895,17 @@ class SedarServer:
 
             sched = SlotScheduler(slots, RequestQueue(queue_depth))
             pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
-            cache1, _ = self.model.init_cache(1, max_len)
-            state = {"cache": jax.tree.map(
-                         lambda x: jnp.stack([x] * slots), cache1),
-                     "tok": jnp.zeros((slots, 1), jnp.int32),
-                     "pos": jnp.zeros((slots,), jnp.int32),
-                     "active": jnp.zeros((slots,), jnp.bool_),
-                     "t": jnp.asarray(0, jnp.int32)}
+            state = self.packed_state(slots, max_len)
             counting = self.model.counts_experts
             prefill_counts: Optional[list] = [] if counting else None
-            if counting:
-                state["moe"] = {k: jnp.asarray(0, jnp.int32)
-                                for k in COUNTERS}
             if start_args is not None:
                 start_args.update(
-                    cache_bytes_slot=sum(x.size * x.dtype.itemsize
-                                         for x in jax.tree.leaves(cache1)),
+                    cache_bytes_slot=sum(
+                        x.size * x.dtype.itemsize
+                        for x in jax.tree.leaves(state["cache"])) // slots,
                     experts_held=(self.cfg.model.num_experts
-                                  if counting else 0))
+                                  if counting else 0),
+                    decode_layout=self.decode_layout)
             # device arrays in one slot's snapshot image: cache, tok, pos
             slot_arrays = len(jax.tree.leaves(state["cache"])) + 2
             dual = eng.executor.init_dual(state)

@@ -231,14 +231,9 @@ def test_fused_replicas_route_alike_and_a_slot_fault_rolls_back():
                       max_pack=2)
     params = srv.model.init(jax.random.PRNGKey(1))
     eng, _ring, _rec = srv._batch_engine(2, 32, 4)
-    state = {"cache": jax.tree.map(lambda x: jnp.stack([x] * 2),
-                                   srv.model.init_cache(1, 32)[0]),
+    state = {**srv.packed_state(2, 32),
              "tok": jnp.asarray([[3], [9]], jnp.int32),
-             "pos": jnp.asarray([0, 0], jnp.int32),
-             "active": jnp.asarray([True, True]),
-             "t": jnp.asarray(0, jnp.int32),
-             "moe": {k: jnp.asarray(0, jnp.int32)
-                     for k in ("routes", "routes_held", "rows")}}
+             "active": jnp.asarray([True, True])}
     dual = eng.executor.init_dual(state)
     for step in range(3):
         dual, eq, _aux = eng.executor._launch(dual, params, step, False, True)

@@ -15,6 +15,7 @@ from repro.checkpoint import count_disk_reads
 from repro.configs import RunConfig, TrainConfig, get_config, \
     reduce_for_smoke
 from repro.core import hostsync
+from repro.core.engine import slot_image
 from repro.core.injection import InjectionSpec
 from repro.runtime.scheduler import synthetic_requests
 from repro.runtime.serve import SedarServer
@@ -331,7 +332,8 @@ def test_flush_snapshot_is_one_launch_of_primary_slot_images(
                 continue
             got_v, got = ring.restore(slot, max_step=version)
             assert got_v == version
-            ref = jax.tree.map(lambda x: x[slot], want)
+            ref = {k: jax.tree.map(lambda x, a=srv.slot_axes[k]: np.asarray(
+                slot_image(x, slot, a)), v) for k, v in want.items()}
             assert jax.tree.structure(got) == jax.tree.structure(ref)
             for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
                 a = np.asarray(a)

@@ -1,10 +1,14 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached, at qwen2-0.5b widths (what interpret mode cannot show:
-tiling, VMEM and lowering rules), plus the CPU interpret-mode check of the
-fused state fingerprint under the engine's replica vmap.
+tiling, VMEM and lowering rules), and the serving decode step, whose
+optimized program must not copy the cache; plus the CPU interpret-mode
+check of the fused state fingerprint under the engine's replica vmap.
 
 The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs these tests loads the TPU compiler library."""
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,9 +17,12 @@ from jax.sharding import SingleDeviceSharding
 
 import repro.kernels.fingerprint as kfp
 from repro.abft.kernels import abft_flash_attention, matmul_pallas
+from repro.configs import (RunConfig, SedarConfig, TrainConfig,
+                           get_config)
 from repro.core.fingerprint import pytree_fingerprint_fused
 from repro.kernels.fingerprint import fingerprint_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.runtime.serve import SedarServer
 
 VOCAB, D, DFF, H, KV, HD = 151_936, 896, 4_864, 14, 2, 64
 SLOTS = 4
@@ -96,6 +103,51 @@ def test_abft_kernels_compile(one_chip):
     txt = _compile_text(lambda q, k, v: abft_flash_attention(
         q, k, v, interpret=False)[0], q, kv, kv)
     assert "tpu_custom_call" in txt
+
+
+def _copies(txt: str):
+    """(element count, line) of every copy in optimized HLO text."""
+    out = []
+    for line in txt.splitlines():
+        m = re.search(r"= \(?\w+\[([\d,]*)\]\S* copy(-start)?\(", line)
+        if m:
+            out.append((math.prod(int(d) for d in m.group(1).split(",")
+                                  if d), line.strip()[:160]))
+    return out
+
+
+@pytest.mark.parametrize("backend", ["none", "fused"])
+def test_packed_decode_copies_no_whole_cache_leaf(one_chip, backend):
+    """The serving decode step (`jit_sedar_decode_step`, and under fused
+    `jit_sedar_fused_decode_step` with its per-slot commit gate) of
+    qwen2-0.5b as the benchmark serves it, 16 slots of 2,312 positions (a
+    cache too large to stage in VMEM whole): the layer loop writes each
+    slot's row into the layer-major cache in place and reads it as it lies,
+    so no copy in the optimized program is as large as one replica's cache
+    leaf (a relayout of the cache into or out of the loop, or a second
+    buffer for the gate's pre-step state)."""
+    rc = RunConfig(model=get_config("qwen2-0.5b"), train=TrainConfig(),
+                   sedar=SedarConfig(validate_lag=8))
+    srv = SedarServer(rc, backend=backend)
+    slots, T = 16, 2312
+    eng = srv._batch_engine(slots, T, 8)[0]
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, srv.model.abstract_params()[0])
+    state = jax.eval_shape(lambda: srv.packed_state(slots, T))
+    leaf = max(a.size for a in jax.tree.leaves(state["cache"]))
+    flag = jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip)
+    if backend == "fused":
+        stacked = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            (2,) + a.shape, a.dtype, sharding=one_chip), state)
+        lowered = eng.executor._step_gated.lower(stacked, params, flag, flag)
+    else:
+        rid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        lowered = eng.executor.step_fn.lower(
+            jax.tree.map(on_chip, state), params, rid, flag)
+    big = [line for n, line in _copies(lowered.compile().as_text())
+           if n >= leaf]
+    assert not big, big
 
 
 def test_fused_fingerprint_pallas_vmapped_matches_jnp():

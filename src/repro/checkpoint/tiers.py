@@ -53,8 +53,8 @@ TIER_ORDER = ("device", "host", "disk", "partner")
 # plus being the last line of defense. `rework_weight` prices one step of
 # lost progress — so a ring slot `k` steps older than a disk version wins
 # until the rework gap outgrows the deserialization saving. Callers can
-# override with measured costs (benchmarks/bench_checkpoint.py measures
-# them; temporal_model.TierCosts models them in hours).
+# override with measured costs (temporal_model.TierCosts models them in
+# hours).
 DEFAULT_RESTORE_COSTS = {"device": 1.0, "host": 4.0,
                          "disk": 64.0, "partner": 96.0}
 DEFAULT_REWORK_WEIGHT = 1.0

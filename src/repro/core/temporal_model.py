@@ -207,7 +207,7 @@ def aet_deferred(p: SedarParams, D: int, mtbe: float, X: float = 0.5) -> float:
     faults and pays the D/2-step discard for EACH of them. Without the
     extra term the model would always prefer the longest window under
     fault storms — exactly when long windows are most expensive (pinned
-    against a measured-cost simulation in bench_autotune)."""
+    against a simulated calm-then-storm run in tests/test_autotune.py)."""
     extra = max(p.T_prog / mtbe - 1.0, 0.0) * deferred_waste(p, D)
     return aet(deferred_fp(p, D, X) + extra, deferred_fa(p, D),
                p.T_prog, mtbe)
@@ -255,7 +255,7 @@ def default_tier_costs(p: SedarParams) -> dict:
     ring is ~2 orders of magnitude cheaper than serialization (pure HBM
     copies), the host ring ~1 order (one batched D2H, no serialization),
     the partner tier doubles the disk cost (second independent copy).
-    Replace with bench_checkpoint.py measurements when available."""
+    Replace with measured tier costs when available."""
     return {
         "device": TierCosts(t_save=p.t_cs / 256.0, t_restore=p.t_cs / 256.0,
                             slots=4),
@@ -564,7 +564,7 @@ def fail_in_place_beats_restart(p: SedarParams, outage_hours: float,
 
 
 # ---------------------------------------------------------------------------
-# Paper Table 3 parameter sets (for validation + benchmarks)
+# Paper Table 3 parameter sets (for validation against the paper)
 # ---------------------------------------------------------------------------
 
 PAPER_TABLE3 = {

@@ -10,7 +10,8 @@ converge to the fault-free trajectory).
 Pipeline state is therefore just the step counter; checkpointing the iterator
 is O(1) regardless of scale. Two sources:
 
-  * SyntheticLM: splitmix64-hashed tokens — zero I/O, used by tests/benches.
+  * SyntheticLM: splitmix64-hashed tokens — zero I/O, the default when no
+    corpus is given.
   * MemmapCorpus: windows into a binary uint16/uint32 token file via
     np.memmap, window offsets hashed from (seed, step, slot).
 

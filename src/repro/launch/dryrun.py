@@ -4,8 +4,7 @@ cell on the production mesh and extract memory / cost / collective analyses.
     PYTHONPATH=src python -m repro.launch.dryrun --arch mistral-large-123b \
         --shape train_4k --mesh single --flavor baseline
 
-Artifacts: one JSON per cell under artifacts/dryrun/. The roofline report
-(benchmarks/roofline.py) reads them.
+Artifacts: one JSON per cell under artifacts/dryrun/.
 
 Flavors:
   baseline : no protection; multi-pod meshes use the pod axis for data

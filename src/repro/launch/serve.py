@@ -116,7 +116,6 @@ def _continuous(args, cfg, ob=None) -> None:
     out, rep = srv.serve(
         params, reqs, slots=args.slots, validate_lag=args.validate_lag,
         queue_depth=args.queue_depth, autotune=tuner,
-        drain_cadence=args.drain_cadence,
         notify_reject=lambda r, e: print(
             f"[SEDAR] request {r.rid} REJECTED after {e.boundary} fault "
             f"(per-request safe stop)", flush=True))
@@ -202,11 +201,6 @@ def main() -> None:
                          "queue sheds load (backpressure rejection)")
     ap.add_argument("--validate-lag", type=int, default=None,
                     help="deferred-validation window D (DESIGN.md §11/§13)")
-    ap.add_argument("--drain-cadence", type=int, default=None,
-                    help="parked decode ticks per token drain (DESIGN.md "
-                         "§18): default = the validate lag (one fused "
-                         "readback per flush); 1 = legacy per-tick "
-                         "emission; >lag accumulates across flushes")
     ap.add_argument("--backend", default="sequential",
                     choices=["none", "sequential", "fused", "abft",
                              "hybrid"])

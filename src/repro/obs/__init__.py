@@ -19,7 +19,7 @@ Contract: everything here is host-side bookkeeping on facts the engine
 already read back — **telemetry never issues a device sync**, and with
 everything disabled each instrumentation point costs one ``is None`` /
 bool test (asserted by tests/test_observability_e2e.py via
-``count_transfers`` and bounded by bench_observability.py).
+``count_transfers``).
 
 This package never imports the engine/runtime modules (they import us),
 so there are no cycles and `repro.obs` stays importable without jax.

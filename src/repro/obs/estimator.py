@@ -15,7 +15,7 @@ Two intake paths, same accumulators:
     journal (records past the last seen seq). This is what the Autotuner
     calls between steps; it reads ONLY host-side aggregates the engine
     already produced, so the zero-extra-hostsync contract holds trivially.
-  * ``observe_*`` — direct push for benches/tests that synthesize streams
+  * ``observe_*`` — direct push for tests that synthesize streams
     without a running engine.
 
 Estimates are EWMA-smoothed with a sliding window for dispersion; MTBE is
@@ -120,7 +120,7 @@ class OnlineEstimator:
         self._hist_seen: Dict[Any, tuple] = {}
         self._journal_seq = -1
 
-    # -- direct push (benches/tests) ----------------------------------------
+    # -- direct push (tests) -------------------------------------------------
 
     def observe_step_s(self, seconds: float, weight: int = 1) -> None:
         self._step_s.add(seconds, weight)

@@ -12,8 +12,7 @@ Design constraints (DESIGN.md §15):
   * **Metrics-off is a no-op.** The registry starts disabled; the producer
     hooks installed into hostsync/prefill/store are `None` until
     `enable()` runs, so the disabled fast path is one `is None` test —
-    nothing allocates, nothing locks. Benchmarks assert < 3% overhead for
-    the ENABLED path (`bench_observability.py`).
+    nothing allocates, nothing locks.
   * **Cross-thread aggregation is explicit.** Every mutation takes the
     registry lock, so counts from a background consumer thread (the
     ROADMAP's detokenize-drain item) aggregate correctly — unlike the
@@ -25,8 +24,7 @@ Design constraints (DESIGN.md §15):
 
 `percentile(values, q)` is the repo's one shared nearest-rank percentile
 (matches `numpy.percentile(..., method="inverted_cdf")`); the scheduler's
-TTFT/latency reports and the bench harness use it instead of hand-rolled
-index formulas.
+TTFT/latency reports use it instead of hand-rolled index formulas.
 """
 from __future__ import annotations
 

@@ -31,7 +31,7 @@ to a new prompt while a pending flush can still prove the old request's
 tail corrupt and need the slot's state back for rollback.
 
 The traffic generator (`synthetic_requests`) produces the open-loop replay
-workload the launcher and benchmarks drive: Poisson-ish arrivals at a
+workload the launcher and the tests drive: Poisson-ish arrivals at a
 configurable rate on the decode-tick clock, a categorical prompt-length
 mix, and per-request token budgets — all seeded, so fault campaigns are
 bitwise reproducible against their fault-free twins.
@@ -304,7 +304,7 @@ def ttlt_percentiles_ms(requests: Iterable[Request]
 def stream_stats_ms(requests: Iterable[Request]) -> Dict[str, float]:
     """One bundle of client-visible streaming percentiles in ms: TTFT
     (first token), ITL (inter-token gap) and TTLT (full turnaround) —
-    what bench_serve rows and the `--continuous` CLI summary print."""
+    what the `--continuous` CLI summary prints."""
     reqs = list(requests)
     ttft50, ttft99 = ttft_percentiles_ms(reqs)
     itl50, itl99 = latency_percentiles_ms(reqs)

@@ -807,8 +807,7 @@ class SedarServer:
     def serve(self, params, requests, *, slots: int = 4,
               max_len: Optional[int] = None, validate_lag: Optional[int] = None,
               queue_depth: int = 0, max_steps: Optional[int] = None,
-              notify_reject=None, packed_prefill: bool = True,
-              autotune=None, drain_cadence: Optional[int] = None,
+              notify_reject=None, autotune=None,
               on_token=None, consumer_depth: int = 8):
         """Continuous-batching protected decode over an open-loop request
         stream. Mutates and returns the `Request` objects (lifecycle fields
@@ -820,11 +819,10 @@ class SedarServer:
         detected fault rolls back only the affected slots from the Tier-0
         ring) — token emission itself is deferred to the flush cadence
         through the engine's TokenRing and streamed from a detokenize
-        consumer thread (DESIGN.md §18). `drain_cadence` sets how many
-        parked ticks a drain waits for (None -> the validate lag, i.e.
-        every flush; 1 -> the legacy per-tick emission readback, kept as
-        the bench baseline); `on_token(req, tok, index)` streams each
-        delivered token (called from the consumer thread in drain mode);
+        consumer thread (DESIGN.md §18), one drain per flush; at lag 1
+        every tick reads its tokens back. `on_token(req, tok, index)`
+        streams each delivered token (called from the consumer thread in
+        drain mode);
         `consumer_depth` bounds the detokenize queue (backpressure).
         `queue_depth` bounds the admission queue (backpressure ->
         immediate rejection). `autotune` (a policy.Autotuner with
@@ -877,20 +875,16 @@ class SedarServer:
             ring_on = eng.validate_lag > 1   # clamped lag => pre-commit gate
             # lag-aligned batched drain (DESIGN.md §18): tokens leave the
             # device through flush_deferred's fused readback and reach the
-            # request streams via the consumer thread. Per-tick emission
-            # survives as `drain_cadence=1` (and as the only mode at lag 1,
-            # where every commit is already a sync point).
-            drain_on = ring_on and (drain_cadence is None
-                                    or int(drain_cadence) > 1)
+            # request streams via the consumer thread. Lag 1 emits per
+            # tick: every commit there is already a sync point.
+            drain_on = ring_on
             tokring = consumer = None
             expected: Dict[int, int] = {}   # slot -> optimistic token count
             if drain_on:
                 consumer = DetokenizeConsumer(on_token=on_token,
                                               max_queue=consumer_depth).start()
-                tokring = TokenRing(
-                    cadence=(int(drain_cadence) if drain_cadence
-                             else eng.validate_lag),
-                    sink=consumer.submit)
+                tokring = TokenRing(cadence=eng.validate_lag,
+                                    sink=consumer.submit)
                 eng.emission_ring = tokring
 
             sched = SlotScheduler(slots, RequestQueue(queue_depth))
@@ -910,10 +904,7 @@ class SedarServer:
             slot_arrays = len(jax.tree.leaves(state["cache"])) + 2
             dual = eng.executor.init_dual(state)
 
-            # packed_prefill=False keeps the legacy one-launch-per-request
-            # admission — the equality oracle (and bench baseline) for the
-            # bucketed pack path
-            use_packed = packed_prefill and self.prefiller.supported
+            use_packed = self.prefiller.supported
             prefill_events: List[DetectionEvent] = []
             t = 0
             cap = max_steps or (sum(r.max_new_tokens for r in requests)
@@ -940,7 +931,8 @@ class SedarServer:
                                                 sched, notify_reject,
                                                 prefill_events,
                                                 prefill_counts)
-                    # longer than the ladder, or the legacy path
+                    # longer than the ladder, or a family without packed
+                    # prefill
                     for slot, req in overflow:
                         dual = self._admit_slot(eng, dual, params, slot, req,
                                                 t, ring, ring_on, max_len)
@@ -1039,7 +1031,7 @@ class SedarServer:
                         for slot in ready:
                             self._finish(sched, slot, rep)
             else:
-                # per-tick emission (lag 1, or drain_cadence=1 baseline):
+                # per-tick emission (lag 1):
                 # tok + pos fetched in a single transfer batch; per-slot
                 # position deltas drive emission, so partial commits
                 # (faulty slot frozen) and rollbacks (position regressed)

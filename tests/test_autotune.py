@@ -8,6 +8,7 @@ real toy engine end-to-end — including the acceptance criteria that every
 alert/reconfig reconstructs byte-for-byte from the journal and that a
 fault-free protected run has IDENTICAL host-sync label maps with the
 autotuner on vs off."""
+import dataclasses
 import os
 
 import jax
@@ -99,7 +100,7 @@ class _StubEngine:
 
 def _calibrate_storm(est, n_steps=64, gap_s=72.0, n_faults=12):
     """Feed a fully-confident storm calibration: 2s steps, 4s syncs, and
-    faults every ``gap_s`` (72s = 0.02h MTBE — the bench's storm phase)."""
+    faults every ``gap_s`` (72s = 0.02h MTBE — the storm of PHASES below)."""
     for _ in range(n_steps):
         est.observe_step_s(2.0)
     for _ in range(8):
@@ -142,7 +143,7 @@ def test_estimator_mtbe_prior_then_measured():
 
 def test_estimator_tracks_mtbe_shift():
     """Calm (1h gaps) then storm (72s gaps): the EWMA must converge to the
-    storm rate — the quantity the bench's lag retarget keys on."""
+    storm rate — the quantity the lag retarget keys on."""
     est = OnlineEstimator(BASE)
     t = 0.0
     for _ in range(6):
@@ -462,6 +463,86 @@ def test_autotuner_backend_advice_is_an_alert_not_a_swap():
         assert adv["severity"] == "info"
         assert adv["detail"]["recommended"] == "abft"
     assert eng.reconfigs == []       # advisory: no knob was touched
+
+
+# ---------------------------------------------------------------------------
+# a shifting fault environment: calm, then a storm
+# ---------------------------------------------------------------------------
+
+T_STEP_S, T_SYNC_S = 2.0, 4.0
+PHASES = ((1600, 8.0), (600, 0.02))       # (steps, MTBE hours)
+
+
+def _fault_steps(seed=0):
+    """Steps at which a fault commits: exponential gaps per phase, one
+    trace shared by every policy."""
+    rs = np.random.RandomState(seed)
+    faults, offset = set(), 0
+    for steps, mtbe_h in PHASES:
+        gap = mtbe_h * 3600.0 / T_STEP_S
+        t = rs.exponential(gap)
+        while t < steps:
+            faults.add(offset + int(t))
+            t += rs.exponential(gap)
+        offset += steps
+    return faults
+
+
+def _simulated_wall(faults, lag, tuner=None):
+    """Deferred validation at `lag` (or as `tuner` retunes it), costed per
+    step, per flush, and per step a detection discards (fault commit ->
+    the flush that surfaces it): the trade Eq. (11) prices. The tuner's
+    estimator is fed what a running engine would measure: jittered step
+    times, flush times and back-dated fault times."""
+    rs = np.random.RandomState(1)
+    eng = _StubEngine(lag=lag)
+    wall, step, last_flush, pending = 0.0, 0, 0, []
+    for steps, _ in PHASES:
+        for _ in range(steps):
+            step += 1
+            dt = T_STEP_S * (1.0 + 0.05 * rs.randn())
+            wall += dt
+            if tuner is not None:
+                tuner.estimator.observe_step_s(dt)
+            if step - 1 in faults:
+                pending.append(step)
+            eng.pending_validation = step - last_flush < eng.validate_lag
+            if not eng.pending_validation:
+                wall += T_SYNC_S
+                if tuner is not None:
+                    tuner.estimator.observe_sync_s(T_SYNC_S)
+                if pending:
+                    wall += (step - min(pending) + 1) * T_STEP_S
+                    if tuner is not None:
+                        for fs in pending:
+                            tuner.estimator.observe_fault(
+                                wall - (step - fs) * T_STEP_S)
+                    pending.clear()
+                last_flush = step
+            if tuner is not None:
+                tuner.maybe_tune(eng, step)
+    return wall, eng
+
+
+def test_autotuner_converges_after_mtbe_shift():
+    """The lag the Autotuner lands on after the storm is within one ladder
+    step of the calibrated optimum, it calibrates the step time within 5%
+    under 5% jitter, and its run costs no more than any fixed lag's on the
+    same fault trace."""
+    base = dataclasses.replace(BASE, t_step=T_STEP_S / 3600.0,
+                               t_sync=T_SYNC_S / 3600.0)
+    faults = _fault_steps()
+    tuner = Autotuner(base, AutotuneConfig(interval_steps=16, persistence=2,
+                                           min_confidence=0.25))
+    wall, eng = _simulated_wall(faults, 8, tuner)
+    snap = tuner.estimator.calibrated_params()
+    want = tm.optimal_validate_lag(snap.params, snap.mtbe_hours)
+    ladder = list(tm.LAG_CANDIDATES)
+    assert eng.reconfigs
+    assert abs(ladder.index(eng.validate_lag) - ladder.index(want)) <= 1
+    assert abs(snap.params.t_step * 3600.0 - T_STEP_S) / T_STEP_S < 0.05
+    fixed = {lag: _simulated_wall(faults, lag)[0] for lag in ladder}
+    assert wall <= min(fixed.values()), (wall, fixed)
 
 
 # ---------------------------------------------------------------------------

@@ -7,7 +7,8 @@ Covers the acceptance properties of the device-resident protected step:
     hook the whole engine/driver stack reports through);
   * a fault injected at step k with validate_lag=D is detected at step
     <= k+D, rolls back to a checkpoint <= k, and the replayed trajectory
-    is bitwise-identical to a validate_lag=1 run of the same backend;
+    is bitwise-identical to a fault-free validate_lag=1 run of the same
+    compiled program (the unfired twin of the fault's spec);
   * the fused (single-launch, vmapped) executor matches its own lag=1
     trajectory bitwise at any lag, and its commit gate keeps L0 retry
     working even with donated buffers;
@@ -15,6 +16,7 @@ Covers the acceptance properties of the device-resident protected step:
   * bounded-chain L2 GC retains one checkpoint older than the validation
     frontier (the deferred retention rule).
 """
+import dataclasses
 import os
 
 import jax
@@ -22,7 +24,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import SedarConfig
+from repro.configs import (RunConfig, SedarConfig, TrainConfig, get_config,
+                           reduce_for_smoke)
 from repro.core import hostsync
 from repro.core.detection import SedarSafeStop
 from repro.core.engine import FusedSequentialExecutor
@@ -32,6 +35,7 @@ from repro.core.injection import InjectionSpec, MemoryInjectionFlag, \
     inject_tree
 from repro.core.policy import make_engine
 from repro.core.recovery import RetryRecovery
+from repro.runtime.train import SedarTrainer
 
 
 # -- toy workload (same shape as test_engine's) ------------------------------
@@ -119,6 +123,13 @@ SPEC = InjectionSpec(leaf_idx=0, flat_idx=5, bit=20, step=4, replica=1,
                      target="grads")
 
 
+def _unfired(spec):
+    """The fault's twin that never fires: `step` past any run. A fault-free
+    reference built from it compiles the same injection hook, so it is the
+    same program as the faulted run (the contract of DESIGN.md §11)."""
+    return dataclasses.replace(spec, step=10 ** 6)
+
+
 # -- zero-sync steady state ---------------------------------------------------
 
 @pytest.mark.parametrize("backend", ["fused", "sequential"])
@@ -149,6 +160,32 @@ def test_zero_transfers_between_flushes(tmp_workdir, backend):
     assert eng.validated_frontier == 8
 
 
+@pytest.mark.parametrize("backend", ["fused", "sequential"])
+def test_trainer_steps_sync_only_at_flushes(tmp_workdir, backend):
+    """The same property through SedarTrainer on the paper test app: at
+    validate_lag=8, the only readbacks of 16 fault-free steps are the two
+    window flushes."""
+    rc = RunConfig(
+        model=reduce_for_smoke(get_config("paper-testapp")),
+        train=TrainConfig(global_batch=2, seq_len=16, steps=16,
+                          warmup_steps=2, lr=1e-3),
+        sedar=SedarConfig(level=1, replication=backend, validate_interval=1,
+                          validate_lag=8, param_validate_interval=0,
+                          checkpoint_interval=0))
+    tr = SedarTrainer(rc, tmp_workdir)
+    eng = tr.engine
+    batch = {k: jnp.asarray(v) for k, v in tr.data.batch(0).items()}
+    eng.run_protected_step(tr.init_dual(), batch, 0)   # compile, uncounted
+    dual = tr.init_dual()
+    eng.reset()
+    with hostsync.count_transfers() as st:
+        for s in range(16):
+            out = eng.run_protected_step(dual, batch, s)
+            dual = out.dual
+            assert out.event is None
+    assert st.by_label == {"deferred_flush": 2}
+
+
 def test_lag1_syncs_every_compare(tmp_workdir):
     """Control: the classic path reads the predicate back every step."""
     eng = _toy_engine(tmp_workdir, 2, backend="fused", lag=1,
@@ -172,7 +209,7 @@ def test_lag1_syncs_every_compare(tmp_workdir):
 def test_deferred_fault_detected_within_window(tmp_workdir, backend, lag):
     """Fault at step k: detection fires at <= k+D, rollback lands on a
     checkpoint <= k, and the replayed trajectory is bitwise-identical to a
-    validate_lag=1 run of the same backend."""
+    fault-free validate_lag=1 run of the same backend and program."""
     k = SPEC.step
     eng = _toy_engine(tmp_workdir, 2, spec=SPEC, backend=backend, lag=lag,
                       ckpt_interval=3)
@@ -186,8 +223,8 @@ def test_deferred_fault_detected_within_window(tmp_workdir, backend, lag):
     assert [r["kind"] for r in eng.recoveries] == ["restore"]
     assert eng.recoveries[0]["step"] <= k      # pre-fault checkpoint
 
-    ref = _toy_engine(tmp_workdir + "_ref", 2, backend=backend, lag=1,
-                      ckpt_interval=3)
+    ref = _toy_engine(tmp_workdir + "_ref", 2, spec=_unfired(SPEC),
+                      backend=backend, lag=1, ckpt_interval=3)
     dual_ref, _ = _drive(ref, 10)
     np.testing.assert_array_equal(_x(eng, dual), _x(ref, dual_ref))
 
@@ -215,8 +252,8 @@ def test_deferred_fault_near_end_caught_by_final_flush(tmp_workdir):
     assert not stopped
     assert [e.boundary for e in eng.detections] == ["deferred"]
     assert eng.detections[0].step == 7
-    ref = _toy_engine(tmp_workdir + "_ref", 2, backend="fused", lag=1,
-                      ckpt_interval=3)
+    ref = _toy_engine(tmp_workdir + "_ref", 2, spec=_unfired(spec),
+                      backend="fused", lag=1, ckpt_interval=3)
     dual_ref, _ = _drive(ref, 8)
     np.testing.assert_array_equal(_x(eng, dual), _x(ref, dual_ref))
 
@@ -243,7 +280,8 @@ def test_fused_lag1_commit_gate_supports_retry(tmp_workdir):
     assert not stopped
     assert [e.boundary for e in eng.detections] == ["commit"]
     assert [r["kind"] for r in eng.recoveries] == ["retry"]
-    ref = _toy_engine(tmp_workdir + "_ref", 1, backend="fused", lag=1)
+    ref = _toy_engine(tmp_workdir + "_ref", 1, spec=_unfired(SPEC),
+                      backend="fused", lag=1)
     ref.recovery = RetryRecovery(max_retries=4)
     dual_ref, _ = _drive(ref, 8)
     np.testing.assert_array_equal(_x(eng, dual), _x(ref, dual_ref))
@@ -257,8 +295,8 @@ def test_fused_l3_validated_checkpoint_roundtrip(tmp_workdir):
     dual, stopped = _drive(eng, 8)
     assert not stopped
     assert [r["kind"] for r in eng.recoveries] == ["restore"]
-    ref = _toy_engine(tmp_workdir + "_ref", 3, backend="fused", lag=1,
-                      ckpt_interval=3)
+    ref = _toy_engine(tmp_workdir + "_ref", 3, spec=_unfired(SPEC),
+                      backend="fused", lag=1, ckpt_interval=3)
     dual_ref, _ = _drive(ref, 8)
     np.testing.assert_array_equal(_x(eng, dual), _x(ref, dual_ref))
 

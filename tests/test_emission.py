@@ -369,28 +369,3 @@ def test_run_ending_midwindow_releases_exactly_once(oracle):
     # even for requests the cap cut off)
     for r in out:
         assert list(r.tokens) == clean_toks[r.rid][:len(r.tokens)]
-
-
-def test_drain_cadence_one_is_bitwise_baseline(oracle):
-    """drain_cadence=1 keeps the legacy per-tick readback; its streams are
-    bitwise identical to lag-aligned drain at the same lag."""
-    rc, params, clean_toks = oracle
-    srv = SedarServer(rc, dual=True)
-    out, rep = srv.serve(params, _requests(), slots=SLOTS, validate_lag=8,
-                         drain_cadence=1)
-    _assert_streams_equal(out, clean_toks)
-    assert rep.tokens_emitted == sum(len(r.tokens) for r in out)
-
-
-def test_drain_cadence_above_lag_accumulates(oracle):
-    """drain_cadence > lag: sub-cadence flushes validate predicates while
-    rows ride along; tokens surface in even fewer, bigger batches and the
-    streams still match."""
-    rc, params, clean_toks = oracle
-    srv = SedarServer(rc, dual=True)
-    with hostsync.count_transfers(cross_thread=True) as st:
-        out, rep = srv.serve(params, _requests(), slots=SLOTS,
-                             validate_lag=4, drain_cadence=12)
-    _assert_streams_equal(out, clean_toks)
-    # fewer token_emit items than one 3-leaf batch per lag-4 window
-    assert st.by_label.get("token_emit", 0) < 3 * (rep.steps // 4 + 2)

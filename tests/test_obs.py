@@ -733,93 +733,3 @@ def test_status_render_empty_dir_is_graceful(tmp_path):
     from repro.launch.status import render
     page = render(str(tmp_path))
     assert "journal: empty" in page
-
-
-# ---------------------------------------------------------------------------
-# CI bench-regression gate (benchmarks/compare.py)
-# ---------------------------------------------------------------------------
-
-def _summary(metrics=None, acceptance=None):
-    return {"suites": {"s": {"artifact": "BENCH_s.json",
-                             "metrics": metrics or {},
-                             "acceptance": acceptance or {}}}}
-
-
-def test_compare_direction_heuristics():
-    from benchmarks.compare import direction
-    assert direction("protected_steps_per_s") == +1
-    assert direction("serve_goodput_tok_s") == +1
-    assert direction("adaptive_wall_s") == -1
-    assert direction("mttr_s") == -1
-    assert direction("mystery_quantity") is None
-    # PR-10 drain metrics: gated in the directions they must move
-    assert direction("continuous_drain_tokens_per_s") == +1
-    assert direction("emission_syncs_per_token") == -1
-
-
-def test_compare_flags_directional_regressions():
-    from benchmarks.compare import compare
-    base = _summary(metrics={"steps_per_s": 100.0, "wall_s": 10.0},
-                    acceptance={"converged": True})
-    same = compare(base, base)
-    assert same == []
-    # throughput falls 50% -> regression; cost falls -> improvement
-    cur = _summary(metrics={"steps_per_s": 50.0, "wall_s": 5.0},
-                   acceptance={"converged": True})
-    regs = compare(base, cur)
-    assert [r["metric"] for r in regs] == ["steps_per_s"]
-    # cost rises 50% -> regression, within threshold -> clean
-    cur = _summary(metrics={"steps_per_s": 100.0, "wall_s": 15.0})
-    assert [r["metric"] for r in compare(base, cur)][:1] == ["wall_s"]
-    cur = _summary(metrics={"steps_per_s": 95.0, "wall_s": 11.0},
-                   acceptance={"converged": True})
-    assert compare(base, cur) == []
-
-
-def test_compare_acceptance_flip_and_missing_suite():
-    from benchmarks.compare import compare
-    base = _summary(metrics={"wall_s": 10.0}, acceptance={"converged": True})
-    cur = _summary(metrics={"wall_s": 10.0}, acceptance={"converged": False})
-    regs = compare(base, cur)
-    assert [(r["kind"], r["metric"]) for r in regs] == \
-        [("acceptance", "converged")]
-    regs = compare(base, {"suites": {}})
-    assert regs[0]["kind"] == "missing"
-    # undirectable metrics are never gated
-    base = _summary(metrics={"mystery_quantity": 1.0})
-    cur = _summary(metrics={"mystery_quantity": 100.0})
-    assert compare(base, cur) == []
-
-
-def test_compare_cli_skips_without_baseline(tmp_path, capsys, monkeypatch):
-    from benchmarks import compare as cmp
-    cur = tmp_path / "BENCH_summary.json"
-    cur.write_text(json.dumps(_summary(metrics={"wall_s": 10.0})))
-    monkeypatch.setattr("sys.argv", [
-        "compare", "--baseline", str(tmp_path / "missing.json"),
-        "--current", str(cur)])
-    with pytest.raises(SystemExit) as e:
-        cmp.main()
-    assert e.value.code == 0
-    assert "skipping" in capsys.readouterr().out
-
-
-def test_compare_cli_fails_on_regression(tmp_path, capsys, monkeypatch):
-    from benchmarks import compare as cmp
-    base = tmp_path / "base.json"
-    cur = tmp_path / "cur.json"
-    base.write_text(json.dumps(_summary(metrics={"wall_s": 10.0})))
-    cur.write_text(json.dumps(_summary(metrics={"wall_s": 20.0})))
-    monkeypatch.setattr("sys.argv", [
-        "compare", "--baseline", str(base), "--current", str(cur)])
-    with pytest.raises(SystemExit) as e:
-        cmp.main()
-    assert e.value.code == 1
-    assert "wall_s" in capsys.readouterr().out
-    # loosening the threshold clears it
-    monkeypatch.setattr("sys.argv", [
-        "compare", "--baseline", str(base), "--current", str(cur),
-        "--threshold", "1.5"])
-    with pytest.raises(SystemExit) as e:
-        cmp.main()
-    assert e.value.code == 0

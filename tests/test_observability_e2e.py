@@ -290,25 +290,40 @@ def test_metrics_on_adds_zero_host_syncs(tmp_workdir):
                            label="deferred_flush") == 1
 
 
+def _serve_transfers(rc, params):
+    """Per-label transfer counts of one fault-free serve at lag=8, taken
+    after a warm-up call."""
+    srv = SedarServer(rc, dual=True)
+    srv.serve(params, _serve_requests(), slots=SLOTS,
+              validate_lag=8)                          # warm the jit cache
+    with hostsync.count_transfers() as st:
+        _, rep = srv.serve(params, _serve_requests(), slots=SLOTS,
+                           validate_lag=8)
+    assert not rep.detections
+    return st
+
+
 def test_metrics_on_serve_same_transfer_labels():
     """The same contract through the full continuous-batching loop: the
     per-label transfer counts of a fault-free serve at lag=8 are identical
     with metrics+journal on vs off."""
     rc = _serve_cfg()
     params = SedarServer(rc, dual=True).model.init(jax.random.PRNGKey(0))
-
-    def run():
-        srv = SedarServer(rc, dual=True)
-        srv.serve(params, _serve_requests(), slots=SLOTS,
-                  validate_lag=8)                      # warm the jit cache
-        with hostsync.count_transfers() as st:
-            _, rep = srv.serve(params, _serve_requests(), slots=SLOTS,
-                               validate_lag=8)
-        assert not rep.detections
-        return st
-
-    off = run()
+    off = _serve_transfers(rc, params)
     obs.enable_metrics()
     obs.set_journal(obs.FaultJournal())
-    on = run()
+    on = _serve_transfers(rc, params)
+    assert on.by_label == off.by_label, (on.by_label, off.by_label)
+
+
+def test_trace_on_serve_same_transfer_labels():
+    """Trace spans on top of metrics+journal: the serve loop's per-label
+    transfer counts still equal the telemetry-off run's."""
+    rc = _serve_cfg()
+    params = SedarServer(rc, dual=True).model.init(jax.random.PRNGKey(0))
+    off = _serve_transfers(rc, params)
+    obs.enable_metrics()
+    obs.set_journal(obs.FaultJournal())
+    obs.enable_trace()
+    on = _serve_transfers(rc, params)
     assert on.by_label == off.by_label, (on.by_label, off.by_label)

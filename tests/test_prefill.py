@@ -104,10 +104,11 @@ def test_padded_prefill_bitwise_equals_exact(setup):
 
 def test_packed_serve_equals_legacy_admission(setup):
     """The whole point: packed bucketed admission produces bitwise the same
-    streams as one-exact-launch-per-request admission."""
+    streams as one-exact-launch-per-request admission (forced via a ladder
+    every prompt overflows)."""
     rc, params, clean_toks = setup
-    srv = SedarServer(rc, dual=True)
-    out, rep = srv.serve(params, _reqs(), slots=SLOTS, packed_prefill=False)
+    srv = SedarServer(rc, dual=True, prefill_buckets=(1,))
+    out, rep = srv.serve(params, _reqs(), slots=SLOTS)
     assert rep.prefill_packs == 0
     _assert_streams_equal({r.rid: r for r in out}, clean_toks)
 

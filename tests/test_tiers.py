@@ -10,6 +10,7 @@ Acceptance (ISSUE 4):
   * Tier-2 corruption falls back to Tier 3 (then Tier 1) as a recorded
     recovery event, never an exception.
 """
+import dataclasses
 import os
 
 import jax
@@ -121,6 +122,13 @@ def _drive(eng, num_steps, on_event=None, max_iters=200):
 
 SPEC = InjectionSpec(leaf_idx=0, flat_idx=5, bit=20, step=4, replica=1,
                      target="grads")
+
+
+def _unfired(spec):
+    """The fault's twin that never fires: `step` past any run. A fault-free
+    reference built from it compiles the same injection hook, so it is the
+    same program as the faulted run (the contract of DESIGN.md §11)."""
+    return dataclasses.replace(spec, step=10 ** 6)
 
 
 # -- rings --------------------------------------------------------------------
@@ -240,6 +248,30 @@ def test_planner_rework_outweighs_tier_cost_at_distance(tmp_path):
     assert tc.plan(max_step=100)[0] == ("disk", 100)
 
 
+@pytest.mark.parametrize("tier,version", [("device", 8), ("host", 4),
+                                          ("disk", 8)])
+def test_restore_names_the_tier_it_read(tmp_path, tier, version):
+    """A restore names the tier that served it, and only the disk tier
+    reads disk: the device ring serves while it holds the version, the
+    host ring once the device ring is empty, disk once both are."""
+    sched = TierSchedule(device=1, host=4, disk=8)
+    tc = TieredCheckpointer(sched, device_slots=4, host_slots=4,
+                            disk_store=CheckpointStore(str(tmp_path)))
+    st = _state(3)
+    for step in range(1, 9):
+        tc.save(step, st, async_=False)
+    if tier != "device":
+        tc.device.clear()
+    if tier == "disk":
+        tc.host.clear()
+    template = jax.tree.map(np.asarray, st)
+    with count_disk_reads() as dr:
+        got, info = tc.restore(version, template)
+    assert info == {"tier": tier, "version": version}
+    assert (dr.reads > 0) == (tier == "disk"), dr.reads
+    np.testing.assert_array_equal(np.asarray(got["x"]), np.asarray(st["x"]))
+
+
 # -- acceptance: zero-disk-read ring recovery under L2 ------------------------
 
 @pytest.mark.parametrize("backend", ["sequential", "fused"])
@@ -264,8 +296,8 @@ def test_l2_fault_recovers_from_device_ring_zero_disk_reads(tmp_workdir,
     rec = eng.recoveries[0]
     assert rec["tier"] == "device" and rec["step"] <= SPEC.step
     # the replayed trajectory matches a fault-free flat-disk run bitwise
-    ref = _toy_engine(tmp_workdir + "_ref", 2, backend=backend,
-                      tiers="disk")
+    ref = _toy_engine(tmp_workdir + "_ref", 2, spec=_unfired(SPEC),
+                      backend=backend, tiers="disk")
     dual_ref, _ = _drive(ref, 10)
     np.testing.assert_array_equal(
         np.asarray(eng.executor.peek(dual, "x")),
@@ -292,8 +324,8 @@ def test_l2_deferred_window_fault_restores_from_ring(tmp_workdir):
     assert ev.boundary == "deferred" and ev.step == SPEC.step
     rec = eng.recoveries[0]
     assert rec["tier"] == "device" and rec["step"] <= SPEC.step
-    ref = _toy_engine(tmp_workdir + "_ref", 2, backend="fused", lag=1,
-                      tiers="disk")
+    ref = _toy_engine(tmp_workdir + "_ref", 2, spec=_unfired(SPEC),
+                      backend="fused", lag=1, tiers="disk")
     dual_ref, _ = _drive(ref, 12)
     np.testing.assert_array_equal(
         np.asarray(eng.executor.peek(dual, "x")),
